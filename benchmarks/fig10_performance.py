@@ -34,6 +34,20 @@ CONFIGS = (  # (label, partitions, page_shift, design)
 )
 
 
+def configs():
+    """The nine designs (4K/2M x partition counts x DIPTA/ideal) as one
+    batch of system configs, in CONFIGS order."""
+    return [
+        SystemSimConfig(
+            cache=CACHE,
+            accel_tlb=ACCEL_TLB if design == "conventional" else None,
+            mem_tlb=MEM_TLB, num_partitions=parts, page_shift=shift,
+            accel_probe_on_miss_only=True,
+        )
+        for _, parts, shift, design in CONFIGS
+    ]
+
+
 @with_runlog("fig10")
 def run(quick: bool = False, kernel_mode: str = "auto",
         resume: bool = False, chunk_accesses=None, sched=None):
@@ -50,15 +64,9 @@ def run(quick: bool = False, kernel_mode: str = "auto",
         ipa = tr.instr_per_access
         # All nine designs (4K/2M x partition counts x DIPTA/ideal) share one
         # batched pass over the trace.
-        evs, metas[f"system-{w}"] = run_sweep_system(tr.lines, [
-            SystemSimConfig(
-                cache=CACHE,
-                accel_tlb=ACCEL_TLB if design == "conventional" else None,
-                mem_tlb=MEM_TLB, num_partitions=parts, page_shift=shift,
-                accel_probe_on_miss_only=True,
-            )
-            for _, parts, shift, design in CONFIGS
-        ], kernel_mode=kernel_mode, run=rc, name=f"system-{w}", sched=sched)
+        evs, metas[f"system-{w}"] = run_sweep_system(
+            tr.lines, configs(), kernel_mode=kernel_mode, run=rc,
+            name=f"system-{w}", sched=sched)
         perfs = {}
         for i_c, (label, parts, shift, design) in enumerate(CONFIGS):
             perfs[label] = cpi.evaluate_design(

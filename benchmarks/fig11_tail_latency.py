@@ -43,26 +43,32 @@ PARTITIONS = 32
 QUEUES = timeline.TimelineConfig(mshrs=8, tlb_ports=1, dram_banks=16)
 
 
-@with_runlog("fig11")
-def run(quick: bool = False, kernel_mode: str = "auto",
-        resume: bool = False, chunk_accesses=None, sched=None):
-    accels = (1, 4, 16) if quick else (1, 2, 4, 8, 16)
+LAT = SystemLatencies(n_sockets=8)
+
+
+def accel_counts(quick: bool = False):
+    return (1, 4, 16) if quick else (1, 2, 4, 8, 16)
+
+
+def matrix(quick: bool = False, kernel_mode: str = "auto", *, run,
+           sched=None, seed: int = 7):
+    """The figure's (design x workload x accel-count) timeline specs.
+
+    One trace + one system sweep per workload (through the crash-safe
+    orchestrator under ``run``), shared by the whole accel loop.  Returns
+    ``(specs, cells, metas)``: ``cells[i]`` is the (workload, accels) of
+    specs ``2i`` (conventional) and ``2i + 1`` (SPARTA), ``metas`` the
+    system sweeps' run metadata."""
+    accels = accel_counts(quick)
     n_ops = 1_000 if quick else 8_000
     # The crash-safe chunked engines stream the trace with a bounded
     # per-chunk working set, so the full-mode cap is no longer pinned to the
     # monolithic pass's 150k ceiling.
     cap = 24_000 if quick else 400_000
-    lat = SystemLatencies(n_sockets=8)
-    a_max = accels[-1]
-    rc = run_config("fig11", resume=resume, chunk_accesses=chunk_accesses)
     metas = {}
-
-    # One trace + one system sweep per workload, shared by the whole accel
-    # loop; one timeline sweep pass for the whole figure.  Every sweep runs
-    # through the crash-safe orchestrator: chunked, checkpointed, resumable.
     specs, cells = [], []
     for w in W4:
-        streams = traces.thread_traces(w, a_max, n_ops=n_ops, seed=7)
+        streams = traces.thread_traces(w, accels[-1], n_ops=n_ops, seed=seed)
         inter = traces.interleave(streams)[:cap]
         evs, metas[f"system-{w}"] = run_sweep_system(inter, [
             SystemSimConfig(cache=CACHE, accel_tlb=ACCEL_TLB,
@@ -70,7 +76,7 @@ def run(quick: bool = False, kernel_mode: str = "auto",
             SystemSimConfig(cache=CACHE, accel_tlb=None,
                             mem_tlb=MEM_TLB, num_partitions=PARTITIONS,
                             page_shift=12),
-        ], kernel_mode=kernel_mode, run=rc, name=f"system-{w}", sched=sched)
+        ], kernel_mode=kernel_mode, run=run, name=f"system-{w}", sched=sched)
         for A in accels:
             ids = timeline.round_robin_accel_ids(inter.shape[0], A)
             specs.append(timeline.TimelineSpec(
@@ -80,8 +86,20 @@ def run(quick: bool = False, kernel_mode: str = "auto",
                 inter, evs[1], "sparta", cfg=QUEUES,
                 num_partitions=PARTITIONS, num_accelerators=A, accel_ids=ids))
             cells.append((w, A))
+    return specs, cells, metas
+
+
+@with_runlog("fig11")
+def run(quick: bool = False, kernel_mode: str = "auto",
+        resume: bool = False, chunk_accesses=None, sched=None):
+    accels = accel_counts(quick)
+    a_max = accels[-1]
+    rc = run_config("fig11", resume=resume, chunk_accesses=chunk_accesses)
+    # One timeline sweep pass for the whole figure.  Every sweep runs
+    # through the crash-safe orchestrator: chunked, checkpointed, resumable.
+    specs, cells, metas = matrix(quick, kernel_mode, run=rc, sched=sched)
     results, metas["timeline"] = run_sweep_timeline(
-        specs, lat, kernel_mode=kernel_mode, run=rc, name="timeline",
+        specs, LAT, kernel_mode=kernel_mode, run=rc, name="timeline",
         sched=sched)
 
     rows = []

@@ -24,6 +24,21 @@ MEM_TLB = TLBConfig(entries=128, ways=4)
 CACHE = TLBConfig(entries=256, ways=4)  # 16KB / 64B lines
 
 
+def configs():
+    """The figure's batch: the conventional baseline, the accel-TLB capacity
+    sweep, and the virtual-cache/no-TLB point."""
+    cfgs = [SystemSimConfig(
+        cache=CACHE, accel_tlb=TLBConfig(entries=128, ways=4),
+        mem_tlb=MEM_TLB, num_partitions=1, accel_probe_on_miss_only=True)]
+    cfgs += [SystemSimConfig(
+        cache=CACHE, accel_tlb=TLBConfig(entries=e, ways=4),
+        mem_tlb=MEM_TLB, num_partitions=P, accel_probe_on_miss_only=False)
+        for e in ENTRIES]
+    cfgs.append(SystemSimConfig(
+        cache=CACHE, accel_tlb=None, mem_tlb=MEM_TLB, num_partitions=P))
+    return cfgs
+
+
 @with_runlog("fig9")
 def run(quick: bool = False, kernel_mode: str = "auto",
         resume: bool = False, chunk_accesses=None, sched=None):
@@ -38,15 +53,7 @@ def run(quick: bool = False, kernel_mode: str = "auto",
         # Baseline (conventional, virtual cache + 128-entry accel TLB), the
         # accel-TLB capacity sweep, and the virtual-cache/no-TLB point all
         # ride ONE batched pass over the trace.
-        cfgs = [SystemSimConfig(
-            cache=CACHE, accel_tlb=TLBConfig(entries=128, ways=4),
-            mem_tlb=MEM_TLB, num_partitions=1, accel_probe_on_miss_only=True)]
-        cfgs += [SystemSimConfig(
-            cache=CACHE, accel_tlb=TLBConfig(entries=e, ways=4),
-            mem_tlb=MEM_TLB, num_partitions=P, accel_probe_on_miss_only=False)
-            for e in ENTRIES]
-        cfgs.append(SystemSimConfig(
-            cache=CACHE, accel_tlb=None, mem_tlb=MEM_TLB, num_partitions=P))
+        cfgs = configs()
         evs, metas[f"system-{w}"] = run_sweep_system(
             tr.lines, cfgs, kernel_mode=kernel_mode, run=rc, name=f"system-{w}",
             sched=sched)

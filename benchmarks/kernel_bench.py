@@ -1,8 +1,9 @@
-"""Kernel micro-benchmarks: reference-vs-interpret allclose + XLA-path timing.
+"""Kernel micro-benchmarks: reference-vs-Pallas allclose + XLA-path timing.
 
-On this CPU container the timing column measures the *reference* (XLA) path
-(the Pallas kernels execute via the interpreter, which is not representative
-of TPU performance); the allclose column is the correctness deliverable.
+The correctness rows compare each Pallas kernel against its oracle: the
+Mosaic kernel on a TPU backend, the Pallas interpreter on CPU.  The timing
+column measures the *reference* (XLA) path; the allclose column is the
+correctness deliverable.
 
 The sweep-engine section times the batched-scan reference against the exact
 stack-distance backend on a fig4-style sweep; the timeline section times the
@@ -66,9 +67,16 @@ def _step(name: str, profile: bool):
             else contextlib.nullcontext())
 
 
+def _pallas_mode() -> str:
+    """The Pallas mode this backend runs: the Mosaic kernel on a TPU, the
+    interpreter on CPU."""
+    return "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+
+
 def _run_benches(quick: bool, profile: bool = False):
     rng = np.random.default_rng(0)
     rows = []
+    pallas_mode = _pallas_mode()
 
     # flash attention
     from repro.kernels.flash_attention import flash_attention
@@ -77,7 +85,7 @@ def _run_benches(quick: bool, profile: bool = False):
     k = jnp.asarray(rng.standard_normal((B, Hkv, T, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, Hkv, T, D)), jnp.float32)
     ref = flash_attention(q, k, v, kernel_mode="reference")
-    pal = flash_attention(q, k, v, block_q=64, block_k=64, kernel_mode="pallas_interpret")
+    pal = flash_attention(q, k, v, block_q=64, block_k=64, kernel_mode=pallas_mode)
     err = float(jnp.abs(ref - pal).max())
     us = _timeit(lambda a, b, c: flash_attention(a, b, c, kernel_mode="reference"), q, k, v,
                  label="kernel:flash_attention")
@@ -92,7 +100,7 @@ def _run_benches(quick: bool, profile: bool = False):
     tbl = jnp.asarray(rng.choice(slots, (B, pages), replace=False).astype(np.int32))
     ctx = jnp.asarray(rng.integers(1, pages * page, B).astype(np.int32))
     ref = paged_attention(q1, kp, vp, tbl, ctx, kernel_mode="reference")
-    pal = paged_attention(q1, kp, vp, tbl, ctx, kernel_mode="pallas_interpret")
+    pal = paged_attention(q1, kp, vp, tbl, ctx, kernel_mode=pallas_mode)
     err = float(jnp.abs(ref - pal).max())
     us = _timeit(lambda *a: paged_attention(*a, kernel_mode="reference"), q1, kp, vp, tbl, ctx,
                  label="kernel:paged_attention")
@@ -107,7 +115,7 @@ def _run_benches(quick: bool, profile: bool = False):
     w = jnp.asarray(rng.uniform(0.8, 0.999, (Bh, H, Ts, N)), jnp.float32)
     u = jnp.asarray(rng.standard_normal((H, N)) * 0.5, jnp.float32)
     oref, sref = rwkv6_scan(r, kk, vv, w, u, kernel_mode="reference")
-    opal, spal = rwkv6_scan(r, kk, vv, w, u, chunk=32, kernel_mode="pallas_interpret")
+    opal, spal = rwkv6_scan(r, kk, vv, w, u, chunk=32, kernel_mode=pallas_mode)
     err = float(jnp.abs(oref - opal).max())
     us = _timeit(lambda *a: rwkv6_scan(*a, kernel_mode="reference")[0], r, kk, vv, w, u,
                  label="kernel:rwkv6_scan")
@@ -123,7 +131,7 @@ def _run_benches(quick: bool, profile: bool = False):
     C = jnp.asarray(rng.standard_normal((Bh, Ts, Nst)) * 0.5, jnp.float32)
     Dp = jnp.asarray(rng.standard_normal((H,)), jnp.float32)
     yref, _ = mamba2_scan(x, dt, A, Bm, C, Dp, kernel_mode="reference")
-    ypal, _ = mamba2_scan(x, dt, A, Bm, C, Dp, chunk=32, kernel_mode="pallas_interpret")
+    ypal, _ = mamba2_scan(x, dt, A, Bm, C, Dp, chunk=32, kernel_mode=pallas_mode)
     err = float(jnp.abs(yref - ypal).max())
     us = _timeit(lambda *a: mamba2_scan(*a, kernel_mode="reference")[0], x, dt, A, Bm, C, Dp,
                  label="kernel:mamba2_scan")
@@ -134,7 +142,7 @@ def _run_benches(quick: bool, profile: bool = False):
     s = jnp.asarray(rng.integers(0, 64, 4096), jnp.int32)
     t = jnp.asarray(rng.integers(0, 50, 4096), jnp.int32)
     ref = tlb_sim(s, t, 64, 4, kernel_mode="reference")
-    pal = tlb_sim(s, t, 64, 4, block=512, kernel_mode="pallas_interpret")
+    pal = tlb_sim(s, t, 64, 4, block=512, kernel_mode=pallas_mode)
     err = float((np.asarray(ref) != np.asarray(pal)).mean())
     us = _timeit(lambda a, b: tlb_sim(a, b, 64, 4, kernel_mode="reference"), s, t,
                  label="kernel:tlb_sim")
@@ -150,7 +158,7 @@ def _run_benches(quick: bool, profile: bool = False):
     flags = jnp.asarray(flags)
     init = jnp.asarray(rng.integers(0, 40, (L, W)), jnp.int32)
     dref, fref = stack_scan(tags, flags, init, kernel_mode="reference")
-    dpal, fpal = stack_scan(tags, flags, init, kernel_mode="pallas_interpret")
+    dpal, fpal = stack_scan(tags, flags, init, kernel_mode=pallas_mode)
     err = float((np.asarray(dref) != np.asarray(dpal)).mean()
                 + (np.asarray(fref) != np.asarray(fpal)).mean())
     us = _timeit(lambda a, b, c: stack_scan(a, b, c, kernel_mode="reference")[0],
@@ -173,7 +181,7 @@ def _run_benches(quick: bool, profile: bool = False):
         jnp.zeros(n, jnp.float32),
     )
     ref = timeline_sim(*tl_inputs, tp, kernel_mode="reference")
-    pal = timeline_sim(*tl_inputs, tp, block=512, kernel_mode="pallas_interpret")
+    pal = timeline_sim(*tl_inputs, tp, block=512, kernel_mode=pallas_mode)
     err = float(sum((np.asarray(r) != np.asarray(p)).mean()
                     for r, p in zip(ref, pal)))
     us = _timeit(lambda *a: timeline_sim(*a, tp, kernel_mode="reference")[0],
@@ -347,7 +355,7 @@ def _timeline_bench(quick: bool):
     kw = dict(cfg=timeline.TimelineConfig(mshrs=8, tlb_ports=1, dram_banks=16),
               num_partitions=32, num_accelerators=4)
 
-    pallas_mode = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    pallas_mode = _pallas_mode()
 
     def timed(mode):
         m = measure(timeline.simulate_timeline, inter, ev, "sparta", lat,
@@ -444,7 +452,7 @@ def _timeline_batched_bench(quick: bool):
             num_accelerators=sp.num_accelerators, accel_ids=sp.accel_ids,
             kernel_mode="reference") for sp in specs]
 
-    pallas_mode = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    pallas_mode = _pallas_mode()
     m_loop, ref = timed(looped, "timeline_batched:looped")
     m_bat, bat = timed(
         lambda: timeline.sweep_timeline(specs, lat, kernel_mode="reference"),
@@ -543,7 +551,7 @@ def _system_batched_bench(quick: bool):
         m = measure(fn, reps=ENGINE_REPS, label=label)
         return m, m.result
 
-    pallas_mode = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    pallas_mode = _pallas_mode()
     m_loop, ref = timed(lambda: [simulate_system(tr.lines, c) for c in cfgs],
                         "system_batched:looped")
     m_bat, bat = timed(lambda: sweep_system(tr.lines, cfgs, kernel_mode="reference"),
@@ -666,4 +674,7 @@ if __name__ == "__main__":
     elif args.check:
         check_bench_history()
     else:
+        from repro.runtime.compile_cache import configure_compile_cache
+
+        configure_compile_cache()
         run(quick=args.quick, profile_dir=args.profile)

@@ -183,6 +183,9 @@ def main(argv=None) -> None:
         _explain_dispatch()
         return
 
+    from repro.runtime.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         fig2_pagewalk, fig4_tlb_sensitivity, fig5_contention, fig6_pagefault,
         fig7_miss_penalty, fig8_multiprog, fig9_accel_tlb, fig10_performance,

@@ -76,6 +76,7 @@ __all__ = [
     "DispatchDecision",
     "CalibrationStore",
     "CalibrationCorruptError",
+    "default_backend",
     "default_mode",
     "cold_start_mode",
     "stackdist_eligible",
@@ -114,7 +115,7 @@ class CalibrationCorruptError(RuntimeError):
     regenerated — delete the file deliberately to start cold."""
 
 
-def _default_backend() -> str:
+def default_backend() -> str:
     # Routed through repro.kernels.common's jax reference so tests that
     # monkeypatch `kernels.common.jax.default_backend` steer this layer too.
     from repro.kernels import common as _kc
@@ -125,7 +126,15 @@ def _default_backend() -> str:
 def default_mode() -> str:
     """The generic cold-start rule (per-op kernels, no engine context):
     the Mosaic kernel on TPU backends, the scan reference elsewhere."""
-    return "pallas" if _default_backend() == "tpu" else "reference"
+    return "pallas" if default_backend() == "tpu" else "reference"
+
+
+def kernel_candidates() -> List[str]:
+    """The Pallas backends ``"auto"`` may weigh here: the Mosaic kernel on
+    TPU backends, the interpreter on CPU backends (where it is the only way
+    to run the kernel body) and neither elsewhere."""
+    backend = default_backend()
+    return {"tpu": ["pallas"], "cpu": ["pallas_interpret"]}.get(backend, [])
 
 
 def stackdist_eligible(specs: Sequence) -> bool:
@@ -436,9 +445,7 @@ def decide_tlb(kernel_mode: str, specs: Sequence, *,
     candidates = ["reference"]
     if eligible:
         candidates.append("stackdist")
-    if _default_backend() == "tpu":
-        candidates.append("pallas")
-    candidates.append("pallas_interpret")
+    candidates += kernel_candidates()
     geoms = [sp.geometry for sp in specs]
     features = {
         "words_per_access": 3,
@@ -474,9 +481,7 @@ def decide_system(kernel_mode: str, cfgs: Sequence, *,
         state += 2 * ((cs + 1) * cw + (asets + 1) * aw
                       + (ms * c.num_partitions + 1) * mw)
     candidates = ["reference"]
-    if _default_backend() == "tpu":
-        candidates.append("pallas")
-    candidates.append("pallas_interpret")
+    candidates += kernel_candidates()
     return _decide(
         "sweep_system", kernel_mode,
         None if kernel_mode == "auto" else concrete,
@@ -499,9 +504,7 @@ def decide_timeline(kernel_mode: str, *, batch: int,
 
     concrete = resolve_timeline_mode(kernel_mode, batch=batch)
     candidates = ["reference"]
-    if _default_backend() == "tpu":
-        candidates.append("pallas")
-    candidates.append("pallas_interpret")
+    candidates += kernel_candidates()
     features: Dict[str, object] = {"words_per_access": 11}
     if state_bytes is not None:
         features["state_bytes"] = int(state_bytes)

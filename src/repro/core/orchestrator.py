@@ -32,10 +32,12 @@ three entry points — :func:`run_sweep_tlb`, :func:`run_sweep_system`,
   with bounded exponential backoff, then split in half (block-aligned), and
   finally the backend is downgraded ``pallas -> pallas_interpret ->
   reference`` (sticky for the rest of the run — and, via the checkpoint,
-  across restarts).  Every retry/halve/downgrade is recorded in the run's
-  ``meta["events"]`` so a run that silently fell back is visible in the
-  recorded figure/benchmark metadata.  Non-transient errors raise
-  immediately.
+  across restarts).  ``pallas_interpret`` is a rung only on CPU backends:
+  on a TPU the ladder goes ``pallas -> reference``, and a compile or
+  lowering error is never transient, so it surfaces instead of descending.
+  Every retry/halve/downgrade is recorded in the run's ``meta["events"]``
+  so a run that silently fell back is visible in the recorded
+  figure/benchmark metadata.  Non-transient errors raise immediately.
 
 * **Preemption.**  A :class:`repro.runtime.fault_tolerance.PreemptionHandler`
   (installed automatically when checkpointing is on) turns SIGTERM/SIGINT
@@ -77,7 +79,7 @@ from repro.core.sweep import (
 )
 from repro.core.timeline import TimelineResult, TimelineSpec, TimelineSweepStream
 from repro.core.tlbsim import SystemSimConfig
-from repro.kernels.common import resolve_mode
+from repro.kernels.common import interpret_allowed, resolve_mode
 from repro.runtime import telemetry
 from repro.runtime.fault_tolerance import (
     PreemptionHandler,
@@ -106,8 +108,17 @@ __all__ = [
 ]
 
 # Degradation ladder, fastest first; a run enters at its resolved mode and
-# only ever moves right.
+# only ever moves right (see ladder_from for the rungs a backend has).
 LADDER = ("pallas", "pallas_interpret", "reference")
+
+
+def ladder_from(mode: str) -> Tuple[str, ...]:
+    """The rungs a run entering at ``mode`` may descend through.  The
+    interpreter is a rung only where it may run at all (CPU backends), so a
+    TPU run can never fall back onto it."""
+    rungs = tuple(m for m in LADDER
+                  if m != "pallas_interpret" or interpret_allowed())
+    return rungs[rungs.index(mode):]
 
 CKPT_FORMAT = "repro-sweep-ckpt-v1"
 
@@ -191,7 +202,7 @@ class _ChunkRunner:
         # tracer disabled, it is plain accumulation.
         self.throughput: dict = {}
         start_mode = resolve_mode(start_mode)  # never "auto" past this point
-        self.ladder = LADDER[LADDER.index(start_mode):]
+        self.ladder = ladder_from(start_mode)
         self.rung = 0
         self.events: List[dict] = []
         self.chunks_committed = 0
@@ -272,7 +283,7 @@ class _ChunkRunner:
         if dd:
             blob_dec = dispatch.DispatchDecision.from_json(dd)
             if blob_dec.mode in LADDER:
-                self.ladder = LADDER[LADDER.index(blob_dec.mode):]
+                self.ladder = ladder_from(blob_dec.mode)
                 self.rung = 0
             self.decision = dataclasses.replace(
                 blob_dec, reason=blob_dec.reason + " (reused from checkpoint)",

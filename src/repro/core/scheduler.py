@@ -124,6 +124,8 @@ class ScheduleConfig:
     ``shards=0`` auto-sizes to ``2 * workers`` (over-decomposition keeps
     workers busy when shard runtimes are uneven).  ``executor="auto"``
     resolves to ``serial`` for one worker and ``thread`` otherwise.
+    ``"process"`` is for CPU backends only: one process per chip, so on a
+    TPU backend it raises before spawning anything.
     ``hold_s`` and ``on_shard_start`` are fault-injection seams: the hold
     sleeps each shard's *first* attempt after its lease is acquired (the CI
     smoke's window for SIGKILLing a worker mid-shard), and the hook fires
@@ -537,6 +539,13 @@ def _schedule(*, engine: str, payload: Callable[[int, int], dict],
     tracer = telemetry.get_tracer()
     n_shards = sched.resolve_shards(n_items)
     kind = sched.resolve_executor()
+    if kind == "process" and dispatch.default_backend() == "tpu":
+        # The backend decision above already touched JAX, so this process
+        # holds the chip; a spawned worker that needs it fails or hangs.
+        raise ValueError(
+            "executor='process' cannot run on a TPU backend: this process "
+            "holds the chip, so spawned workers could not reach it; use "
+            "executor='thread' or 'serial'")
     workers = 1 if kind == "serial" else max(1, sched.workers)
     ckpt_root = (pathlib.Path(run_cfg.checkpoint_dir)
                  if run_cfg.checkpoint_dir else None)

@@ -154,9 +154,10 @@ class BatchedTLBResult:
         return 1.0 - w.mean(axis=1)
 
 
-# Per-core VMEM is ~16 MB on current TPUs; cap the stacked scratch state
-# (2 x B x S x W x int32) well below that and chunk the batch when a sweep's
-# padded envelope would not fit.  Chunks still stream the trace once each.
+# The kernels' scoped VMEM is 16 MiB by default on v5e; cap the stacked
+# state (as the kernels lay it out) at half of that and chunk the batch when
+# a sweep's padded envelope would not fit.  Chunks still stream the trace
+# once each.
 _VMEM_STATE_BUDGET_BYTES = 8 * 1024 * 1024
 
 
@@ -279,13 +280,21 @@ def envelope_chunks(
     return chunks
 
 
+def _lru_state_words(sets: int, ways: int) -> int:
+    """int32 words of one config's (tags, last) pair as the Pallas kernels
+    hold it in VMEM: ``sets`` + 1 rows (the +1 is the parked row that
+    trace-padding accesses may use) in the lane-dense layout."""
+    from repro.kernels.tlb_sim.kernel import LANES, lane_layout
+
+    return 2 * lane_layout(sets + 1, ways).rows * LANES
+
+
 def _vmem_chunks(geoms: Sequence[Tuple[int, int]], *, block: int = 512) -> list:
     """TLB-sweep instantiation of :func:`envelope_chunks`: stacked LRU state
-    is 2 x (sets + 1) x ways int32 per config (+1 set row because
-    trace-padding accesses may get parked there) and each config streams
+    is :func:`_lru_state_words` per config and each config streams
     3 x block words (set/tag/hit)."""
     return envelope_chunks(
-        geoms, lambda g: 2 * (g[0] + 1) * g[1],
+        geoms, lambda g: _lru_state_words(*g),
         stream_words=3 * block, budget_bytes=_VMEM_STATE_BUDGET_BYTES)
 
 
@@ -463,14 +472,13 @@ def _system_vmem_chunks(
     dims: Sequence[Tuple[int, int, int, int, int, int]], *, block: int = 512
 ) -> list:
     """Joint-system instantiation of :func:`envelope_chunks`: per config the
-    stacked LRU state is ``2 x ((cs+1)*cw + (as+1)*aw + (ms+1)*mw)`` int32
-    words (tags + last-use for each of the three structures, each with one
-    extra set row because trace-tail padding accesses may get parked there)
-    and each config streams 7 x block words per grid step (six (set, tag) key
-    views in, one packed hit word out)."""
+    stacked LRU state is :func:`_lru_state_words` for each of the three
+    structures (cache, accel TLB, mem TLB) and each config streams
+    7 x block words per grid step (six (set, tag) key views in, one packed
+    hit word out)."""
     return envelope_chunks(
         dims,
-        lambda g: 2 * ((g[0] + 1) * g[1] + (g[2] + 1) * g[3] + (g[4] + 1) * g[5]),
+        lambda g: sum(_lru_state_words(g[k], g[k + 1]) for k in (0, 2, 4)),
         stream_words=7 * block, budget_bytes=_VMEM_STATE_BUDGET_BYTES)
 
 

@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -59,7 +58,7 @@ def hierarchical_psum(mesh: Mesh, *, intra_axes=("data",), inter_axis="pod"):
 def hierarchical_psum_shardmapped(mesh: Mesh, grads_spec):
     """shard_map-wrapped variant for replicated-gradient pytrees."""
     fn = hierarchical_psum(mesh)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(grads_spec,), out_specs=grads_spec,
-        check_rep=False,
+        check_vma=False,
     )

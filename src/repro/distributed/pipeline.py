@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -69,12 +68,12 @@ def pipeline_apply(
         # Stack per-stage outputs; only the last stage's slice is real.
         return outs[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),    # params sharded by stage; x replicated
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     outs = fn(stage_params, x)
     return outs[-1]

@@ -29,20 +29,16 @@ NEG_INF = -1e30
 
 def _paged_kernel(
     table_ref, ctx_ref,             # scalar-prefetch (SMEM)
-    q_ref, k_ref, v_ref,            # VMEM blocks
-    o_acc_ref, o_m_ref, o_l_ref,    # outputs (residuals)
-    m_scr, l_scr, acc_scr,
+    q_ref, k_ref, v_ref,            # VMEM blocks; q [1, Hkv, G, D]
+    o_acc_ref, o_m_ref, o_l_ref,    # residuals [1, Hkv, G, D], [1, Hkv, G] x2
+    m_scr, l_scr, acc_scr,          # [Hkv, G], [Hkv, G], [Hkv, G, D]
     *,
     sm_scale: float,
     page: int,
     pages: int,
-    hq: int,
-    hkv: int,
-    d: int,
 ):
     b = pl.program_id(0)
     p = pl.program_id(1)
-    g = hq // hkv
 
     @pl.when(p == 0)
     def _init():
@@ -56,7 +52,7 @@ def _paged_kernel(
 
     @pl.when(valid_page)
     def _compute():
-        q = q_ref[0].astype(jnp.float32).reshape(hkv, g, d)
+        q = q_ref[0].astype(jnp.float32)                 # [Hkv, G, D]
         k = k_ref[0].astype(jnp.float32)                 # [page, Hkv, D]
         v = v_ref[0].astype(jnp.float32)
         kt = jnp.transpose(k, (1, 0, 2))                 # [Hkv, page, D]
@@ -69,19 +65,18 @@ def _paged_kernel(
         mask = t_ids < ctx
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...].reshape(hkv, g)
+        m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
         alpha = jnp.exp(m_prev - m_new)
         pr = jnp.exp(s - m_new[..., None])
         pr = jnp.where(mask, pr, 0.0)
-        vt = jnp.where(mask.reshape(1, page, 1)[:, :, :], jnp.transpose(v, (1, 0, 2)), 0.0)
-        l_new = l_scr[...].reshape(hkv, g) * alpha + pr.sum(axis=-1)
-        acc = acc_scr[...].reshape(hkv, g, d) * alpha[..., None] + jax.lax.dot_general(
+        v_ids = page_start + jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
+        vt = jnp.where(v_ids < ctx, jnp.transpose(v, (1, 0, 2)), 0.0)
+        l_scr[...] = l_scr[...] * alpha + pr.sum(axis=-1)
+        acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
             pr, vt, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )
-        m_scr[...] = m_new.reshape(hq)
-        l_scr[...] = l_new.reshape(hq)
-        acc_scr[...] = acc.reshape(hq, d)
+        m_scr[...] = m_new
 
     @pl.when(p == pages - 1)
     def _finish():
@@ -104,23 +99,26 @@ def paged_attention_pallas(
     sm_scale: float | None = None,
     interpret: bool = False,
 ):
-    """Returns residuals (acc, m, l); normalise with ``ref.merge_partials``
-    (single-partition callers divide locally in ops.py)."""
+    """Returns residuals (acc [B, Hq, D], m [B, Hq], l [B, Hq]); normalise
+    with ``ref.merge_partials`` (single-partition callers divide locally in
+    ops.py).  Inside the kernel the query heads are grouped by KV head
+    ([Hkv, G] blocks), so no block or scratch needs an in-kernel reshape."""
     B, Hq, D = q.shape
     slots, page, Hkv, _ = k_pool.shape
+    G = Hq // Hkv
     pages = block_table.shape[1]
     scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
 
     grid = (B, pages)
     kernel = functools.partial(
         _paged_kernel,
-        sm_scale=scale, page=page, pages=pages, hq=Hq, hkv=Hkv, d=D,
+        sm_scale=scale, page=page, pages=pages,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, p, tbl, ctx: (b, 0, 0)),
+            pl.BlockSpec((1, Hkv, G, D), lambda b, p, tbl, ctx: (b, 0, 0, 0)),
             # THE SPARTA LOOKUP: the table value selects the pool slot the
             # DMA reads — translation programs the fetch.
             pl.BlockSpec(
@@ -133,23 +131,24 @@ def paged_attention_pallas(
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, p, tbl, ctx: (b, 0, 0)),
-            pl.BlockSpec((1, Hq), lambda b, p, tbl, ctx: (b, 0)),
-            pl.BlockSpec((1, Hq), lambda b, p, tbl, ctx: (b, 0)),
+            pl.BlockSpec((1, Hkv, G, D), lambda b, p, tbl, ctx: (b, 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, G), lambda b, p, tbl, ctx: (b, 0, 0)),
+            pl.BlockSpec((1, Hkv, G), lambda b, p, tbl, ctx: (b, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((Hq,), jnp.float32),
-            pltpu.VMEM((Hq,), jnp.float32),
-            pltpu.VMEM((Hq, D), jnp.float32),
+            pltpu.VMEM((Hkv, G), jnp.float32),
+            pltpu.VMEM((Hkv, G), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G), jnp.float32),
         ],
         interpret=interpret,
-    )(block_table, ctx_len, q, k_pool, v_pool)
+    )(block_table, ctx_len, q.reshape(B, Hkv, G, D), k_pool, v_pool)
+    return acc.reshape(B, Hq, D), m.reshape(B, Hq), l.reshape(B, Hq)

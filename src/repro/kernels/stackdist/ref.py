@@ -20,27 +20,32 @@ import jax.numpy as jnp
 
 
 def lru_stack_step(
-    stack: jnp.ndarray,      # int32 [..., W] MRU-first, -1 = empty
-    tag: jnp.ndarray,        # int32 [...]
-    seg_start: jnp.ndarray,  # bool  [...]
+    stack: jnp.ndarray,      # int32 [W, L] MRU-first along axis 0, -1 = empty
+    tag: jnp.ndarray,        # int32 [1, L]
+    seg_start: jnp.ndarray,  # bool  [1, L]
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Advance capped LRU stacks by one access per lane.
+    """Advance L capped LRU stacks (one per lane, ways down axis 0) by one
+    access each.
 
-    Returns ``(new_stack, depth)``.  The update is exact for any ways <= W:
-    the capped stack always equals the first W entries of the uncapped LRU
-    stack (recency only ever deepens, so truncated entries never resurface).
+    Returns ``(new_stack, depth [1, L])``.  The update is exact for any
+    ways <= W: the capped stack always equals the first W entries of the
+    uncapped LRU stack (recency only ever deepens, so truncated entries
+    never resurface).  Ways run down the sublanes and lanes across the TPU
+    lanes, so the Pallas kernel reads each access as one tile-aligned row;
+    the first matching slot is ``min(where(eq, iota, W))``, which Mosaic
+    lowers where an integer ``argmax`` is refused.
     """
-    W = stack.shape[-1]
-    stack = jnp.where(seg_start[..., None], -1, stack)
-    eq = stack == tag[..., None]
-    found = jnp.any(eq, axis=-1)
-    depth = jnp.where(found, jnp.argmax(eq, axis=-1).astype(jnp.int32), -1)
-    # Move the tag to the front: rotate slots [0, idx] right by one, where idx
+    W = stack.shape[0]
+    stack = jnp.where(seg_start, -1, stack)
+    way_ix = jax.lax.broadcasted_iota(jnp.int32, stack.shape, 0)
+    pos = jnp.min(jnp.where(stack == tag, way_ix, W), axis=0, keepdims=True)
+    found = pos < W
+    depth = jnp.where(found, pos, -1)
+    # Move the tag to the front: rotate slots [0, idx] down by one, where idx
     # is the tag's slot on a hit and the last slot (LRU eviction) on a miss.
-    idx = jnp.where(found, depth, W - 1)
-    shifted = jnp.concatenate([tag[..., None], stack[..., :-1]], axis=-1)
-    way_ix = jax.lax.broadcasted_iota(jnp.int32, stack.shape, stack.ndim - 1)
-    new = jnp.where(way_ix <= idx[..., None], shifted, stack)
+    idx = jnp.where(found, pos, W - 1)
+    shifted = jnp.where(way_ix == 0, tag, jnp.roll(stack, 1, axis=0))
+    new = jnp.where(way_ix <= idx, shifted, stack)
     return new, depth
 
 
@@ -54,8 +59,7 @@ def stack_scan_ref(
 
     def step(stack, inp):
         t, f = inp
-        new, depth = lru_stack_step(stack, t, f)
-        return new, depth
+        return lru_stack_step(stack, t[None], f[None])
 
-    final, depths = jax.lax.scan(step, init_stack, (tags.T, seg_flags.T))
-    return depths.T, final
+    final, depths = jax.lax.scan(step, init_stack.T, (tags.T, seg_flags.T))
+    return depths[:, 0].T, final.T

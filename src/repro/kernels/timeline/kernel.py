@@ -5,21 +5,23 @@ Same architecture as the ``tlb_sim`` trace kernel: the full queueing state
 bank free times — a few KB at any realistic configuration) stays **resident
 in VMEM scratch** for the entire trace.  TPU grids execute sequentially, so
 scratch persists across grid steps while each step streams one trace block
-(the eight per-access input columns) HBM->VMEM and writes the block's
+(the eight per-access input columns) HBM->SMEM and writes the block's
 (latency, overhead, done) columns back.
 
-The per-access update is :func:`repro.kernels.timeline.ref.timeline_step` —
-*shared* with the ``lax.scan`` oracle, so the two paths are bit-identical by
-construction.  Inside the kernel the state is read from scratch as whole
-(small) arrays, advanced functionally, and stored back; the access loop is
+The per-access update is
+:func:`repro.kernels.timeline.ref.timeline_step_dyn` — *shared* with the
+batched ``lax.scan`` reference, so the two paths are bit-identical by
+construction.  Inside the kernel each sim's state is read from scratch as
+whole (small) rows and planes, advanced functionally, and stored back; the
+per-access inputs and outputs are SMEM scalars.  The access loop is
 inherently serial (queue state carries a dependency) but each step is a
-handful of scalar gathers plus a ports-wide argmin.
+handful of one-hot selects and reductions plus a ports-wide min.
 
 ``timeline_sim_batched_pallas`` adds the **sim batch dimension** for the
 ``sweep_timeline`` engine (:mod:`repro.core.timeline`): B sims' queueing
 states are stacked as the leading VMEM scratch axis (padded to the batch's
 common resource envelope, poisoned per ``ref.timeline_init_state_batched``),
-each grid step fetches one trace block HBM->VMEM once for all sims, and the
+each grid step fetches one trace block once for all sims, and the
 per-sim configuration rides along as packed ``fparams``/``iparams`` rows
 consumed by the shared :func:`~repro.kernels.timeline.ref.timeline_step_dyn`.
 The sim axis is what gives this kernel something to amortize — a single
@@ -35,49 +37,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import smem_block
 from repro.kernels.timeline.ref import (
     PORT_POISON,
     TimelineParams,
-    timeline_step,
+    pack_params,
     timeline_step_dyn,
 )
 
 
-def _timeline_kernel(
-    a_ref, p_ref, bd_ref, bp_ref,   # int32 [BLK] ids
-    c_ref, th_ref, mh_ref,          # int32 [BLK] hit bits
-    pen_ref,                        # f32   [BLK] serialized penalty
-    lat_ref, ov_ref, done_ref,      # f32   [BLK] outputs
-    acc_scr, mshr_scr, cnt_scr, port_scr, bank_scr,  # persistent VMEM state
-    *,
-    block: int,
-    params: TimelineParams,
-):
-    i = pl.program_id(0)
 
-    @pl.when(i == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        mshr_scr[...] = jnp.zeros_like(mshr_scr)
-        cnt_scr[...] = jnp.zeros_like(cnt_scr)
-        port_scr[...] = jnp.zeros_like(port_scr)
-        bank_scr[...] = jnp.zeros_like(bank_scr)
+def _sim_step(b, j, streams, fp_ref, ip_ref, outs, scratch):
+    """Advance sim ``b`` by its access ``j``: the shared
+    :func:`timeline_step_dyn` on that sim's VMEM-resident state, with the
+    per-access inputs, parameters and outputs as SMEM scalars.  The
+    per-accelerator and per-bank vectors are read and written as ``[1, n]``
+    rows (a dynamic sublane offset), the 2-D queues as whole planes."""
+    acc_scr, mshr_scr, cnt_scr, port_scr, bank_scr = scratch
+    row = (pl.ds(b, 1), slice(None))
+    state = (acc_scr[row], mshr_scr[b], cnt_scr[row], port_scr[b],
+             bank_scr[row])
+    inp = tuple(ref[b, j] for ref in streams)
+    fp = [fp_ref[b, k] for k in range(fp_ref.shape[1])]
+    ip = [ip_ref[b, k] for k in range(ip_ref.shape[1])]
+    (acc, mshr, cnt, port, bank), ys = timeline_step_dyn(state, inp, fp, ip)
+    acc_scr[row] = acc
+    mshr_scr[b] = mshr
+    cnt_scr[row] = cnt
+    port_scr[b] = port
+    bank_scr[row] = bank
+    for ref, y in zip(outs, ys):
+        ref[b, j] = y
+
+
+def _access_loop(streams, fp_ref, ip_ref, outs, scratch, *, block: int,
+                 num_sims: int):
+    """Advance every sim through the grid step's ``block`` accesses."""
 
     def body(j, _):
-        state = (acc_scr[...], mshr_scr[...], cnt_scr[...],
-                 port_scr[...], bank_scr[...])
-        inp = (a_ref[j], p_ref[j], bd_ref[j], bp_ref[j],
-               c_ref[j], th_ref[j], mh_ref[j], pen_ref[j])
-        (acc, mshr, cnt, port, bank), (lat, ov, done) = timeline_step(
-            state, inp, params)
-        acc_scr[...] = acc
-        mshr_scr[...] = mshr
-        cnt_scr[...] = cnt
-        port_scr[...] = port
-        bank_scr[...] = bank
-        lat_ref[j] = lat
-        ov_ref[j] = ov
-        done_ref[j] = done
+        def per_sim(b, _):
+            _sim_step(b, j, streams, fp_ref, ip_ref, outs, scratch)
+            return 0
+
+        jax.lax.fori_loop(0, num_sims, per_sim, 0)
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
@@ -105,33 +107,21 @@ def _timeline_batched_kernel(
         # Port columns beyond each sim's own tlb_ports are poisoned as
         # always-busy so the earliest-free argmin never selects them (the
         # exact init of ref.timeline_init_state_batched).
-        col = jax.lax.broadcasted_iota(jnp.int32, port_scr.shape, 2)
-        port_scr[...] = jnp.where(col < ip_ref[:, 5][:, None, None],
-                                  jnp.float32(0.0), jnp.float32(PORT_POISON))
-        bank_scr[...] = jnp.zeros_like(bank_scr)
+        col = jax.lax.broadcasted_iota(jnp.int32, port_scr.shape[1:], 1)
 
-    def body(j, _):
-        def per_sim(b, _):
-            state = (acc_scr[b], mshr_scr[b], cnt_scr[b],
-                     port_scr[b], bank_scr[b])
-            inp = (a_ref[b, j], p_ref[b, j], bd_ref[b, j], bp_ref[b, j],
-                   c_ref[b, j], th_ref[b, j], mh_ref[b, j], pen_ref[b, j])
-            (acc, mshr, cnt, port, bank), (lat, ov, done) = timeline_step_dyn(
-                state, inp, fp_ref[b], ip_ref[b])
-            acc_scr[b] = acc
-            mshr_scr[b] = mshr
-            cnt_scr[b] = cnt
-            port_scr[b] = port
-            bank_scr[b] = bank
-            lat_ref[b, j] = lat
-            ov_ref[b, j] = ov
-            done_ref[b, j] = done
+        def poison(b, _):
+            port_scr[b] = jnp.where(col < ip_ref[b, 5], jnp.float32(0.0),
+                                    jnp.float32(PORT_POISON))
             return 0
 
-        jax.lax.fori_loop(0, num_sims, per_sim, 0)
-        return 0
+        jax.lax.fori_loop(0, num_sims, poison, 0)
+        bank_scr[...] = jnp.zeros_like(bank_scr)
 
-    jax.lax.fori_loop(0, block, body, 0)
+    _access_loop(
+        (a_ref, p_ref, bd_ref, bp_ref, c_ref, th_ref, mh_ref, pen_ref),
+        fp_ref, ip_ref, (lat_ref, ov_ref, done_ref),
+        (acc_scr, mshr_scr, cnt_scr, port_scr, bank_scr),
+        block=block, num_sims=num_sims)
 
 
 def _timeline_batched_carry_kernel(
@@ -163,28 +153,33 @@ def _timeline_batched_carry_kernel(
         port_scr[...] = port_in[...]
         bank_scr[...] = bank_in[...]
 
-    def body(j, _):
-        def per_sim(b, _):
-            state = (acc_scr[b], mshr_scr[b], cnt_scr[b],
-                     port_scr[b], bank_scr[b])
-            inp = (a_ref[b, j], p_ref[b, j], bd_ref[b, j], bp_ref[b, j],
-                   c_ref[b, j], th_ref[b, j], mh_ref[b, j], pen_ref[b, j])
-            (acc, mshr, cnt, port, bank), (lat, ov, done) = timeline_step_dyn(
-                state, inp, fp_ref[b], ip_ref[b])
-            acc_scr[b] = acc
-            mshr_scr[b] = mshr
-            cnt_scr[b] = cnt
-            port_scr[b] = port
-            bank_scr[b] = bank
-            lat_ref[b, j] = lat
-            ov_ref[b, j] = ov
-            done_ref[b, j] = done
-            return 0
+    _access_loop(
+        (a_ref, p_ref, bd_ref, bp_ref, c_ref, th_ref, mh_ref, pen_ref),
+        fp_ref, ip_ref, (lat_ref, ov_ref, done_ref),
+        (acc_scr, mshr_scr, cnt_scr, port_scr, bank_scr),
+        block=block, num_sims=num_sims)
 
-        jax.lax.fori_loop(0, num_sims, per_sim, 0)
-        return 0
 
-    jax.lax.fori_loop(0, block, body, 0)
+_STATE_DTYPES = (jnp.float32, jnp.float32, jnp.int32, jnp.float32,
+                 jnp.float32)
+
+
+def _specs(B: int, block: int):
+    """Per-access streams and the per-sim parameter rows live in SMEM (the
+    kernel reads and writes them one scalar at a time)."""
+    stream = pl.BlockSpec((B, block), lambda i: (0, i), memory_space=pltpu.SMEM)
+
+    def params(c):
+        return pl.BlockSpec((B, c), lambda i: (0, 0), memory_space=pltpu.SMEM)
+
+    return [stream] * 8 + [params(8), params(7)], [stream] * 3
+
+
+def _cast_inputs(accel, part, bank_data, bank_pte, cache_hit, tlb_hit,
+                 mem_hit, pen, fparams, iparams):
+    ints = (accel, part, bank_data, bank_pte, cache_hit, tlb_hit, mem_hit)
+    return (*(x.astype(jnp.int32) for x in ints), pen.astype(jnp.float32),
+            fparams.astype(jnp.float32), iparams.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -209,32 +204,26 @@ def timeline_sim_batched_pallas_carry(
     B, n = accel.shape
     block = min(block, n)
     assert n % block == 0, f"chunk length {n} must be a multiple of block {block}"
+    block = smem_block(block, B, 11)
     grid = (n // block,)
-    stream = pl.BlockSpec((B, block), lambda i: (0, i))
-    whole = lambda c: pl.BlockSpec((B, c), lambda i: (0, 0))
+    in_specs, out_specs = _specs(B, block)
 
-    def whole_nd(arr):
+    def whole(arr):
         return pl.BlockSpec(arr.shape, lambda i: (0,) * arr.ndim)
 
-    state_dtypes = (jnp.float32, jnp.float32, jnp.int32, jnp.float32,
-                    jnp.float32)
     outs = pl.pallas_call(
         functools.partial(
             _timeline_batched_carry_kernel, block=block, num_sims=B),
         grid=grid,
-        in_specs=[stream] * 8 + [whole(8), whole(7)]
-        + [whole_nd(s) for s in state],
-        out_specs=[stream] * 3 + [whole_nd(s) for s in state],
+        in_specs=in_specs + [whole(s) for s in state],
+        out_specs=out_specs + [whole(s) for s in state],
         out_shape=[jax.ShapeDtypeStruct((B, n), jnp.float32)] * 3
         + [jax.ShapeDtypeStruct(s.shape, d)
-           for s, d in zip(state, state_dtypes)],
+           for s, d in zip(state, _STATE_DTYPES)],
         interpret=interpret,
-    )(accel.astype(jnp.int32), part.astype(jnp.int32),
-      bank_data.astype(jnp.int32), bank_pte.astype(jnp.int32),
-      cache_hit.astype(jnp.int32), tlb_hit.astype(jnp.int32),
-      mem_hit.astype(jnp.int32), pen.astype(jnp.float32),
-      fparams.astype(jnp.float32), iparams.astype(jnp.int32),
-      *(s.astype(d) for s, d in zip(state, state_dtypes)))
+    )(*_cast_inputs(accel, part, bank_data, bank_pte, cache_hit, tlb_hit,
+                    mem_hit, pen, fparams, iparams),
+      *(s.astype(d) for s, d in zip(state, _STATE_DTYPES)))
     return tuple(outs[:3]), tuple(outs[3:])
 
 
@@ -258,22 +247,22 @@ def timeline_sim_batched_pallas(
 ):
     """B-sim batched timeline simulation: every sim's queueing state is
     stacked on the leading VMEM scratch axis and each grid step streams one
-    trace block (all sims' per-access columns) HBM->VMEM once.  Returns
+    trace block (all sims' per-access columns) once.  Returns
     (latency, overhead, done), each f32 [B, N]; per sim bit-identical to
-    :func:`timeline_sim_pallas` / the scan reference on that sim's own
-    configuration (they all run one shared step)."""
+    the scan reference on that sim's own configuration (they all run one
+    shared step)."""
     B, n = accel.shape
     A, M, P, T, D = envelope
     block = min(block, n)
     assert n % block == 0, f"trace length {n} must be a multiple of block {block}"
+    block = smem_block(block, B, 11)
     grid = (n // block,)
-    stream = pl.BlockSpec((B, block), lambda i: (0, i))
-    whole = lambda c: pl.BlockSpec((B, c), lambda i: (0, 0))
+    in_specs, out_specs = _specs(B, block)
     outs = pl.pallas_call(
         functools.partial(_timeline_batched_kernel, block=block, num_sims=B),
         grid=grid,
-        in_specs=[stream] * 8 + [whole(8), whole(7)],
-        out_specs=[stream] * 3,
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((B, n), jnp.float32)] * 3,
         scratch_shapes=[
             pltpu.VMEM((B, A), jnp.float32),
@@ -283,11 +272,8 @@ def timeline_sim_batched_pallas(
             pltpu.VMEM((B, D), jnp.float32),
         ],
         interpret=interpret,
-    )(accel.astype(jnp.int32), part.astype(jnp.int32),
-      bank_data.astype(jnp.int32), bank_pte.astype(jnp.int32),
-      cache_hit.astype(jnp.int32), tlb_hit.astype(jnp.int32),
-      mem_hit.astype(jnp.int32), pen.astype(jnp.float32),
-      fparams.astype(jnp.float32), iparams.astype(jnp.int32))
+    )(*_cast_inputs(accel, part, bank_data, bank_pte, cache_hit, tlb_hit,
+                    mem_hit, pen, fparams, iparams))
     return tuple(outs)
 
 
@@ -306,30 +292,16 @@ def timeline_sim_pallas(
     block: int = 512,
     interpret: bool = False,
 ):
-    """Returns (latency, overhead, done), each f32 [N]."""
-    n = accel.shape[0]
-    block = min(block, n)
-    assert n % block == 0, f"trace length {n} must be a multiple of block {block}"
-    grid = (n // block,)
-    stream = pl.BlockSpec((block,), lambda i: (i,))
-    A = params.num_accels
-    outs = pl.pallas_call(
-        functools.partial(_timeline_kernel, block=block, params=params),
-        grid=grid,
-        in_specs=[stream] * 8,
-        out_specs=[stream] * 3,
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.float32)] * 3,
-        scratch_shapes=[
-            pltpu.VMEM((A,), jnp.float32),
-            pltpu.VMEM((A, max(params.mshrs, 1)), jnp.float32),
-            pltpu.VMEM((A,), jnp.int32),
-            pltpu.VMEM((max(params.num_partitions, 1), max(params.tlb_ports, 1)),
-                       jnp.float32),
-            pltpu.VMEM((max(params.dram_banks, 1),), jnp.float32),
-        ],
-        interpret=interpret,
-    )(accel.astype(jnp.int32), part.astype(jnp.int32),
-      bank_data.astype(jnp.int32), bank_pte.astype(jnp.int32),
-      cache_hit.astype(jnp.int32), tlb_hit.astype(jnp.int32),
-      mem_hit.astype(jnp.int32), pen.astype(jnp.float32))
-    return tuple(outs)
+    """One sim with static parameters: the batched kernel on a batch of one
+    (its parameters packed as data).  Returns (latency, overhead, done),
+    each f32 [N]."""
+    fp, ip = pack_params(params)
+    envelope = (params.num_accels, max(params.mshrs, 1),
+                max(params.num_partitions, 1), max(params.tlb_ports, 1),
+                max(params.dram_banks, 1))
+    outs = timeline_sim_batched_pallas(
+        *(x[None] for x in (accel, part, bank_data, bank_pte, cache_hit,
+                            tlb_hit, mem_hit, pen)),
+        jnp.asarray(fp)[None], jnp.asarray(ip)[None], envelope,
+        block=block, interpret=interpret)
+    return tuple(o[0] for o in outs)

@@ -264,12 +264,34 @@ def _masked_set(arr, mask, value):
     return jnp.where(mask, value, arr)
 
 
+def _masked_get(arr, mask):
+    """Dense equivalent of ``arr[idx]`` (``mask`` one-hot at idx): the max
+    over the masked-in element and the dtype's lowest value, which returns
+    that element bit-exactly.  A gather from a value at a traced index is a
+    dynamic slice, which Mosaic cannot lower; this form compiles there and
+    vmaps into plain selects."""
+    low = (-jnp.inf if jnp.issubdtype(arr.dtype, jnp.floating)
+           else jnp.iinfo(arr.dtype).min)
+    return jnp.max(jnp.where(mask, arr, jnp.asarray(low, arr.dtype)))
+
+
+def _ix(arr, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, arr.shape, axis)
+
+
 def timeline_step_dyn(state, inp, fp, ip):
     """One sim's :func:`timeline_step` with traced per-sim parameters and
     envelope-padded state.  Shared by the batched ``lax.scan`` reference
     (vmapped over sims) and the batched Pallas kernel (fori over sims), so
     those two paths are bit-identical by construction — and each sim is
-    bit-identical to the static-param oracle on its own configuration."""
+    bit-identical to the static-param oracle on its own configuration.
+
+    Every read and write at a traced index goes through a one-hot mask
+    (:func:`_masked_get` / :func:`_masked_set`), so the step holds no gather
+    or scatter.  The per-accelerator and per-bank vectors may carry a
+    leading unit axis (the kernel keeps them as ``[1, n]`` rows); their
+    index runs along the last axis.  ``fp`` / ``ip`` may be arrays or
+    sequences of scalars."""
     acc_next, mshr_ring, mshr_cnt, port_free, bank_free = state
     a, part, bank_d, bank_p, c, th, mh, pen = inp
     l_cache, l_tlb, l_dram, t_net = fp[0], fp[1], fp[2], fp[3]
@@ -278,40 +300,43 @@ def timeline_step_dyn(state, inp, fp, ip):
     mshrs, ports, banks = ip[3], ip[5], ip[6]
     zero = jnp.float32(0.0)
     c_hit = c != 0
-    nominal = acc_next[a]
-
-    accel_ix = jax.lax.iota(jnp.int32, acc_next.shape[0])
-    bank_ix = jax.lax.iota(jnp.int32, bank_free.shape[0])
+    accel_mask = _ix(acc_next, acc_next.ndim - 1) == a
+    bank_ix = _ix(bank_free, bank_free.ndim - 1)
+    nominal = _masked_get(acc_next, accel_mask)
 
     # --- MSHR admission (slot ids never reach padded columns) ---------------
-    slot = mshr_cnt[a] % jnp.maximum(mshrs, 1)
-    w_mshr = jnp.maximum(mshr_ring[a, slot] - nominal, zero)
+    slot = _masked_get(mshr_cnt, accel_mask) % jnp.maximum(mshrs, 1)
+    mshr_mask = (_ix(mshr_ring, 0) == a) & (_ix(mshr_ring, 1) == slot)
+    w_mshr = jnp.maximum(_masked_get(mshr_ring, mshr_mask) - nominal, zero)
     use_mshr = (~c_hit) & (mshrs > 0)
     issue = nominal + jnp.where(use_mshr, w_mshr, zero)
 
     t0 = issue + l_cache
 
     # --- SPARTA port queue (poisoned columns lose every argmin) -------------
+    # The earliest-free port of the partition's row, first index on ties
+    # (argmin's rule); its free time is the row minimum itself.
     arr = t0 + t_net
-    row = port_free[part]
-    pslot = jnp.argmin(row)
-    w_port = jnp.where(ports > 0, jnp.maximum(row[pslot] - arr, zero), zero)
+    in_row = _ix(port_free, 0) == part
+    row_min = jnp.min(jnp.where(in_row, port_free, jnp.float32(jnp.inf)))
+    col = _ix(port_free, 1)
+    pslot = jnp.min(jnp.where(in_row & (port_free == row_min), col,
+                              port_free.shape[1]))
+    w_port = jnp.where(ports > 0, jnp.maximum(row_min - arr, zero), zero)
     do_port = memtlb & (~c_hit) & (ports > 0)
-    port_mask = (
-        (jax.lax.broadcasted_iota(jnp.int32, port_free.shape, 0) == part)
-        & (jax.lax.broadcasted_iota(jnp.int32, port_free.shape, 1) == pslot))
-    port_free = _masked_set(port_free, port_mask & do_port,
+    port_free = _masked_set(port_free, in_row & (col == pslot) & do_port,
                             arr + w_port + tlb_occ)
     probe_done = arr + w_port + l_tlb
 
     # --- translation-path DRAM reference (conv walk / SPARTA PTE read) ------
     walk_arr = t0 + l_tlb + t_net
     trans_arr = jnp.where(serial, walk_arr, probe_done)
-    w_tr = jnp.where(banks > 0,
-                     jnp.maximum(bank_free[bank_p] - trans_arr, zero), zero)
+    pte_mask = bank_ix == bank_p
+    w_tr = jnp.where(banks > 0, jnp.maximum(
+        _masked_get(bank_free, pte_mask) - trans_arr, zero), zero)
     do_tr = (~c_hit) & (banks > 0) & jnp.where(
         serial, th == 0, memtlb & (mh == 0))
-    bank_free = _masked_set(bank_free, (bank_ix == bank_p) & do_tr,
+    bank_free = _masked_set(bank_free, pte_mask & do_tr,
                             trans_arr + w_tr + dram_occ)
 
     walk = walk2 + w_tr + l_dram
@@ -323,10 +348,10 @@ def timeline_step_dyn(state, inp, fp, ip):
     pen_eff = jnp.where(serial | memtlb, zero, pen)
 
     # --- data DRAM access (all designs) -------------------------------------
-    w_data = jnp.where(banks > 0,
-                       jnp.maximum(bank_free[bank_d] - data_arr, zero), zero)
-    bank_free = _masked_set(bank_free,
-                            (bank_ix == bank_d) & (~c_hit) & (banks > 0),
+    data_mask = bank_ix == bank_d
+    w_data = jnp.where(banks > 0, jnp.maximum(
+        _masked_get(bank_free, data_mask) - data_arr, zero), zero)
+    bank_free = _masked_set(bank_free, data_mask & (~c_hit) & (banks > 0),
                             data_arr + w_data + dram_occ + pen_eff)
 
     lat_conv = l_cache + trans_conv + t_net + w_data + l_dram + t_net
@@ -339,12 +364,9 @@ def timeline_step_dyn(state, inp, fp, ip):
     done = issue + latency
 
     # --- state updates -------------------------------------------------------
-    mshr_mask = (
-        (jax.lax.broadcasted_iota(jnp.int32, mshr_ring.shape, 0) == a)
-        & (jax.lax.broadcasted_iota(jnp.int32, mshr_ring.shape, 1) == slot))
     mshr_ring = _masked_set(mshr_ring, mshr_mask & use_mshr, done)
-    mshr_cnt = mshr_cnt + jnp.where((accel_ix == a) & use_mshr, 1, 0)
-    acc_next = _masked_set(acc_next, accel_ix == a, issue + issue_iv)
+    mshr_cnt = mshr_cnt + jnp.where(accel_mask & use_mshr, 1, 0)
+    acc_next = _masked_set(acc_next, accel_mask, issue + issue_iv)
     return (acc_next, mshr_ring, mshr_cnt, port_free, bank_free), (
         latency, overhead, done)
 

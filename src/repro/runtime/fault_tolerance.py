@@ -116,14 +116,18 @@ class PreemptionHandler:
         self._previous = {}
 
 
-# XLA surfaces runtime faults as XlaRuntimeError (a RuntimeError subclass)
-# whose message starts with an absl status code.  These codes are the
-# machine-transient ones (device OOM, preempted backend, flaky transport);
-# INVALID_ARGUMENT / compile-time failures are NOT here on purpose — they are
-# deterministic and retrying them just burns the budget before surfacing.
+# XLA surfaces runtime faults as JaxRuntimeError whose message starts with
+# an absl status code.  These codes are the machine-transient ones (device
+# OOM, preempted backend, flaky transport).  INVALID_ARGUMENT, INTERNAL and
+# UNKNOWN are NOT here on purpose: they are deterministic or unexplained,
+# and a Mosaic compile failure surfaces as INTERNAL.
 _TRANSIENT_STATUS = ("RESOURCE_EXHAUSTED", "DEADLINE_EXCEEDED", "UNAVAILABLE",
-                     "ABORTED", "CANCELLED", "INTERNAL", "UNKNOWN",
-                     "out of memory", "OOM")
+                     "ABORTED", "CANCELLED", "out of memory", "OOM")
+
+# A compile or lowering failure is deterministic whatever status it carries
+# (a kernel over its SMEM/VMEM budget is RESOURCE_EXHAUSTED at compile time):
+# retrying it, halving the chunk or switching backend only hides it.
+_COMPILE_MARKERS = ("compil", "mosaic", "lowering")
 
 # OSError is mostly deterministic (missing file, bad permissions, dir-vs-file,
 # full disk): retrying those just replays the failure — and, worse, walks a
@@ -141,31 +145,26 @@ def is_transient(exc: BaseException) -> bool:
     """Is this exception a transient runtime fault worth retrying?
 
     Policy: deterministic program bugs (ValueError, TypeError, KeyError,
-    AssertionError, ...) are never transient.  XLA runtime errors are
-    transient only for the retryable status codes above — this applies to
-    any RuntimeError carrying one of those markers, so old jax without
-    ``jax.errors.JaxRuntimeError`` still classifies; a RuntimeError without
-    one is a program bug and surfaces immediately.  OS-level errors are
-    transient only for MemoryError/TimeoutError/ConnectionError and the
-    "try again" errnos in :data:`_TRANSIENT_ERRNOS`; deterministic
-    filesystem failures (FileNotFoundError, PermissionError, ENOSPC, ...)
-    are not retried.
+    AssertionError, NotImplementedError, ...) are never transient, and
+    neither is any compile or lowering error (Mosaic, the XLA compiler).
+    A ``JaxRuntimeError`` is transient only for the retryable status codes
+    above; any other RuntimeError is a program bug and surfaces
+    immediately.  OS-level errors are transient only for
+    MemoryError/TimeoutError/ConnectionError and the "try again" errnos in
+    :data:`_TRANSIENT_ERRNOS`; deterministic filesystem failures
+    (FileNotFoundError, PermissionError, ENOSPC, ...) are not retried.
     """
-    try:
-        from jax.errors import JaxRuntimeError
-    except Exception:  # pragma: no cover - ancient jax
-        JaxRuntimeError = ()
-    if JaxRuntimeError and isinstance(exc, JaxRuntimeError):
+    from jax.errors import JaxRuntimeError
+
+    if isinstance(exc, JaxRuntimeError):
         msg = str(exc)
+        if any(m in msg.lower() for m in _COMPILE_MARKERS):
+            return False
         return any(code in msg for code in _TRANSIENT_STATUS)
     if isinstance(exc, (MemoryError, TimeoutError, ConnectionError)):
         return True
     if isinstance(exc, OSError):
         return exc.errno in _TRANSIENT_ERRNOS
-    if isinstance(exc, RuntimeError) and not isinstance(
-            exc, (NotImplementedError, RecursionError)):
-        msg = str(exc)
-        return any(code in msg for code in _TRANSIENT_STATUS)
     return False
 
 

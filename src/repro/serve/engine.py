@@ -32,13 +32,22 @@ class Request:
     max_new_tokens: int
     seq_id: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
+    # Top-1 minus top-2 logit of each greedy pick: how close the choice was
+    # to a tie (two numerically different backends may only disagree there).
+    margins: List[float] = dataclasses.field(default_factory=list)
     done: bool = False
+
+
+def greedy(logits: jnp.ndarray):
+    """Greedy pick over the last axis: (token ids, top-1 minus top-2 logit)."""
+    top2 = jax.lax.top_k(logits.astype(jnp.float32), 2)[0]
+    return jnp.argmax(logits, axis=-1), top2[..., 0] - top2[..., 1]
 
 
 class SpartaEngine:
     def __init__(self, cfg: ModelConfig, params, *, num_partitions: int = 4,
                  slots_per_partition: int = 64, max_batch: int = 4,
-                 kernel_mode: str = "reference"):
+                 kernel_mode: str = "auto"):
         self.cfg = cfg
         self.params = params
         self.kernel_mode = kernel_mode
@@ -57,10 +66,13 @@ class SpartaEngine:
         self.active: List[Request] = []
         self.finished: Dict[int, Request] = {}
         self._next_rid = 0
-        self._decode = jax.jit(
-            lambda p, tok, kp, vp, tbl, ctx: tfm.decode_step(
-                p, tok, cfg, kp, vp, tbl, ctx, kernel_mode=kernel_mode),
-        )
+
+        def decode(p, tok, kp, vp, tbl, ctx):
+            logits, kp, vp = tfm.decode_step(
+                p, tok, cfg, kp, vp, tbl, ctx, kernel_mode=kernel_mode)
+            return greedy(logits), kp, vp
+
+        self._decode = jax.jit(decode)
 
     # -- request API ---------------------------------------------------------
 
@@ -99,8 +111,9 @@ class SpartaEngine:
             g = self._global_slot(ev["partition"], ev["slot"])
             self.k_pool = self.k_pool.at[:, g].set(kpages[:, 0, ev["lp"]].astype(self.k_pool.dtype))
             self.v_pool = self.v_pool.at[:, g].set(vpages[:, 0, ev["lp"]].astype(self.v_pool.dtype))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        req.generated.append(nxt)
+        nxt, margin = greedy(logits[0, -1])
+        req.generated.append(int(nxt))
+        req.margins.append(float(margin))
 
     def _apply_events(self, events: List[dict]) -> None:
         """Apply CoW copies (old slot -> new slot, same partition)."""
@@ -131,13 +144,14 @@ class SpartaEngine:
         ctx = self.kv.context_lengths(seqs)
         last = np.array([ (r.prompt + r.generated)[-1] for r in self.active], np.int32)
 
-        logits, self.k_pool, self.v_pool = self._decode(
+        (nxt, margin), self.k_pool, self.v_pool = self._decode(
             self.params, jnp.asarray(last), self.k_pool, self.v_pool,
             jnp.asarray(table), jnp.asarray(ctx),
         )
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        nxt, margin = np.asarray(nxt), np.asarray(margin)
         for i, req in enumerate(self.active):
             req.generated.append(int(nxt[i]))
+            req.margins.append(float(margin[i]))
             if len(req.generated) >= req.max_new_tokens:
                 req.done = True
         for req in [r for r in self.active if r.done]:

@@ -9,7 +9,8 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mamba2_scan import mamba2_scan
 from repro.kernels.paged_attention import merge_partials, paged_attention
 from repro.kernels.rwkv6_scan import rwkv6_scan
-from repro.kernels.tlb_sim import tlb_sim, tlb_sim_batched
+from repro.kernels.stackdist import stack_scan
+from repro.kernels.tlb_sim import tlb_sim, tlb_sim_batched, tlb_sim_batched_carry
 from repro.models.flash_ref import flash_attention_jnp
 
 
@@ -117,7 +118,11 @@ def test_mamba2_chunked_vs_exact(rng, B, H, T, P, N, chunk):
     np.testing.assert_allclose(np.asarray(s_pal), np.asarray(s_ref), atol=5e-4)
 
 
-@pytest.mark.parametrize("TS,W,N,blk", [(16, 4, 1024, 256), (64, 4, 2048, 512), (8, 2, 512, 128)])
+@pytest.mark.parametrize("TS,W,N,blk", [
+    (16, 4, 1024, 256), (64, 4, 2048, 512), (8, 2, 512, 128),
+    (5, 3, 512, 128),      # ways padded to a power of two in the lane layout
+    (3, 256, 1024, 256),   # a set wider than the 128 lanes
+])
 def test_tlb_sim_kernel_bit_exact(rng, TS, W, N, blk):
     s = jnp.asarray(rng.integers(0, TS, N), jnp.int32)
     t = jnp.asarray(rng.integers(0, 50, N), jnp.int32)
@@ -129,6 +134,7 @@ def test_tlb_sim_kernel_bit_exact(rng, TS, W, N, blk):
 @pytest.mark.parametrize("TS,W,N,blk,valid", [
     (16, 4, 1024, 256, (4, 2, 1)),    # heterogeneous associativity
     (32, 4, 512, 128, (4, 4, 4, 3)),
+    (9, 6, 512, 128, (6, 5)),         # envelope ways no power of two
 ])
 def test_tlb_sim_batched_kernel_bit_exact(rng, TS, W, N, blk, valid):
     B = len(valid)
@@ -141,3 +147,58 @@ def test_tlb_sim_batched_kernel_bit_exact(rng, TS, W, N, blk, valid):
     for b in range(B):
         one = tlb_sim(s[b], t[b], TS, valid[b], kernel_mode="reference")
         assert (np.asarray(ref[b]) == np.asarray(one)).all()
+
+
+@pytest.mark.parametrize("TS,W,valid", [(17, 4, (4, 2)), (4, 256, (256, 200))])
+def test_tlb_sim_carry_kernel_bit_exact_across_chunks(rng, TS, W, valid):
+    """The carried state round-trips through the kernel's lane-dense layout
+    between chunks, including a ragged (padded) final chunk."""
+    from repro.core.tlbsim import padded_tlb_state
+
+    B, N = len(valid), 700
+    s = jnp.asarray(rng.integers(0, TS - 1, (B, N)), jnp.int32)
+    t = jnp.asarray(rng.integers(0, 300, (B, N)), jnp.int32)
+    outs = {}
+    for mode in ("reference", "pallas_interpret"):
+        tags, last = padded_tlb_state(B, TS, W, valid)
+        hits = []
+        for lo, hi in ((0, 256), (256, 512), (512, N)):
+            h, tags, last = tlb_sim_batched_carry(
+                s[:, lo:hi], t[:, lo:hi], tags, last, lo, block=128,
+                kernel_mode=mode)
+            hits.append(np.asarray(h))
+        # The last set row is the parked row that block padding writes to.
+        outs[mode] = (np.concatenate(hits, 1), np.asarray(tags)[:, :-1],
+                      np.asarray(last)[:, :-1])
+    for a, b in zip(outs["reference"], outs["pallas_interpret"]):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("L,C,W", [(37, 100, 4), (130, 64, 12)])
+def test_stack_scan_kernel_bit_exact_ragged(rng, L, C, W):
+    """Lane counts off the 128-lane tile and access counts off the 8-row
+    block: the kernel pads both and the padding never shows."""
+    tags = jnp.asarray(rng.integers(0, 30, (L, C)), jnp.int32)
+    flags = rng.random((L, C)) < 0.05
+    flags[:, 0] = True
+    flags = jnp.asarray(flags)
+    init = jnp.asarray(rng.integers(-1, 30, (L, W)), jnp.int32)
+    d_ref, f_ref = stack_scan(tags, flags, init, kernel_mode="reference")
+    d_pal, f_pal = stack_scan(tags, flags, init, kernel_mode="pallas_interpret")
+    np.testing.assert_array_equal(np.asarray(d_ref), np.asarray(d_pal))
+    np.testing.assert_array_equal(np.asarray(f_ref), np.asarray(f_pal))
+
+
+def test_lane_layout_round_trips():
+    """The lane-dense state layout of the LRU kernels is a lossless view of
+    the [sets, ways] state the scans carry."""
+    from repro.kernels.tlb_sim.kernel import from_lanes, lane_layout, to_lanes
+
+    rng = np.random.default_rng(0)
+    for sets, ways in [(17, 4), (5, 3), (3, 256), (2049, 8), (1, 1)]:
+        lay = lane_layout(sets, ways)
+        assert lay.rows % 8 == 0 and lay.ways >= ways
+        x = jnp.asarray(rng.integers(0, 1 << 20, (2, sets, ways)), jnp.int32)
+        packed = to_lanes(x, lay, -2)
+        assert packed.shape == (2, lay.rows, 128)
+        np.testing.assert_array_equal(from_lanes(packed, lay, ways), x)

@@ -92,18 +92,20 @@ def test_heartbeat_straggler_detection():
 
 
 def test_retry_step_recovers():
+    from jax.errors import JaxRuntimeError
+
     calls = {"n": 0}
 
     def flaky(x):
         calls["n"] += 1
         if calls["n"] < 3:
-            raise RuntimeError("UNAVAILABLE: transient backend hiccup")
+            raise JaxRuntimeError("UNAVAILABLE: transient backend hiccup")
         return x + 1
 
     assert retry_step(flaky, 41, retries=3, base_delay_s=0) == 42
-    with pytest.raises(RuntimeError):
+    with pytest.raises(JaxRuntimeError):
         retry_step(lambda: (_ for _ in ()).throw(
-            RuntimeError("UNAVAILABLE: always")), retries=1, base_delay_s=0)
+            JaxRuntimeError("UNAVAILABLE: always")), retries=1, base_delay_s=0)
 
 
 def test_is_transient_classification():
@@ -117,9 +119,10 @@ def test_is_transient_classification():
     assert is_transient(ConnectionResetError(errno.ECONNRESET, "reset"))
     assert is_transient(InterruptedError(errno.EINTR, "interrupted"))
     assert is_transient(OSError(errno.EIO, "flaky disk"))
-    # XLA-status-coded runtime faults classify even as bare RuntimeError
-    # (old jax without jax.errors.JaxRuntimeError).
-    assert is_transient(RuntimeError("RESOURCE_EXHAUSTED: out of memory"))
+    # XLA runtime faults with a retryable status code.
+    from jax.errors import JaxRuntimeError
+
+    assert is_transient(JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory"))
     # Deterministic filesystem failures surface immediately: retrying a
     # missing file / bad permission / full disk just replays the failure.
     assert not is_transient(FileNotFoundError(errno.ENOENT, "gone"))
@@ -129,6 +132,7 @@ def test_is_transient_classification():
     # Program bugs are never transient.
     assert not is_transient(ValueError("bad config"))
     assert not is_transient(RuntimeError("refusing to overwrite history"))
+    assert not is_transient(RuntimeError("RESOURCE_EXHAUSTED: not from XLA"))
     assert not is_transient(NotImplementedError())
 
 

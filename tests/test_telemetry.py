@@ -22,6 +22,7 @@ The contract under test:
 import json
 import logging
 
+import jax
 import numpy as np
 import pytest
 
@@ -207,7 +208,7 @@ def test_ladder_events_carry_timestamps_and_elapsed():
     def hook(engine, lo, hi, mode, attempt):
         if failures["left"]:
             failures["left"] -= 1
-            raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected")
 
     cfg = SweepRunConfig(fault_hook=hook, backoff_base_s=0.0,
                          backoff_cap_s=0.0, chunk_accesses=1024)
