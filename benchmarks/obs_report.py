@@ -90,12 +90,18 @@ def phase_breakdown(recs: List[dict]) -> Dict[str, dict]:
     return dict(sorted(agg.items(), key=lambda kv: -kv[1]["total_s"]))
 
 
+def _chunk_spans(recs: List[dict]) -> List[dict]:
+    """The orchestrator's ``chunk`` spans of chunk attempts that completed
+    (an attempt that raised carries an ``error`` attribute)."""
+    return [r for r in recs
+            if r.get("kind") == "span" and r.get("name") == "chunk"
+            and "error" not in r.get("attrs", {})]
+
+
 def engine_throughput(recs: List[dict]) -> Dict[Tuple[str, str], dict]:
     """(engine, mode) -> aggregate chunk throughput from ``chunk`` spans."""
     agg: Dict[Tuple[str, str], dict] = {}
-    for r in recs:
-        if r.get("kind") != "span" or r.get("name") != "chunk":
-            continue
+    for r in _chunk_spans(recs):
         a = r.get("attrs", {})
         key = (str(a.get("engine", "?")), str(a.get("mode", "?")))
         st = agg.setdefault(key, {"chunks": 0, "accesses": 0, "elapsed_s": 0.0})
@@ -112,9 +118,7 @@ def throughput_timeline(recs: List[dict]) -> List[dict]:
     """chunk-by-chunk rows, t_rel measured from the run_start record."""
     t0 = next((r["t_mono"] for r in recs if r.get("kind") == "run_start"), None)
     rows = []
-    for r in recs:
-        if r.get("kind") != "span" or r.get("name") != "chunk":
-            continue
+    for r in _chunk_spans(recs):
         a = r.get("attrs", {})
         rows.append({
             "t_rel_s": (round(r["t_mono"] - t0, 3)
@@ -158,9 +162,7 @@ def dispatch_table(recs: List[dict]) -> List[dict]:
     predictions with the rates the run actually achieved (from its ``chunk``
     spans, simulated accesses per second)."""
     achieved: Dict[Tuple[str, str, str], dict] = {}
-    for r in recs:
-        if r.get("kind") != "span" or r.get("name") != "chunk":
-            continue
+    for r in _chunk_spans(recs):
         a = r.get("attrs", {})
         key = (str(a.get("engine", "?")), str(a.get("name", "?")),
                str(a.get("mode", "?")))

@@ -342,10 +342,10 @@ class _ChunkRunner:
                  rec["mode"],
                  "".join(f" {k}={v}" for k, v in kw.items()))
 
-    def _note_chunk(self, lo: int, hi: int, mode: str, attempt: int,
+    def _note_chunk(self, span, lo: int, hi: int, mode: str,
                     dur_s: float) -> None:
         """Account a successful chunk attempt: per-mode throughput (always)
-        plus a telemetry chunk span (when a run is active)."""
+        plus the achieved rates on its telemetry ``chunk`` span."""
         n = int(hi - lo)
         agg = self.throughput.setdefault(
             mode, {"chunks": 0, "accesses": 0, "sim_accesses": 0,
@@ -354,19 +354,17 @@ class _ChunkRunner:
         agg["accesses"] += n
         agg["sim_accesses"] += n * self.batch
         agg["elapsed_s"] += dur_s
-        telemetry.get_tracer().record_span(
-            "chunk", dur_s, engine=self.stream.engine, name=self.name,
-            lo=int(lo), hi=int(hi), mode=mode, attempt=attempt,
-            accesses=n, configs=self.batch,
-            accesses_per_s=round(n / dur_s, 1) if dur_s > 0 else None,
-            sim_accesses_per_s=(round(n * self.batch / dur_s, 1)
-                                if dur_s > 0 else None))
+        span.set(accesses=n, configs=self.batch,
+                 accesses_per_s=round(n / dur_s, 1) if dur_s > 0 else None,
+                 sim_accesses_per_s=(round(n * self.batch / dur_s, 1)
+                                     if dur_s > 0 else None))
 
     def _exec(self, lo: int, hi: int) -> None:
         """Run span [lo, hi) through retries -> halving -> downgrade."""
         delays = backoff_delays(
             self.cfg.max_retries, base_s=self.cfg.backoff_base_s,
             cap_s=self.cfg.backoff_cap_s, rng=self._rng)
+        tracer = telemetry.get_tracer()
         last_exc: Optional[Exception] = None
         for attempt in range(self.cfg.max_retries + 1):
             mode = self.ladder[self.rung]
@@ -379,9 +377,15 @@ class _ChunkRunner:
             # propagate, leaving the previous blob as the resume point.
             t0 = time.perf_counter()
             try:
-                if self.cfg.fault_hook is not None:
-                    self.cfg.fault_hook(self.stream.engine, lo, hi, mode, attempt)
-                outs = self.run_chunk(lo, hi, mode)
+                with tracer.span("chunk", lo=int(lo), hi=int(hi), mode=mode,
+                                 attempt=attempt) as span:
+                    span.set(engine=self.stream.engine, name=self.name)
+                    if self.cfg.fault_hook is not None:
+                        self.cfg.fault_hook(self.stream.engine, lo, hi, mode,
+                                            attempt)
+                    outs = self.run_chunk(lo, hi, mode)
+                    self._note_chunk(span, lo, hi, mode,
+                                     time.perf_counter() - t0)
             except Exception as exc:
                 if not is_transient(exc):
                     raise
@@ -392,8 +396,8 @@ class _ChunkRunner:
                 if attempt < self.cfg.max_retries:
                     time.sleep(delays[attempt])
                 continue
-            self._note_chunk(lo, hi, mode, attempt, time.perf_counter() - t0)
-            self._commit(lo, hi, outs)
+            with tracer.span("chunk.commit"):
+                self._commit(lo, hi, outs)
             return
         # Retries exhausted.  Halve if the span spans more than one block,
         # else (or eventually) take the next rung down the ladder.
@@ -529,26 +533,61 @@ def run_sweep_tlb(
     is not resumable (``meta["resumable"] = False``).
     """
     addrs = np.asarray(addrs)
-    store = dispatch.store_for(run.calibration_dir)
-    decision = dispatch.decide_tlb(
-        kernel_mode, specs, n_accesses=int(addrs.shape[0]), store=store)
-    dispatch.record_decision(decision, name=name)
-    mode = decision.mode
-    if mode == "stackdist":
-        # Monolithic, but still measured: the stackdist engine's achieved
-        # accesses/s lands in meta["throughput"] (and a single whole-trace
-        # "chunk" span in the run log) just like the streamed backends'.
-        n = int(addrs.shape[0])
+    n = int(addrs.shape[0])
+    tracer = telemetry.get_tracer()
+    with tracer.span("engine", engine=TLBSweepStream.engine, name=name,
+                     accesses=n, configs=len(specs)):
+        handler = None
+        try:
+            with tracer.span("engine.prepare"):
+                store = dispatch.store_for(run.calibration_dir)
+                decision = dispatch.decide_tlb(
+                    kernel_mode, specs, n_accesses=n, store=store)
+                dispatch.record_decision(decision, name=name)
+                if decision.mode != "stackdist":
+                    run, handler = _maybe_handler(run)
+                    stream = TLBSweepStream(specs, block=block)
+                    runner = _ChunkRunner(
+                        stream, n, ("hits",), (bool,),
+                        lambda lo, hi, m: (stream.run_chunk(
+                            addrs[lo:hi], kernel_mode=m),),
+                        decision.mode, run, name=name,
+                        trace_sha=_sha256_arrays(addrs), decision=decision)
+                    done = runner.try_resume()
+            if decision.mode == "stackdist":
+                return _run_stackdist(addrs, specs, decision, store,
+                                      warmup_frac=warmup_frac, block=block,
+                                      name=name)
+            meta = (runner.meta(completed_from_checkpoint=True) if done
+                    else runner.run())
+            with tracer.span("engine.finish"):
+                dispatch.observe(runner.decision, meta.get("throughput") or {},
+                                 store=store, name=name)
+                n0 = int(n * warmup_frac)
+                return BatchedTLBResult(hits=runner.bufs[0], n_warm=n - n0), meta
+        finally:
+            if handler is not None:
+                handler.uninstall()
+
+
+def _run_stackdist(addrs, specs, decision, store, *, warmup_frac, block,
+                   name) -> Tuple[BatchedTLBResult, dict]:
+    """The monolithic stackdist TLB sweep, still measured: its achieved
+    accesses/s lands in meta["throughput"] (and one whole-trace ``chunk``
+    span) like the streamed backends'."""
+    tracer = telemetry.get_tracer()
+    n, mode = int(addrs.shape[0]), decision.mode
+    with tracer.span("chunk", lo=0, hi=n, mode=mode, attempt=0) as span:
         t0 = time.perf_counter()
         res = sweep_tlb(addrs, specs, warmup_frac=warmup_frac,
                         kernel_mode=mode, block=block)
         dur = time.perf_counter() - t0
-        telemetry.get_tracer().record_span(
-            "chunk", dur, engine="sweep_tlb", name=name, lo=0, hi=n,
-            mode=mode, attempt=0, accesses=n, configs=len(specs),
-            accesses_per_s=round(n / dur, 1) if dur > 0 else None,
-            sim_accesses_per_s=(round(n * len(specs) / dur, 1)
-                                if dur > 0 else None))
+        span.set(engine="sweep_tlb", name=name, accesses=n,
+                 configs=len(specs),
+                 accesses_per_s=round(n / dur, 1) if dur > 0 else None,
+                 sim_accesses_per_s=(round(n * len(specs) / dur, 1)
+                                     if dur > 0 else None))
+    with tracer.span("engine.finish"):
         agg = {mode: {"chunks": 1, "accesses": n,
                       "sim_accesses": n * len(specs), "elapsed_s": dur}}
         throughput = _throughput_meta(agg)
@@ -559,25 +598,6 @@ def run_sweep_tlb(
                      "completed_from_checkpoint": False, "checkpoint": None,
                      "throughput": throughput,
                      "dispatch": decision.to_json()}
-
-    run, handler = _maybe_handler(run)
-    try:
-        stream = TLBSweepStream(specs, block=block)
-        n = int(addrs.shape[0])
-        runner = _ChunkRunner(
-            stream, n, ("hits",), (bool,),
-            lambda lo, hi, m: (stream.run_chunk(addrs[lo:hi], kernel_mode=m),),
-            mode, run, name=name, trace_sha=_sha256_arrays(addrs),
-            decision=decision)
-        done = runner.try_resume()
-        meta = runner.meta(completed_from_checkpoint=True) if done else runner.run()
-        dispatch.observe(runner.decision, meta.get("throughput") or {},
-                         store=store, name=name)
-        n0 = int(n * warmup_frac)
-        return BatchedTLBResult(hits=runner.bufs[0], n_warm=n - n0), meta
-    finally:
-        if handler is not None:
-            handler.uninstall()
 
 
 def run_sweep_system(
@@ -594,29 +614,37 @@ def run_sweep_system(
     ``(BatchedSystemEvents, meta)``, bit-identical to the monolithic
     engine."""
     lines = np.asarray(lines)
-    store = dispatch.store_for(run.calibration_dir)
-    decision = dispatch.decide_system(
-        kernel_mode, cfgs, n_accesses=int(lines.shape[0]), store=store)
-    dispatch.record_decision(decision, name=name)
-    run, handler = _maybe_handler(run)
-    try:
-        stream = SystemSweepStream(cfgs, block=block)
-        n = int(lines.shape[0])
-        runner = _ChunkRunner(
-            stream, n, ("cache_hit", "accel_tlb_hit", "mem_tlb_hit"),
-            (bool, bool, bool),
-            lambda lo, hi, m: stream.run_chunk(lines[lo:hi], kernel_mode=m),
-            decision.mode, run, name=name, trace_sha=_sha256_arrays(lines),
-            decision=decision)
-        done = runner.try_resume()
-        meta = runner.meta(completed_from_checkpoint=True) if done else runner.run()
-        dispatch.observe(runner.decision, meta.get("throughput") or {},
-                         store=store, name=name)
-        n0 = int(n * warmup_frac)
-        return BatchedSystemEvents(*runner.bufs, n_warm=n - n0), meta
-    finally:
-        if handler is not None:
-            handler.uninstall()
+    n = int(lines.shape[0])
+    tracer = telemetry.get_tracer()
+    with tracer.span("engine", engine=SystemSweepStream.engine, name=name,
+                     accesses=n, configs=len(cfgs)):
+        handler = None
+        try:
+            with tracer.span("engine.prepare"):
+                store = dispatch.store_for(run.calibration_dir)
+                decision = dispatch.decide_system(
+                    kernel_mode, cfgs, n_accesses=n, store=store)
+                dispatch.record_decision(decision, name=name)
+                run, handler = _maybe_handler(run)
+                stream = SystemSweepStream(cfgs, block=block)
+                runner = _ChunkRunner(
+                    stream, n, ("cache_hit", "accel_tlb_hit", "mem_tlb_hit"),
+                    (bool, bool, bool),
+                    lambda lo, hi, m: stream.run_chunk(lines[lo:hi],
+                                                       kernel_mode=m),
+                    decision.mode, run, name=name,
+                    trace_sha=_sha256_arrays(lines), decision=decision)
+                done = runner.try_resume()
+            meta = (runner.meta(completed_from_checkpoint=True) if done
+                    else runner.run())
+            with tracer.span("engine.finish"):
+                dispatch.observe(runner.decision, meta.get("throughput") or {},
+                                 store=store, name=name)
+                n0 = int(n * warmup_frac)
+                return BatchedSystemEvents(*runner.bufs, n_warm=n - n0), meta
+        finally:
+            if handler is not None:
+                handler.uninstall()
 
 
 def run_sweep_timeline(
@@ -630,27 +658,35 @@ def run_sweep_timeline(
 ) -> Tuple[List[TimelineResult], dict]:
     """Crash-safe :func:`repro.core.timeline.sweep_timeline`; returns
     ``(results, meta)``, bit-identical to the monolithic engine."""
-    store = dispatch.store_for(run.calibration_dir)
     n_acc = max((int(np.asarray(sp.lines).shape[0]) for sp in specs),
                 default=0) if specs else None
-    decision = dispatch.decide_timeline(
-        kernel_mode, batch=len(specs), n_accesses=n_acc, store=store)
-    dispatch.record_decision(decision, name=name)
-    run, handler = _maybe_handler(run)
-    try:
-        stream = TimelineSweepStream(specs, lat, block=block)
-        runner = _ChunkRunner(
-            stream, stream.n, ("latency", "overhead", "done"),
-            (np.float32, np.float32, np.float32),
-            lambda lo, hi, m: stream.run_chunk(lo, hi, kernel_mode=m),
-            decision.mode, run, name=name,
-            trace_sha=_sha256_arrays(*stream._stacked),
-            decision=decision)
-        done = runner.try_resume()
-        meta = runner.meta(completed_from_checkpoint=True) if done else runner.run()
-        dispatch.observe(runner.decision, meta.get("throughput") or {},
-                         store=store, name=name)
-        return stream.finalize(*runner.bufs), meta
-    finally:
-        if handler is not None:
-            handler.uninstall()
+    tracer = telemetry.get_tracer()
+    with tracer.span("engine", engine=TimelineSweepStream.engine, name=name,
+                     accesses=n_acc, configs=len(specs)):
+        handler = None
+        try:
+            with tracer.span("engine.prepare"):
+                store = dispatch.store_for(run.calibration_dir)
+                decision = dispatch.decide_timeline(
+                    kernel_mode, batch=len(specs), n_accesses=n_acc,
+                    store=store)
+                dispatch.record_decision(decision, name=name)
+                run, handler = _maybe_handler(run)
+                stream = TimelineSweepStream(specs, lat, block=block)
+                runner = _ChunkRunner(
+                    stream, stream.n, ("latency", "overhead", "done"),
+                    (np.float32, np.float32, np.float32),
+                    lambda lo, hi, m: stream.run_chunk(lo, hi, kernel_mode=m),
+                    decision.mode, run, name=name,
+                    trace_sha=_sha256_arrays(*stream._stacked),
+                    decision=decision)
+                done = runner.try_resume()
+            meta = (runner.meta(completed_from_checkpoint=True) if done
+                    else runner.run())
+            with tracer.span("engine.finish"):
+                dispatch.observe(runner.decision, meta.get("throughput") or {},
+                                 store=store, name=name)
+                return stream.finalize(*runner.bufs), meta
+        finally:
+            if handler is not None:
+                handler.uninstall()

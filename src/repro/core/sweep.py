@@ -88,8 +88,7 @@ def _note_envelope(stream) -> None:
     tr = telemetry.get_tracer()
     if not tr.active:
         return
-    state = stream.export_state()
-    state_bytes = int(sum(v.nbytes for k, v in state.items() if k != "now"))
+    state_bytes = int(sum(x.nbytes for st in stream._state for x in st))
     tr.event("vmem_envelope", engine=stream.engine,
              configs=stream.batch_size, groups=len(stream.groups),
              group_sizes=[len(g) for g in stream.groups],
@@ -645,21 +644,27 @@ class SystemSweepStream:
         returns (cache, accel_tlb, mem_tlb) hit bits, each bool
         [B, len(lines)].  Commit-on-success like :class:`TLBSweepStream`."""
         mode = resolve_system_mode(kernel_mode)
+        tracer = telemetry.get_tracer()
         lines = np.asarray(lines)
-        streams = [np.stack(rows) for rows in
-                   zip(*(_system_keys(lines, c) for c in self.cfgs))]
+        with tracer.span("chunk.keys"):
+            streams = [np.stack(rows) for rows in
+                       zip(*(_system_keys(lines, c) for c in self.cfgs))]
         n = lines.shape[0]
         from repro.kernels.system_sim import system_sim_batched_carry
 
         hits = [np.empty((len(self.cfgs), n), dtype=bool) for _ in range(3)]
         new_state = []
         for gi, g in enumerate(self.groups):
-            ys, st = system_sim_batched_carry(
-                *(jnp.asarray(s[g]) for s in streams),
-                jnp.asarray(self._flags[g]), self._state[gi], self.now,
-                block=self.block, kernel_mode=mode)
-            for h, y in zip(hits, ys):
-                h[g] = np.asarray(y)   # forces the computation (commit gate)
+            with tracer.span("chunk.upload"):
+                keys = [jnp.asarray(s[g]) for s in streams]
+                flags = jnp.asarray(self._flags[g])
+            with tracer.span("chunk.launch"):
+                ys, st = system_sim_batched_carry(
+                    *keys, flags, self._state[gi], self.now,
+                    block=self.block, kernel_mode=mode)
+            with tracer.span("chunk.pull"):
+                for h, y in zip(hits, ys):
+                    h[g] = np.asarray(y)   # forces the computation (commit gate)
             new_state.append(st)
         self._state = new_state
         self.now += n

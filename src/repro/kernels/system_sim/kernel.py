@@ -225,6 +225,7 @@ def system_sim_batched_pallas_carry(
         scratch_shapes=[pltpu.VMEM(x.shape, jnp.int32) for x in lanes],
         input_output_aliases={7 + k: 1 + k for k in range(6)},
         interpret=interpret,
+        name="system_sim_carry",
     )(c_set.astype(jnp.int32), c_tag.astype(jnp.int32),
       a_set.astype(jnp.int32), a_tag.astype(jnp.int32),
       m_set.astype(jnp.int32), m_tag.astype(jnp.int32),
@@ -271,6 +272,7 @@ def system_sim_batched_pallas(
         scratch_shapes=[pltpu.VMEM((num_cfgs, lay.rows, LANES), jnp.int32)
                         for lay in lays for _ in range(2)],
         interpret=interpret,
+        name="system_sim_batched",
     )(c_set.astype(jnp.int32), c_tag.astype(jnp.int32),
       a_set.astype(jnp.int32), a_tag.astype(jnp.int32),
       m_set.astype(jnp.int32), m_tag.astype(jnp.int32),

@@ -221,6 +221,7 @@ def timeline_sim_batched_pallas_carry(
         + [jax.ShapeDtypeStruct(s.shape, d)
            for s, d in zip(state, _STATE_DTYPES)],
         interpret=interpret,
+        name="timeline_carry",
     )(*_cast_inputs(accel, part, bank_data, bank_pte, cache_hit, tlb_hit,
                     mem_hit, pen, fparams, iparams),
       *(s.astype(d) for s, d in zip(state, _STATE_DTYPES)))
@@ -272,6 +273,7 @@ def timeline_sim_batched_pallas(
             pltpu.VMEM((B, D), jnp.float32),
         ],
         interpret=interpret,
+        name="timeline_batched",
     )(*_cast_inputs(accel, part, bank_data, bank_pte, cache_hit, tlb_hit,
                     mem_hit, pen, fparams, iparams))
     return tuple(outs)

@@ -184,6 +184,7 @@ def tlb_sim_pallas(
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, lay.rows, LANES), jnp.int32)] * 2,
         interpret=interpret,
+        name="tlb_sim",
     )(set_idx.astype(jnp.int32), tag.astype(jnp.int32))
     return hits.astype(bool)
 
@@ -306,6 +307,7 @@ def tlb_sim_batched_pallas_carry(
         scratch_shapes=[pltpu.VMEM(state.shape, jnp.int32)] * 2,
         input_output_aliases={2: 1, 3: 2},
         interpret=interpret,
+        name="tlb_sim_carry",
     )(set_idx.astype(jnp.int32), tag.astype(jnp.int32),
       to_lanes(tags, lay, _POISON_TAG), to_lanes(last, lay, _POISON_LAST),
       jnp.asarray(now0, jnp.int32).reshape(1, 1))
@@ -347,5 +349,6 @@ def tlb_sim_batched_pallas(
         scratch_shapes=[
             pltpu.VMEM((num_cfgs, lay.rows, LANES), jnp.int32)] * 2,
         interpret=interpret,
+        name="tlb_sim_batched",
     )(set_idx.astype(jnp.int32), tag.astype(jnp.int32))
     return hits.astype(bool)

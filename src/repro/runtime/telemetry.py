@@ -13,18 +13,26 @@ item needs).  This module is the one place all of that flows through:
   seconds and ``t_mono`` = ``time.perf_counter()``).  The first record
   stamps ``schema_version`` (:data:`SCHEMA_VERSION`) like BENCH_sweep.json
   rows do.
-* :class:`Span` — a context manager recording wall duration (and optionally
-  device-blocked time via :meth:`Span.block`, which routes through
-  :func:`repro.core.benchtime.block`); spans nest, with ``span_id`` /
-  ``parent_id`` linking the records.  :meth:`Tracer.record_span` logs a
-  span whose duration was measured externally (``benchtime.measure``).
+* :meth:`Tracer.span` — the program's one span API.  Every span, with or
+  without a run, opens a ``jax.profiler.TraceAnnotation`` named
+  ``repro.<name>`` carrying the attributes known at entry, so a profiler
+  trace holds the program's phases on the same clock as the device's
+  operations.  With a run active the span is also a :class:`Span`
+  recording wall duration into the JSONL log; spans nest, with ``span_id``
+  / ``parent_id`` linking the records, and ``set(**attrs)`` adds attributes
+  discovered inside the span to that record.  :meth:`Tracer.record_span`
+  logs a span whose duration was measured externally
+  (``benchtime.measure``).
 * :class:`Counter` / :class:`Gauge` — a per-run registry (simulated-access
   counts, VMEM state footprints, ...), aggregated into the ``run_end``
-  summary.
+  summary.  While a run is active, JAX's compilations are counted into
+  ``jax.lowerings`` and ``jax.backend_compiles``, each with a ``compile``
+  event naming the innermost open span.
 * :class:`Tracer` — the global instance (:func:`get_tracer`).  When no run
-  is active every call is a no-op returning shared null objects, so hot
-  loops can be instrumented unconditionally (tests/test_telemetry.py holds
-  the <2% overhead guard on a disabled-tracer ``run_sweep_tlb``).
+  is active a span only annotates the profiler (about a microsecond) and
+  every other call is a no-op returning shared null objects, so hot loops
+  can be instrumented unconditionally (tests/test_telemetry.py holds the
+  <2% overhead guard on a disabled-tracer ``run_sweep_tlb``).
 
 Lifecycle: :func:`run_scope` (or :func:`start_run`/:func:`end_run`) brackets
 one run; ``run_scope`` catches ``BaseException`` so a ``Preempted`` exit
@@ -32,9 +40,9 @@ still closes the log with an ``error`` on the ``run_end`` record.
 :meth:`Tracer.summary` is the in-memory aggregate the figure drivers stamp
 into their JSON as ``_telemetry`` (next to ``_device`` / ``_crash_safety``).
 
-Deliberately stdlib-only: ``benchtime`` (which imports jax) is pulled in
-lazily inside :meth:`Span.block`, so importing telemetry never costs a jax
-import and ``benchmarks/obs_report.py`` can read the logs without one.
+Importing this module never imports jax: the profiler annotation is looked
+up on the first span and the compile listener on the first run, so
+``benchmarks/obs_report.py`` reads the logs without jax.
 """
 from __future__ import annotations
 
@@ -73,25 +81,26 @@ def _jsonable(x: Any):
     return str(x)
 
 
-class _NullSpan:
-    """The disabled-tracer span: every method is a do-nothing returning
-    something sensible, so instrumented code needs no ``if enabled`` guard.
-    ``block`` returns its argument *without* blocking — the disabled path
-    must not add device synchronization the uninstrumented code lacked."""
+class _ProfilerSpan:
+    """The disabled-tracer span: a profiler annotation and nothing else, so
+    instrumented code needs no ``if enabled`` guard.  ``set`` records
+    nothing."""
 
-    __slots__ = ()
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
 
     def __enter__(self):
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         return False
 
     def set(self, **attrs):
         return self
-
-    def block(self, x):
-        return x
 
 
 class _NullInstrument:
@@ -106,8 +115,30 @@ class _NullInstrument:
         return self
 
 
-_NULL_SPAN = _NullSpan()
 _NULL_INSTRUMENT = _NullInstrument()
+
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str, attrs: dict):
+    """A ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (jax is
+    imported on the first call)."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(f"repro.{name}", **attrs)
+
+
+# JAX's compile-duration events -> the counters they feed: a lowering on
+# every in-process cache miss, a backend compile where the persistent cache
+# missed too.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lowerings",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compiles",
+}
+
 
 # One lock for all counter/gauge mutation: scheduler worker threads update
 # shared instruments concurrently, and `+=` on a float is not atomic.  The
@@ -185,28 +216,29 @@ class RunLog:
 
 
 class Span:
-    """An in-progress span; obtained from :meth:`Tracer.span` and used as a
-    context manager.  ``set(**attrs)`` attaches attributes discovered while
-    the span runs (e.g. achieved accesses/s); ``block(x)`` blocks on a jax
-    value via ``benchtime.block`` and accumulates the wait into the span's
-    ``blocked_s`` attribute."""
+    """An in-progress span; obtained from :meth:`Tracer.span` while a run is
+    active and used as a context manager.  It holds the profiler annotation
+    and records its wall duration into the run; ``set(**attrs)`` attaches
+    attributes discovered while the span runs (e.g. achieved accesses/s) to
+    the JSONL record."""
 
     __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id", "ts",
-                 "_t0", "_blocked_s")
+                 "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict, ann):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self.span_id: Optional[int] = None
         self.parent_id: Optional[int] = None
-        self._blocked_s = 0.0
+        self._ann = ann
 
     def __enter__(self) -> "Span":
         tr = self._tracer
         self.parent_id = tr._stack[-1].span_id if tr._stack else None
         self.span_id = tr._next_id()
         tr._stack.append(self)
+        self._ann.__enter__()
         self.ts = time.time()
         self._t0 = time.perf_counter()
         return self
@@ -215,23 +247,14 @@ class Span:
         self.attrs.update(attrs)
         return self
 
-    def block(self, x):
-        from repro.core.benchtime import block
-
-        t0 = time.perf_counter()
-        block(x)
-        self._blocked_s += time.perf_counter() - t0
-        return x
-
     def __exit__(self, et, ev, tb) -> bool:
         dur_s = time.perf_counter() - self._t0
+        self._ann.__exit__(et, ev, tb)
         tr = self._tracer
         if tr._stack and tr._stack[-1] is self:
             tr._stack.pop()
         if et is not None:
             self.attrs.setdefault("error", f"{et.__name__}: {ev}")
-        if self._blocked_s:
-            self.attrs.setdefault("blocked_s", round(self._blocked_s, 6))
         tr._finish_span(self.name, dur_s, self.span_id, self.parent_id,
                         self.ts, self.attrs)
         return False
@@ -255,6 +278,7 @@ class Tracer:
     def __init__(self):
         self._lock = threading.RLock()
         self._tls = threading.local()
+        self._listening = False
         self._reset()
 
     def _reset(self) -> None:
@@ -288,6 +312,7 @@ class Tracer:
                              self.run, run)
                 self.end_run(error=f"superseded by run {run!r}")
             self._reset()
+            self._listen_for_compiles()
             self.run = run
             self.active = True
             if path is not None:
@@ -322,10 +347,12 @@ class Tracer:
     # `name` is positional-only so callers can attach a `name=...` attribute
     # (e.g. the orchestrator labels chunk spans with the figure name).
     def span(self, name: str, /, **attrs):
-        """Open a span context manager (a shared no-op when disabled)."""
+        """Open a span context manager: a profiler annotation
+        ``repro.<name>`` with ``attrs``, which a run also records."""
+        ann = _annotation(name, attrs)
         if not self.active:
-            return _NULL_SPAN
-        return Span(self, name, attrs)
+            return _ProfilerSpan(ann)
+        return Span(self, name, attrs, ann)
 
     def record_span(self, name: str, dur_s: float, /, **attrs) -> None:
         """Record an already-measured span (duration timed externally)."""
@@ -386,6 +413,25 @@ class Tracer:
         }
 
     # -- internals ----------------------------------------------------------
+
+    def _listen_for_compiles(self) -> None:
+        """Register :meth:`_on_compile` with ``jax.monitoring``, once."""
+        if self._listening:
+            return
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(self._on_compile)
+        self._listening = True
+
+    def _on_compile(self, event: str, duration_secs: float, **_) -> None:
+        counter = COMPILE_EVENTS.get(event)
+        if counter is None or not self.active:
+            return
+        self.counter(counter).add()
+        stack = self._stack
+        self.event("compile", counter=counter,
+                   span=stack[-1].name if stack else None,
+                   dur_s=round(duration_secs, 6))
 
     def _next_id(self) -> int:
         with self._lock:

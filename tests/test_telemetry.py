@@ -7,11 +7,17 @@ The contract under test:
   ``span`` / ``run_end`` records, every record stamped with wall-clock
   (``ts``) and monotonic (``t_mono``) time, and the ``run_end`` summary
   aggregates spans/events/counters/gauges.
-* **Nesting** — spans link ``parent_id`` -> ``span_id``; ``Span.block``
-  accumulates device-blocked time.
-* **No-op fast path** — with no active run every instrument call returns a
-  shared null object, and the total instrument cost of a disabled-tracer
-  ``run_sweep_tlb`` stays under 2% of the sweep's own wall time.
+* **Nesting** — spans link ``parent_id`` -> ``span_id``.
+* **No-op fast path** — with no active run a span only annotates the
+  profiler and every other instrument call returns a shared null object;
+  the total instrument cost of a disabled-tracer ``run_sweep_tlb`` stays
+  under 2% of the sweep's own wall time.
+* **Profiler spans** — an engine call's phases (``repro.engine``,
+  ``engine.prepare``, ``chunk`` with its key/upload/launch/pull phases,
+  ``chunk.commit``, ``engine.finish``) land in a profiler trace, nested and
+  on the trace's clock, with or without a run; a run's JSONL ``chunk``
+  records keep their fields; compilations are counted with the span they
+  happened in.
 * **Orchestrator threading** — ladder events carry timestamps and
   per-attempt elapsed time; chunk spans and per-backend achieved accesses/s
   land in the run log and in ``meta["throughput"]`` (streamed and
@@ -19,8 +25,10 @@ The contract under test:
 * **obs_report** — renders, diffs, tolerates torn tails, and fails on
   banned events (the CI ``--fail-on-event downgrade`` gate).
 """
+import contextlib
 import json
 import logging
+import pathlib
 
 import jax
 import numpy as np
@@ -28,9 +36,11 @@ import pytest
 
 from benchmarks import obs_report
 from repro.core import benchtime
-from repro.core.orchestrator import SweepRunConfig, run_sweep_tlb
+from repro.core.orchestrator import (SweepRunConfig, run_sweep_system,
+                                     run_sweep_tlb)
 from repro.core.sparta import TLBConfig
-from repro.core.sweep import TLBSweepSpec, sweep_tlb
+from repro.core.sweep import TLBSweepSpec, TLBSweepStream, sweep_tlb
+from repro.core.tlbsim import SystemSimConfig
 from repro.runtime import telemetry
 
 BLOCK = 128
@@ -56,6 +66,30 @@ def _sweep_inputs():
 
 def _read(path):
     return [json.loads(line) for line in path.read_text().splitlines()]
+
+
+@contextlib.contextmanager
+def _profiled(logdir):
+    """Record a profiler trace (Python tracer off) into ``logdir``; yields a
+    list filled on exit with the host events: (name, start_ns, end_ns,
+    stats)."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    events = []
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        yield events
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = pathlib.Path(logdir).rglob("*.xplane.pb")
+    for plane in ProfileData.from_file(str(pb)).planes:
+        if plane.name.startswith("/host:"):
+            events += [(e.name, int(e.start_ns),
+                        int(e.start_ns + e.duration_ns),
+                        dict(e.stats) if e.name.startswith("repro.") else {})
+                       for line in plane.lines for e in line.events]
 
 
 # ------------------------------------------------------------ schema/lifecycle
@@ -110,16 +144,6 @@ def test_span_nesting_parent_ids(tmp_path):
     assert spans["measured"]["parent_id"] == spans["outer"]["span_id"]
 
 
-def test_span_block_accumulates_blocked_time(tmp_path):
-    path = tmp_path / "blk.jsonl"
-    x = np.arange(8)
-    with telemetry.run_scope(path, run="t"):
-        with telemetry.get_tracer().span("s") as sp:
-            assert sp.block(x) is x
-    rec = [r for r in _read(path) if r["kind"] == "span"][0]
-    assert rec["attrs"]["blocked_s"] > 0
-
-
 def test_counter_and_gauge_aggregation():
     tr = telemetry.get_tracer()
     tr.start_run(None, run="mem")
@@ -146,19 +170,24 @@ def test_start_run_supersedes_leaked_run(tmp_path):
 # -------------------------------------------------------------- no-op fast path
 
 
-def test_disabled_tracer_is_noop():
+def test_disabled_tracer_is_noop(tmp_path):
+    """With no run a span annotates the profiler and records nothing in
+    telemetry."""
     tr = telemetry.get_tracer()
     assert not tr.active
-    assert tr.span("x", a=1) is telemetry._NULL_SPAN
     assert tr.counter("c") is telemetry._NULL_INSTRUMENT
     assert tr.gauge("g") is telemetry._NULL_INSTRUMENT
-    tr.event("e")             # records nothing, raises nothing
-    tr.record_span("s", 0.5)
+    before, next_id = tr.summary(), tr._id
+    with _profiled(tmp_path) as events:
+        tr.event("e")             # records nothing, raises nothing
+        tr.record_span("s", 0.5)
+        with tr.span("x", a=1) as sp:
+            assert sp.set(k=1) is sp
     assert tr.end_run() == {}
-    obj = object()
-    assert telemetry._NULL_SPAN.block(obj) is obj  # no device sync added
-    with tr.span("x") as sp:
-        sp.set(k=1).block(obj)
+    assert tr.summary() == before and tr._id == next_id
+    (x,) = [e for e in events if e[0] == "repro.x"]
+    assert x[3] == {"a": 1}   # attributes known at entry, not set() ones
+    assert not [e for e in events if e[0] in ("repro.s", "repro.e")]
 
 
 def test_disabled_tracer_overhead_under_2_percent():
@@ -249,7 +278,10 @@ def test_runlog_chunks_and_throughput_meta(tmp_path):
     env = [r for r in recs
            if r["kind"] == "event" and r["name"] == "vmem_envelope"]
     assert env and env[0]["attrs"]["configs"] == len(specs)
-    assert env[0]["attrs"]["state_bytes"] > 0
+    # The carried state's bytes, counted where it lives (no pull-back).
+    state = TLBSweepStream(specs, block=BLOCK).export_state()
+    assert env[0]["attrs"]["state_bytes"] == sum(
+        v.nbytes for k, v in state.items() if k != "now")
     summary = recs[-1]["summary"]
     assert summary["counters"]["sweep_tlb.sim_accesses"]["value"] == \
         4096 * len(specs)
@@ -281,6 +313,137 @@ def test_measure_label_records_span(tmp_path):
     a = spans[0]["attrs"]
     assert a["label"] == "unit:probe" and a["reps"] == 2
     assert a["best_s"] >= 0 and a["spread_frac"] >= 0
+
+
+# ----------------------------------------------------------- profiler spans
+
+
+def _two_chunk_system_sweep():
+    rng = np.random.default_rng(5)
+    lines = rng.integers(0, 1 << 26, 2048).astype(np.int64)
+    cfgs = [SystemSimConfig(num_partitions=8),
+            SystemSimConfig(accel_tlb=TLBConfig(entries=16, ways=4),
+                            num_partitions=4)]
+    return run_sweep_system(lines, cfgs, kernel_mode="reference",
+                            block=BLOCK, name="sys",
+                            run=SweepRunConfig(chunk_accesses=1024))
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("with_run", [False, True])
+def test_engine_phases_land_in_the_profiler_trace(tmp_path, with_run):
+    """One two-chunk ``run_sweep_system`` call: ``repro.engine`` holds
+    ``engine.prepare``, two ``chunk`` spans (with ``lo``/``hi``) each
+    holding ``chunk.keys``/``upload``/``launch``/``pull``, two
+    ``chunk.commit`` and ``engine.finish``, all inside ``bench.window`` on
+    the profiler's clock.  With a run active the JSONL records carry the
+    same tree and the ``chunk`` records keep their fields."""
+    log = tmp_path / "run.jsonl"
+    with contextlib.ExitStack() as stack:
+        if with_run:
+            stack.enter_context(telemetry.run_scope(log, run="t"))
+        events = stack.enter_context(_profiled(tmp_path / "trace"))
+        with jax.profiler.TraceAnnotation("bench.window"):
+            _two_chunk_system_sweep()
+    spans = {}
+    for e in events:
+        if e[0].startswith("repro.") or e[0] == "bench.window":
+            spans.setdefault(e[0], []).append(e)
+    (window,) = spans["bench.window"]
+    (engine,) = spans["repro.engine"]
+    assert engine[3] == {"engine": "sweep_system", "name": "sys",
+                         "accesses": 2048, "configs": 2}
+    assert _inside(engine, window)
+    for one in ("repro.engine.prepare", "repro.engine.finish"):
+        assert len(spans[one]) == 1 and _inside(spans[one][0], engine)
+    chunks = sorted(spans["repro.chunk"], key=lambda e: e[1])
+    assert [(c[3]["lo"], c[3]["hi"]) for c in chunks] == [(0, 1024),
+                                                          (1024, 2048)]
+    assert all(c[3]["mode"] == "reference" and c[3]["attempt"] == 0
+               for c in chunks)
+    for phase in ("keys", "upload", "launch", "pull"):
+        got = spans[f"repro.chunk.{phase}"]
+        assert [sum(_inside(p, c) for p in got) for c in chunks] == [1, 1]
+    commits = spans["repro.chunk.commit"]
+    assert len(commits) == 2 and all(_inside(c, engine) for c in commits)
+    assert not any(_inside(c, k) for c in commits for k in chunks)
+    assert (spans["repro.engine.prepare"][0][2] <= chunks[0][1]
+            and chunks[1][2] <= spans["repro.engine.finish"][0][1])
+    if not with_run:
+        assert not log.exists()
+        return
+    recs = [r for r in _read(log) if r["kind"] == "span"]
+    by_id = {r["span_id"]: r for r in recs}
+    chunk_recs = [r for r in recs if r["name"] == "chunk"]
+    assert len(chunk_recs) == 2
+    for r in chunk_recs:
+        a = r["attrs"]
+        assert set(a) == {"engine", "name", "lo", "hi", "mode", "attempt",
+                          "accesses", "configs", "accesses_per_s",
+                          "sim_accesses_per_s"}
+        assert a["accesses"] == 1024 and a["configs"] == 2
+        assert a["sim_accesses_per_s"] == pytest.approx(
+            2 * a["accesses_per_s"], rel=1e-3)
+        assert by_id[r["parent_id"]]["name"] == "engine"
+    parents = {r["name"]: by_id[r["parent_id"]]["name"]
+               for r in recs if r["parent_id"] is not None}
+    assert parents == {"engine.prepare": "engine", "chunk": "engine",
+                       "chunk.keys": "chunk", "chunk.upload": "chunk",
+                       "chunk.launch": "chunk", "chunk.pull": "chunk",
+                       "chunk.commit": "engine", "engine.finish": "engine"}
+
+
+def test_compiles_are_counted_with_the_span_they_happen_in(tmp_path):
+    path = tmp_path / "c.jsonl"
+    fresh = jax.jit(lambda x: x * 7 + 3)   # a new function: compiles once
+    with telemetry.run_scope(path, run="c"):
+        tr = telemetry.get_tracer()
+        with tr.span("phase"):
+            jax.block_until_ready(fresh(np.arange(4)))
+        jax.block_until_ready(fresh(np.arange(4)))   # cached: no compile
+    s = tr.summary()
+    assert s["counters"]["jax.lowerings"]["value"] == 1
+    assert s["counters"]["jax.backend_compiles"]["value"] == 1
+    compiles = [r["attrs"] for r in _read(path)
+                if r["kind"] == "event" and r["name"] == "compile"]
+    assert [c["counter"] for c in compiles] == ["jax.lowerings",
+                                                "jax.backend_compiles"]
+    assert all(c["span"] == "phase" and c["dur_s"] >= 0 for c in compiles)
+    # With no run active nothing is counted.
+    jax.block_until_ready(jax.jit(lambda x: x - 1)(np.arange(4)))
+    assert tr.summary() == s
+
+
+def test_failed_chunk_attempt_span_is_left_out_of_throughput(tmp_path):
+    """A chunk attempt that raised is a ``chunk`` span carrying ``error``;
+    obs_report's throughput counts only the attempts that completed."""
+    addrs, specs = _sweep_inputs()
+    failures = {"left": 1}
+
+    def hook(engine, lo, hi, mode, attempt):
+        if failures["left"]:
+            failures["left"] -= 1
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    path = tmp_path / "f.jsonl"
+    with telemetry.run_scope(path, run="f"):
+        run_sweep_tlb(addrs, specs, kernel_mode="reference", block=BLOCK,
+                      name="tlb",
+                      run=SweepRunConfig(fault_hook=hook, backoff_base_s=0.0,
+                                         backoff_cap_s=0.0,
+                                         chunk_accesses=1024))
+    recs = _read(path)
+    chunks = [r for r in recs if r["kind"] == "span" and r["name"] == "chunk"]
+    assert len(chunks) == 5
+    (bad,) = [r for r in chunks if "error" in r["attrs"]]
+    assert "RESOURCE_EXHAUSTED" in bad["attrs"]["error"]
+    assert "accesses" not in bad["attrs"]
+    st = obs_report.engine_throughput(recs)[("sweep_tlb", "reference")]
+    assert st["chunks"] == 4 and st["accesses"] == 4096
+    assert len(obs_report.throughput_timeline(recs)) == 4
 
 
 # --------------------------------------------------------------- setup_logging

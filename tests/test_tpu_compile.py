@@ -155,3 +155,67 @@ def test_flash_attention_compiles_at_internvl2_widths(one_chip, tokens):
                jnp.bfloat16)
     _assert_kernel(flash_attention_pallas, q, kv, kv)
 
+
+
+def _lru_case(one_chip, name):
+    """(function, argument specs) of one LRU kernel at a small size: 2
+    configurations, 16 sets (+1 parked row) x 4 ways, 512 accesses."""
+    from repro.kernels.system_sim import kernel as system_sim
+    from repro.kernels.tlb_sim import kernel as tlb_sim
+
+    keys, state = _spec(one_chip, (2, 512)), _spec(one_chip, (2, 17, 4))
+    now, flags = _spec(one_chip, ()), _spec(one_chip, (2, 3))
+    geom, valid = (17, 4) * 3, ((4, 4),) * 3
+    return {
+        "tlb_sim": (lambda s, t: tlb_sim.tlb_sim_pallas(s, t, 16, 4, block=128),
+                    [_spec(one_chip, (512,))] * 2),
+        "tlb_sim_batched": (
+            lambda s, t: tlb_sim.tlb_sim_batched_pallas(s, t, 17, 4, (4, 4),
+                                                        block=128),
+            [keys] * 2),
+        "tlb_sim_carry": (
+            lambda s, t, a, b, n: tlb_sim.tlb_sim_batched_pallas_carry(
+                s, t, a, b, n, block=128),
+            [keys, keys, state, state, now]),
+        "system_sim_batched": (
+            lambda *a: system_sim.system_sim_batched_pallas(
+                *a, geom, valid, block=128),
+            [keys] * 6 + [flags]),
+        "system_sim_carry": (
+            lambda *a: system_sim.system_sim_batched_pallas_carry(
+                *a[:7], tuple(a[7:13]), a[13], block=128),
+            [keys] * 6 + [flags] + [state] * 6 + [now]),
+    }[name]
+
+
+def _timeline_case(one_chip, name):
+    """(function, argument specs) of one timeline kernel: 2 sims, 512
+    accesses, a small resource envelope."""
+    from repro.kernels.timeline.kernel import (
+        timeline_sim_batched_pallas, timeline_sim_batched_pallas_carry)
+    from repro.kernels.timeline.ref import timeline_init_state_batched
+
+    envelope = (2, 4, 8, 1, 4)
+    args = ([_spec(one_chip, (2, 512))] * 7
+            + [_spec(one_chip, (2, 512), jnp.float32),
+               _spec(one_chip, (2, 8), jnp.float32), _spec(one_chip, (2, 7))])
+    if name == "timeline_batched":
+        return (lambda *a: timeline_sim_batched_pallas(*a, envelope, block=128),
+                args)
+    state = jax.eval_shape(lambda: timeline_init_state_batched(
+        2, envelope, jnp.ones((2,), jnp.int32)))
+    return (lambda *a: timeline_sim_batched_pallas_carry(
+                *a[:10], tuple(a[10:]), block=128),
+            args + [_spec(one_chip, s.shape, s.dtype) for s in state])
+
+
+@pytest.mark.parametrize("name", [
+    "tlb_sim", "tlb_sim_batched", "tlb_sim_carry", "system_sim_batched",
+    "system_sim_carry", "timeline_batched", "timeline_carry"])
+def test_pallas_calls_carry_stable_names(one_chip, name):
+    """Each simulator kernel lowers for the chip under its own ``name=``,
+    which names its device operation in a profiler trace."""
+    case = _timeline_case if name.startswith("timeline") else _lru_case
+    fn, args = case(one_chip, name)
+    text = jax.jit(fn).lower(*args).as_text()
+    assert f'kernel_name = "{name}"' in text
