@@ -20,13 +20,15 @@ State layout.  A ``[sets, W]`` LRU array would waste 128/W of every vector
 register and of VMEM (the lane axis pads to 128), so the kernels keep it
 lane-dense (:func:`lane_layout`): ways padded to a power of two with
 poisoned slots, ``128 / W`` sets per 128-lane row (or ``W / 128`` rows per
-set for very wide sets).  The per-access trace words and hit bits are SMEM
-scalars.
+set for very wide sets).  The per-access trace words are SMEM scalars.
 
 The access loop is inherently serial (LRU state carries a dependency), but
-each probe is one vector compare/select over the set's row.  The host-side
-oracles are ``repro.core.tlbsim._scan_tlb`` and
-``repro.core.tlbsim._scan_tlb_batched``.
+each probe is one vector compare/select over the set's row, and its way
+choice, hit and update mask stay vectors (:func:`lru_probe`).  These
+kernels store each hit to an SMEM word, the probe's one vector-to-scalar
+transfer; the joint-system kernel keeps its hits on the vector unit
+(``repro.kernels.system_sim``).  The host-side oracles are
+``repro.core.tlbsim._scan_tlb`` and ``repro.core.tlbsim._scan_tlb_batched``.
 """
 from __future__ import annotations
 
@@ -101,7 +103,9 @@ def lru_probe(tags_ref, last_ref, b, s, t, now, do_update=True, *,
               ways: int):
     """Probe set ``s`` of config ``b`` for tag ``t``; on ``do_update`` write
     ``t`` and the stamp ``now`` into the hit way, or the LRU way on a miss.
-    Returns the hit bit.  ``ways`` is the layout's padded associativity.
+    Returns the hit as a bool ``[1, 1]`` vector.  ``ways`` is the layout's
+    padded associativity; ``do_update`` is a bool or a bool ``[1, 1]``
+    vector.
 
     The set's row is read and written whole through a dynamic sublane
     offset (``pl.ds``), its slots masked out of the 128 lanes, and the way
@@ -109,6 +113,9 @@ def lru_probe(tags_ref, last_ref, b, s, t, now, do_update=True, *,
     least-recent way, the same first-index tie-break as the oracle's
     ``argmax`` / ``argmin``.  Mosaic lowers neither scalar VMEM stores nor
     integer arg-reductions, so this is the form that compiles for the chip.
+    Every reduction keeps its dimensions: the way, the hit and the update
+    mask stay on the vector unit, and only the set index and the operands
+    ``s``, ``t``, ``now`` (scalars from SMEM) cross from the scalar unit.
     """
     if ways <= LANES:
         per_row = LANES // ways
@@ -122,12 +129,19 @@ def lru_probe(tags_ref, last_ref, b, s, t, now, do_update=True, *,
         way_ix = (jax.lax.broadcasted_iota(jnp.int32, (span, LANES), 0) * LANES
                   + jax.lax.broadcasted_iota(jnp.int32, (span, LANES), 1))
         in_set = way_ix >= 0
+
+    def first(x):
+        # Lanes, then sublanes: a reduction over both axes at once goes
+        # through a scalar.
+        x = jnp.min(x, axis=1, keepdims=True)
+        return x if x.shape[0] == 1 else jnp.min(x, axis=0, keepdims=True)
+
     row_t = tags_ref[idx]
     row_l = last_ref[idx]
-    hit_way = jnp.min(jnp.where(in_set & (row_t == t), way_ix, ways))
+    hit_way = first(jnp.where(in_set & (row_t == t), way_ix, ways))
     hit = hit_way < ways
-    lru = jnp.min(jnp.where(in_set, row_l, _POISON_LAST))
-    lru_way = jnp.min(jnp.where(in_set & (row_l == lru), way_ix, ways))
+    lru = first(jnp.where(in_set, row_l, _POISON_LAST))
+    lru_way = first(jnp.where(in_set & (row_l == lru), way_ix, ways))
     sel = in_set & (way_ix == jnp.where(hit, hit_way, lru_way)) & do_update
     tags_ref[idx] = jnp.where(sel, t, row_t)
     last_ref[idx] = jnp.where(sel, now, row_l)
@@ -154,7 +168,7 @@ def _tlb_kernel(
     def body(j, _):
         hit = lru_probe(tags_scr, last_scr, 0, set_ref[j], tag_ref[j],
                         base + j + 1, ways=lay.ways)
-        hit_ref[j] = hit.astype(jnp.int32)
+        hit_ref[j] = hit.astype(jnp.int32)[0, 0]
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
@@ -199,7 +213,7 @@ def _batched_access_loop(set_ref, tag_ref, hit_ref, tags, last, base, *,
         def per_cfg(b, _):
             hit = lru_probe(tags, last, b, set_ref[b, j], tag_ref[b, j], now,
                             ways=ways)
-            hit_ref[b, j] = hit.astype(jnp.int32)
+            hit_ref[b, j] = hit.astype(jnp.int32)[0, 0]
             return 0
 
         jax.lax.fori_loop(0, num_cfgs, per_cfg, 0)

@@ -10,6 +10,18 @@ from repro.core.sparta import (
 )
 
 
+@pytest.fixture(scope="module")
+def jax_started():
+    """The first call of each JAX operation in a process starts the backend
+    and compiles the operation, which can take longer than an example's
+    deadline: make those first calls before the first example."""
+    import jax.numpy as jnp
+
+    int(mem_partition_index_hash(jnp.int32(0), 1))
+    int(partition_local_vpn(jnp.int32(0), 1))
+
+
+@pytest.mark.usefixtures("jax_started")
 @given(st.integers(0, 2**40), st.sampled_from([1, 2, 4, 8, 32, 128]))
 def test_partition_hash_bijective(vpn, P):
     import jax.numpy as jnp

@@ -172,3 +172,118 @@ def test_system_mode_resolution():
     expect = "pallas" if jax.default_backend() == "tpu" else "reference"
     assert resolve_system_mode("auto") == expect
     assert resolve_system_mode("pallas_interpret") == "pallas_interpret"
+
+
+# ---------------------------------------------------------------------------
+# Grid steps that are no multiple of the 128-lane hit tile.
+# ---------------------------------------------------------------------------
+
+# Per structure (sets, ways) of the envelope, one spare parked set row
+# included, and each of the three configurations' own ways.
+_GEOM = (17, 4, 9, 4, 33, 4)
+_VALID = ((4, 2, 4), (4, 4, 1), (2, 4, 4))
+_FLAGS = np.array([[1, 1, 1], [0, 1, 0], [1, 0, 1]], np.int32)
+
+
+def _key_streams(seed: int, n: int):
+    """Random (set, tag) streams of the three structures for the three
+    configurations; sets stop short of each structure's parked row."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    streams = []
+    for k in range(3):
+        streams.append(rng.integers(0, _GEOM[2 * k] - 1, (3, n)))
+        streams.append(rng.integers(0, 12, (3, n)))
+    return [jnp.asarray(s, jnp.int32) for s in streams]
+
+
+def _ref_flags():
+    import jax.numpy as jnp
+
+    return tuple(jnp.asarray(_FLAGS[:, c] > 0) for c in range(3))
+
+
+@pytest.mark.parametrize("n,block", [(200, 512), (100, 512), (400, 200)])
+def test_system_kernel_ragged_blocks_match_scan(n, block):
+    """Blocks of 200 (a full 128-access hit tile and a 72-access tail),
+    100 (a tail alone) and two grid steps of 200 against the batched scan."""
+    import jax.numpy as jnp
+
+    from repro.kernels.system_sim.kernel import system_sim_batched_pallas
+    from repro.kernels.system_sim.ref import system_sim_batched_ref
+
+    streams = _key_streams(n + block, n)
+    ref = system_sim_batched_ref(tuple(streams), _ref_flags(), _GEOM, _VALID)
+    pal = system_sim_batched_pallas(*streams, jnp.asarray(_FLAGS), _GEOM,
+                                    _VALID, block=block, interpret=True)
+    for k, r, p in zip(HIT_KEYS, ref, pal):
+        np.testing.assert_array_equal(np.asarray(p), np.asarray(r), err_msg=k)
+
+
+@pytest.mark.parametrize("chunks,block", [((200, 100), 512),
+                                          ((256, 128, 44), 128)])
+def test_system_carry_kernel_ragged_chunks_match_scan(chunks, block):
+    """Chunks whose kernel blocks are no multiple of 128 (200, 100 and a
+    partial last chunk of 44) carry state exactly as the carried scan does,
+    and together equal one monolithic scan."""
+    import jax.numpy as jnp
+
+    from repro.core.tlbsim import padded_tlb_state
+    from repro.kernels.system_sim.kernel import system_sim_batched_pallas_carry
+    from repro.kernels.system_sim.ref import (
+        system_sim_batched_carry_ref, system_sim_batched_ref)
+
+    n = sum(chunks)
+    streams = _key_streams(n, n)
+    state0 = tuple(x for k in range(3) for x in padded_tlb_state(
+        3, _GEOM[2 * k], _GEOM[2 * k + 1], _VALID[k]))
+    flags = jnp.asarray(_FLAGS)
+    got, want = [], []
+    st_p = st_r = state0
+    lo = 0
+    for size in chunks:
+        part = [s[:, lo:lo + size] for s in streams]
+        h_p, st_p = system_sim_batched_pallas_carry(
+            *part, flags, st_p, lo, block=block, interpret=True)
+        h_r, st_r = system_sim_batched_carry_ref(
+            tuple(part), _ref_flags(), st_r, jnp.asarray(lo))
+        got.append([np.asarray(h) for h in h_p])
+        want.append([np.asarray(h) for h in h_r])
+        lo += size
+    for a, b in zip(st_p, st_r):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    whole = system_sim_batched_ref(tuple(streams), _ref_flags(), _GEOM,
+                                   _VALID)
+    for k in range(3):
+        p = np.concatenate([g[k] for g in got], axis=1)
+        np.testing.assert_array_equal(
+            p, np.concatenate([w[k] for w in want], axis=1), err_msg=HIT_KEYS[k])
+        np.testing.assert_array_equal(p, np.asarray(whole[k]),
+                                      err_msg=HIT_KEYS[k])
+
+
+@pytest.mark.parametrize("sets,ways,valid", [(17, 4, (4, 2)),
+                                             (4, 256, (256, 200))])
+def test_tlb_carry_kernel_ragged_chunks_match_scan(sets, ways, valid):
+    """The probe the system kernel shares, in the TLB carry kernel: chunks of
+    200 and 100 accesses (blocks no multiple of 128), narrow ways and ways
+    that span two rows."""
+    import jax.numpy as jnp
+
+    from repro.core.tlbsim import padded_tlb_state
+    from repro.kernels.tlb_sim.kernel import tlb_sim_batched_pallas_carry
+    from repro.kernels.tlb_sim.ref import tlb_sim_batched_carry_ref
+
+    rng = np.random.default_rng(ways)
+    s = jnp.asarray(rng.integers(0, sets, (2, 300)), jnp.int32)
+    t = jnp.asarray(rng.integers(0, 3 * ways, (2, 300)), jnp.int32)
+    pal = ref = padded_tlb_state(2, sets, ways, valid)
+    for lo, hi in ((0, 200), (200, 300)):
+        h_p, *pal = tlb_sim_batched_pallas_carry(
+            s[:, lo:hi], t[:, lo:hi], *pal, lo, interpret=True)
+        h_r, *ref = tlb_sim_batched_carry_ref(s[:, lo:hi], t[:, lo:hi], *ref,
+                                              lo)
+        np.testing.assert_array_equal(np.asarray(h_p), np.asarray(h_r))
+    for a, b in zip(pal, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

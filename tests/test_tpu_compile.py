@@ -14,6 +14,12 @@ widths.  The topology is described inside a fixture, never at import time:
 only one process may load the TPU library, and the test workers all import
 this module.
 """
+import os
+import pathlib
+import subprocess
+import sys
+import textwrap
+
 import pytest
 
 import jax
@@ -56,6 +62,68 @@ def _state_specs(one_chip, stream):
     state = stream.export_state()
     return [_spec(one_chip, state[k].shape, state[k].dtype)
             for k in state if k != "now"]
+
+
+# Compiles the fig10 system kernel for a described v5e with Mosaic's dumps
+# on; exits 77 where no v5e can be described.
+_FIG10_DUMP = textwrap.dedent("""
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from benchmarks import fig10_performance as fig10
+    from repro.core.sweep import SystemSweepStream
+    from repro.core.tlbsim import SystemSimConfig
+    from repro.kernels.system_sim.kernel import system_sim_batched_pallas_carry
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:
+        raise SystemExit(77)
+    jax.config.update("jax_enable_compilation_cache", False)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    spec = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=one_chip)
+    cfgs = [SystemSimConfig(
+        cache=fig10.CACHE,
+        accel_tlb=fig10.ACCEL_TLB if design == "conventional" else None,
+        mem_tlb=fig10.MEM_TLB, num_partitions=parts, page_shift=shift,
+        accel_probe_on_miss_only=True)
+        for _, parts, shift, design in fig10.CONFIGS]
+    stream = SystemSweepStream(cfgs)
+    state = stream.export_state()
+    keys = spec((len(cfgs), %d))
+    jax.jit(lambda *a: system_sim_batched_pallas_carry(
+        *a[:7], tuple(a[7:13]), a[13], block=stream.block)).lower(
+        *[keys] * 6, spec((len(cfgs), 3)),
+        *[spec(state[k].shape, state[k].dtype) for k in state if k != "now"],
+        spec(())).compile()
+""" % CHUNK)
+
+
+def test_system_sim_probe_keeps_to_the_vector_unit(tmp_path):
+    """The fig10 system kernel's compiled access loop moves no value from
+    the vector unit to the scalar unit: its final LLO has no ``llo.vtos``.
+    (A probe that reduced the way choice to scalars had nine per design.)
+
+    The dump flag is read when the TPU library loads, so the compile runs in
+    a child process, and this test comes first in the file: before the
+    ``one_chip`` fixture has loaded the library into this one.  Where the
+    child cannot load it or describe a v5e, the test skips."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]))
+    env["LIBTPU_INIT_ARGS"] = (env.get("LIBTPU_INIT_ARGS", "")
+                               + f" --xla_mosaic_dump_to={tmp_path}").strip()
+    run = subprocess.run([sys.executable, "-c", _FIG10_DUMP], cwd=root,
+                         env=env, capture_output=True, text=True, timeout=300)
+    llo = sorted(tmp_path.glob("*system_sim_carry-post-finalize-llo.txt"))
+    if run.returncode != 0 or not llo:
+        pytest.skip(f"no Mosaic dump of the fig10 kernel (exit "
+                    f"{run.returncode}): {run.stderr[-500:]}")
+    text = llo[-1].read_text()
+    assert "llo.vmin.xlane" in text   # the probe's way choice is in this dump
+    assert text.count("llo.vtos") == 0
 
 
 def test_tlb_sim_carry_compiles_for_the_8_config_sweep(one_chip):
