@@ -96,14 +96,17 @@ def _note_envelope(stream) -> None:
     tr.gauge(f"{stream.engine}.state_bytes").set(state_bytes)
 
 
-def _count_sim_accesses(stream, n: int) -> None:
-    """Counters for one committed chunk: trace accesses consumed and
-    simulated (config x access) pairs advanced."""
+def _count_sim_accesses(stream, n: int, lru_probes: int = 0) -> None:
+    """Counters for one committed chunk: trace accesses consumed, simulated
+    (config x access) pairs advanced and, where the engine reports them,
+    LRU probes run (``lru_probes`` per access)."""
     tr = telemetry.get_tracer()
     if not tr.active:
         return
     tr.counter(f"{stream.engine}.trace_accesses").add(int(n))
     tr.counter(f"{stream.engine}.sim_accesses").add(int(n) * stream.batch_size)
+    if lru_probes:
+        tr.counter(f"{stream.engine}.lru_probes").add(int(n) * lru_probes)
 
 
 # ---------------------------------------------------------------------------
@@ -497,6 +500,33 @@ def _system_keys(lines: np.ndarray, cfg: SystemSimConfig):
     return c_set, c_tag, a_set, a_tag, m_set, m_tag
 
 
+def _system_share(cfgs: Sequence[SystemSimConfig]):
+    """The share map of one kernel batch
+    (:mod:`repro.kernels.system_sim.kernel`): per structure, each design's
+    representative, the first design of ``cfgs`` whose structure has the
+    same geometry, key rule and probe condition, or -1 where the design has
+    none.  Such structures see equal inputs at every access, so their states
+    and hit bits are equal."""
+    def reps(rows):
+        first = {}
+        return tuple(-1 if row is None else first.setdefault(row, i)
+                     for i, row in enumerate(rows))
+
+    c_rows = [None if c.cache is None else _geom(c.cache) for c in cfgs]
+    a_rows = [None if c.accel_tlb is None else
+              (c_rows[i], _geom(c.accel_tlb), c.page_shift,
+               c.accel_probe_on_miss_only) for i, c in enumerate(cfgs)]
+    m_rows = [(c_rows[i], _geom(c.mem_tlb)[0] * c.num_partitions,
+               _geom(c.mem_tlb)[1], c.num_partitions, c.page_shift)
+              for i, c in enumerate(cfgs)]
+    return reps(c_rows), reps(a_rows), reps(m_rows)
+
+
+def _probes_per_access(share) -> int:
+    """Structures the kernel probes per access under ``share``."""
+    return sum(len({r for r in rep if r >= 0}) for rep in share)
+
+
 def sweep_system(
     lines: np.ndarray,
     cfgs: Sequence[SystemSimConfig],
@@ -578,7 +608,8 @@ def sweep_system(
             chunk_streams += [jnp.asarray(s_c), jnp.asarray(t_c)]
         ys = system_sim_batched(
             *chunk_streams, jnp.asarray(flags_np[chunk]),
-            tuple(geom), tuple(valid), block=blk, kernel_mode=mode)
+            tuple(geom), tuple(valid), block=blk,
+            share=_system_share([cfgs[i] for i in chunk]), kernel_mode=mode)
         for h, y in zip(hits, ys):
             h[chunk] = np.asarray(y)[:, :n]
     return BatchedSystemEvents(*hits, n_warm=n - n0)
@@ -610,6 +641,8 @@ class SystemSweepStream:
         self._geos = (c_geo, a_geo, m_geo)
         dims = [c_geo[i] + a_geo[i] + m_geo[i] for i in range(len(self.cfgs))]
         self.groups = _system_vmem_chunks(dims, block=self.block)
+        self._shares = [_system_share([self.cfgs[i] for i in g])
+                        for g in self.groups]
         self._flags = np.asarray(
             [[c.cache is not None, c.accel_tlb is not None,
               c.accel_probe_on_miss_only] for c in self.cfgs], np.int32)
@@ -623,6 +656,9 @@ class SystemSweepStream:
                 st += list(padded_tlb_state(len(g), sets + 1, ways, valid))
             self._state.append(tuple(st))
         self.now = 0
+        # Every chunk runs padded to the longest this stream has run, so a
+        # trace's short tail reuses the kernel its full chunks compiled.
+        self._length = 0
         _note_envelope(self)
 
     @property
@@ -650,6 +686,7 @@ class SystemSweepStream:
             streams = [np.stack(rows) for rows in
                        zip(*(_system_keys(lines, c) for c in self.cfgs))]
         n = lines.shape[0]
+        self._length = max(self._length, n)
         from repro.kernels.system_sim import system_sim_batched_carry
 
         hits = [np.empty((len(self.cfgs), n), dtype=bool) for _ in range(3)]
@@ -661,6 +698,7 @@ class SystemSweepStream:
             with tracer.span("chunk.launch"):
                 ys, st = system_sim_batched_carry(
                     *keys, flags, self._state[gi], self.now,
+                    share=self._shares[gi], length=self._length,
                     block=self.block, kernel_mode=mode)
             with tracer.span("chunk.pull"):
                 for h, y in zip(hits, ys):
@@ -668,7 +706,10 @@ class SystemSweepStream:
             new_state.append(st)
         self._state = new_state
         self.now += n
-        _count_sim_accesses(self, n)
+        # The scan probes every structure of every design; the kernel each
+        # representative.
+        _count_sim_accesses(self, n, 3 * len(self.cfgs) if mode == "reference"
+                            else sum(map(_probes_per_access, self._shares)))
         return tuple(hits)
 
     def export_state(self) -> dict:

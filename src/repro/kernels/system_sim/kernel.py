@@ -2,8 +2,8 @@
 Pallas TPU kernel.
 
 Same architecture as ``repro.kernels.tlb_sim.tlb_sim_batched_pallas``, with
-THREE stacked LRU structures instead of one: every config's (tags, last-use)
-state for the data cache, the accelerator-side TLB, and the partitioned
+THREE LRU structures per config instead of one: the (tags, last-use) state
+of the data cache, the accelerator-side TLB, and the partitioned
 memory-side TLB array stays **resident in VMEM** for the entire trace (TPU
 grids execute sequentially, so scratch persists across grid steps), each in
 the tlb_sim kernel's lane-dense layout.  Each grid step streams one trace
@@ -19,15 +19,33 @@ combined as vector masks, and each packed word is selected into a
 stored into a VMEM hit block once per 128 accesses.  Scalars only flow the
 other way: key words, set indices, flags and the stamp.
 
+Designs often share structures: a figure compares translation designs
+behind one accelerator cache, and designs with one memory-TLB geometry see
+the same cache misses.  Two designs share a structure when its geometry,
+key rule and probe condition match, because its inputs are then equal at
+every access.  A static *share map* from the host names, per structure,
+each design's representative (the first design with that structure), or
+``-1`` where the design has none; each access probes every representative
+once, in straight-line code, and no absent structure.  Each kept structure
+has VMEM refs of its own, so no probe's row may alias another's and the
+scheduler overlaps independent probes.  A follower's state is its
+representative's: the carry kernel writes it back to every follower once
+per call.  Where nothing is shared, every design is its own representative.
+
+The carry kernel's grid length is data: it runs only the grid steps that
+hold the chunk's own accesses, so a caller may pad every chunk of a trace
+to one length and trace, lower and compile the kernel once.
+
 Per-config structure presence and the virtual-cache probe policy ride along
 as an int32 ``[B, 3]`` flag row (``has_cache``, ``has_accel``,
-``accel_probe_on_miss_only``) consumed as *data*, exactly like the batched
-scan oracle (:func:`repro.kernels.system_sim.ref.system_sim_batched_ref`):
-probes always execute, updates and hit bits are gated by the flags, so
-heterogeneous design points (cacheless accelerators, physical vs virtual
-caches) share one pallas_call.  Way padding beyond each config's own
-associativity is poisoned with the shared ``_POISON_TAG`` / ``_POISON_LAST``
-scheme, keeping the kernel bit-identical per config to the oracle.
+``accel_probe_on_miss_only``) consumed as *data* by the representatives'
+probes, exactly like the batched scan oracle
+(:func:`repro.kernels.system_sim.ref.system_sim_batched_ref`): updates and
+hit bits are gated by the flags, so heterogeneous design points (cacheless
+accelerators, physical vs virtual caches) share one pallas_call.  Way
+padding beyond each config's own associativity is poisoned with the shared
+``_POISON_TAG`` / ``_POISON_LAST`` scheme, keeping the kernel bit-identical
+per config to the oracle.
 """
 from __future__ import annotations
 
@@ -54,51 +72,77 @@ from repro.kernels.tlb_sim.kernel import (
 )
 
 
+# Per structure (cache, accel TLB, mem TLB): each design's representative,
+# the design whose state and hits it shares, or -1 where it has none.
+Share = Tuple[Tuple[int, ...], Tuple[int, ...], Tuple[int, ...]]
+
+
+def _representatives(share: Share):
+    """``(k, b)`` of every structure the kernel keeps and probes: structure
+    ``k`` (0 cache, 1 accel TLB, 2 mem TLB) of representative ``b``."""
+    return [(k, b) for k, rep in enumerate(share)
+            for b in sorted({r for r in rep if r >= 0})]
+
+
 def _access_loop(streams, flags_ref, hit_ref, states, base, *, block: int,
-                 num_cfgs: int, lays: Tuple[LaneLayout, ...]):
-    """Advance every config's three structures through the grid step's
-    ``block`` accesses (``states`` = (tags, last) refs per structure).
+                 share: Share, lays: Tuple[LaneLayout, ...]):
+    """Advance every representative structure through the grid step's
+    ``block`` accesses, one probe each per access: the caches first, then
+    the TLBs, whose probe conditions read their cache's hit.  ``states``
+    maps each ``(k, b)`` of :func:`_representatives` to its own
+    ``(tags, last)`` refs, ``[1, R, 128]`` each: refs of their own, so no
+    probe's row may alias another's.
 
     The packed hit words of each group of 128 accesses are carried as one
     ``[B, 128]`` tile and stored into the VMEM block ``hit_ref`` together;
     a block that is no multiple of 128 ends with a shorter group."""
-    c_set, c_tag, a_set, a_tag, m_set, m_tag = streams
-    (c_tags, c_last), (a_tags, a_last), (m_tags, m_last) = states
+    c_rep, a_rep, m_rep = share
+    num_cfgs = len(c_rep)
+    # Designs with the same three representatives have the same hit word.
+    words = {}
+    for b, key in enumerate(zip(c_rep, a_rep, m_rep)):
+        words.setdefault(key, []).append(b)
     sublane = jax.lax.broadcasted_iota(jnp.int32, (num_cfgs, LANES), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (num_cfgs, LANES), 1)
 
     def access(lo, i, tile):
         j = lo + i
         now = base + j + 1
+
+        def probe(k, b, do_update):
+            tags, last = states[k, b]
+            return lru_probe(tags, last, 0, streams[2 * k][b, j],
+                             streams[2 * k + 1][b, j], now, do_update,
+                             ways=lays[k].ways)
+
+        off = jnp.zeros((1, 1), jnp.bool_)
+        hits = ({-1: off}, {-1: off}, {})
+        for k, b in _representatives(share):
+            if k == 0:
+                has_c = flags_ref[b, 0] > 0
+                hits[0][b] = has_c & probe(0, b, has_c)
+            elif k == 1:
+                has_a = flags_ref[b, 1] > 0
+                miss_only = flags_ref[b, 2] > 0
+                # Physical cache: accel TLB probed every access.  Virtual
+                # cache: only on cache misses (translation needed only to
+                # leave the accelerator).
+                do_a = (~hits[0][c_rep[b]] | ~miss_only) & has_a
+                hits[1][b] = (probe(1, b, do_a) | ~do_a) & has_a
+            else:
+                # Memory-side TLB sees only cache misses.
+                c = hits[0][c_rep[b]]
+                hits[2][b] = probe(2, b, ~c) | c
+
         on_lane = lane == i
-
-        def per_cfg(b, tile):
-            has_c = flags_ref[b, 0] > 0
-            has_a = flags_ref[b, 1] > 0
-            miss_only = flags_ref[b, 2] > 0
-
-            def probe(k, tags, last, s, t, do_update):
-                return lru_probe(tags, last, b, s, t, now, do_update,
-                                 ways=lays[k].ways)
-
-            c_raw = probe(0, c_tags, c_last, c_set[b, j], c_tag[b, j], has_c)
-            c_hit = has_c & c_raw
-            # Physical cache: accel TLB probed every access.  Virtual cache:
-            # only on cache misses (translation needed only to leave the
-            # accelerator).
-            do_a = (~c_hit | ~miss_only) & has_a
-            a_raw = probe(1, a_tags, a_last, a_set[b, j], a_tag[b, j], do_a)
-            a_hit = (a_raw | ~do_a) & has_a
-            # Memory-side TLB sees only cache misses.
-            m_raw = probe(2, m_tags, m_last, m_set[b, j], m_tag[b, j], ~c_hit)
-            m_hit = m_raw | c_hit
-
-            word = (c_hit.astype(jnp.int32)
-                    | (a_hit.astype(jnp.int32) << 1)
-                    | (m_hit.astype(jnp.int32) << 2))
-            return jnp.where(on_lane & (sublane == b), word, tile)
-
-        return jax.lax.fori_loop(0, num_cfgs, per_cfg, tile)
+        for (c, a, m), rows in words.items():
+            word = (hits[0][c].astype(jnp.int32)
+                    | (hits[1][a].astype(jnp.int32) << 1)
+                    | (hits[2][m].astype(jnp.int32) << 2))
+            mine = functools.reduce(jnp.logical_or,
+                                    [sublane == b for b in rows])
+            tile = jnp.where(on_lane & mine, word, tile)
+        return tile
 
     def run(lo, n):
         """The hit tile of accesses ``lo .. lo + n`` (``n <= 128``)."""
@@ -118,37 +162,46 @@ def _access_loop(streams, flags_ref, hit_ref, states, base, *, block: int,
         hit_ref[:, full * LANES:] = run(full * LANES, tail)[:, :tail]
 
 
+def _state_scratch(share: Share, lays):
+    """VMEM scratch of the kept structures: a ``[1, R, 128]`` tags and a
+    last-use plane for each of :func:`_representatives`."""
+    return [pltpu.VMEM((1, lays[k].rows, LANES), jnp.int32)
+            for k, _ in _representatives(share) for _ in range(2)]
+
+
+def _state_refs(share: Share, refs):
+    """:func:`_state_scratch`'s refs, keyed by ``(k, b)``."""
+    return {kb: (refs[2 * n], refs[2 * n + 1])
+            for n, kb in enumerate(_representatives(share))}
+
+
 def _system_batched_kernel(
     c_set_ref, c_tag_ref,   # int32 [B, BLK] cache (set, tag) views (SMEM)
     a_set_ref, a_tag_ref,   # int32 [B, BLK] accel-TLB views
     m_set_ref, m_tag_ref,   # int32 [B, BLK] mem-TLB views
     flags_ref,              # int32 [B, 3]  (has_cache, has_accel, miss_only)
     hit_ref,                # int32 [B, BLK] packed hit words out (VMEM)
-    c_tags, c_last,         # [B, R, 128] persistent lane-dense VMEM state
-    a_tags, a_last,
-    m_tags, m_last,
-    *,
+    *state_refs,            # [1, R, 128] persistent lane-dense VMEM state
     block: int,
+    share: Share,
     lays: Tuple[LaneLayout, LaneLayout, LaneLayout],
     valid: Tuple[Tuple[int, ...], Tuple[int, ...], Tuple[int, ...]],
 ):
     i = pl.program_id(0)
-    states = ((c_tags, c_last), (a_tags, a_last), (m_tags, m_last))
+    states = _state_refs(share, state_refs)
 
     @pl.when(i == 0)
     def _init():
         # Poison ways beyond each config's associativity in each structure:
         # their tag never matches and their last-use stamp is never the LRU
         # minimum.  valid is static, so the per-config masks are compile-time
-        # constants, unrolled over the B axis (the tlb_sim kernel's scheme,
-        # three times over).
-        for (tags, last), lay, vws in zip(states, lays, valid):
-            for b, vw in enumerate(vws):
-                tags[b], last[b] = poisoned_state(lay, vw)
+        # constants (the tlb_sim kernel's scheme, per kept structure).
+        for (k, b), (tags, last) in states.items():
+            tags[0], last[0] = poisoned_state(lays[k], valid[k][b])
 
     _access_loop((c_set_ref, c_tag_ref, a_set_ref, a_tag_ref, m_set_ref,
                   m_tag_ref), flags_ref, hit_ref, states, i * block,
-                 block=block, num_cfgs=len(valid[0]), lays=lays)
+                 block=block, share=share, lays=lays)
 
 
 def _system_batched_carry_kernel(
@@ -161,44 +214,46 @@ def _system_batched_carry_kernel(
     m_tags_in, m_last_in,
     nb_ref,                 # int32 [1, 1] global access count before chunk
     hit_ref,                # int32 [B, BLK] packed hit words out (VMEM)
-    c_tags_out, c_last_out,  # int32 [B, R, 128] carried state out (HBM)
-    a_tags_out, a_last_out,
+    c_tags_out, c_last_out,  # int32 [B, R, 128] carried state out (HBM),
+    a_tags_out, a_last_out,  # aliased to the state in
     m_tags_out, m_last_out,
-    c_tags, c_last,         # int32 [B, R, 128] working state (VMEM)
-    a_tags, a_last,
-    m_tags, m_last,
-    *,
+    *state_refs,            # int32 [1, R, 128] working state (VMEM)
     block: int,
-    num_cfgs: int,
+    share: Share,
     lays: Tuple[LaneLayout, LaneLayout, LaneLayout],
 ):
-    """Chunk-resumable variant of :func:`_system_batched_kernel`: the six
-    carried state arrays are copied HBM->VMEM once at grid step 0 (the
-    caller owns the poison init), mutated in place across the sequential
-    grid, and copied back after the last step.  Timestamps continue the
-    global access counter, so chunked execution is bit-identical to the
-    monolithic kernel."""
+    """Chunk-resumable variant of :func:`_system_batched_kernel`: each kept
+    structure's carried planes are copied HBM->VMEM once at grid step 0
+    (the caller owns the poison init), mutated in place across the
+    sequential grid, and copied back after the last step to every design
+    that shares it.  An absent structure's planes are not touched: the
+    state out is the state in.  Timestamps continue the global access
+    counter, so chunked execution is bit-identical to the monolithic
+    kernel."""
     i = pl.program_id(0)
     ins = (c_tags_in, c_last_in, a_tags_in, a_last_in, m_tags_in, m_last_in)
     outs = (c_tags_out, c_last_out, a_tags_out, a_last_out, m_tags_out,
             m_last_out)
-    work = (c_tags, c_last, a_tags, a_last, m_tags, m_last)
+    states = _state_refs(share, state_refs)
 
     @pl.when(i == 0)
     def _load():
-        for src, dst in zip(ins, work):
-            pltpu.sync_copy(src, dst)
+        for (k, b), work in states.items():
+            for src, dst in zip(ins[2 * k:2 * k + 2], work):
+                pltpu.sync_copy(src.at[pl.ds(b, 1)], dst)
 
     _access_loop((c_set_ref, c_tag_ref, a_set_ref, a_tag_ref, m_set_ref,
-                  m_tag_ref), flags_ref, hit_ref,
-                 ((c_tags, c_last), (a_tags, a_last), (m_tags, m_last)),
-                 nb_ref[0, 0] + i * block, block=block, num_cfgs=num_cfgs,
+                  m_tag_ref), flags_ref, hit_ref, states,
+                 nb_ref[0, 0] + i * block, block=block, share=share,
                  lays=lays)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _flush():
-        for src, dst in zip(work, outs):
-            pltpu.sync_copy(src, dst)
+        for (k, b), work in states.items():
+            for d, r in enumerate(share[k]):
+                if r == b:
+                    for src, dst in zip(work, outs[2 * k:2 * k + 2]):
+                        pltpu.sync_copy(src, dst.at[pl.ds(d, 1)])
 
 
 def _hit_block(num_cfgs: int, n: int, block: int):
@@ -220,7 +275,7 @@ def _unpack(hits):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "share", "interpret"))
 def system_sim_batched_pallas_carry(
     c_set: jnp.ndarray, c_tag: jnp.ndarray,   # int32 [B, L]
     a_set: jnp.ndarray, a_tag: jnp.ndarray,
@@ -228,13 +283,20 @@ def system_sim_batched_pallas_carry(
     flags: jnp.ndarray,                       # int32 [B, 3]
     state,                                    # 6-tuple int32 [B, S, W]
     now0: jnp.ndarray,                        # int32 scalar
+    n_valid: jnp.ndarray,                     # int32 scalar, <= L
     *,
+    share: Share,
     block: int = 512,
     interpret: bool = False,
 ):
     """Chunk-resumable batched joint-pipeline simulation; returns
-    ``((cache_hit, accel_tlb_hit, mem_tlb_hit), state')``."""
+    ``((cache_hit, accel_tlb_hit, mem_tlb_hit), state')``.  Only the grid
+    steps that hold the first ``n_valid`` accesses run; hit bits past them
+    are undefined.  ``share`` is the share map (module docstring); a
+    follower's carried state must equal its representative's, as it does
+    from fresh state on."""
     num_cfgs, n = c_set.shape
+    assert all(len(rep) == num_cfgs for rep in share)
     block = min(block, n)
     assert n % block == 0, f"chunk length {n} must be a multiple of block {block}"
     # Only the six key views stream through SMEM; budgeting the hit word as
@@ -250,10 +312,10 @@ def system_sim_batched_pallas_carry(
     hit_spec, hit_shape = _hit_block(num_cfgs, n, block)
     outs = pl.pallas_call(
         functools.partial(
-            _system_batched_carry_kernel, block=block, num_cfgs=num_cfgs,
+            _system_batched_carry_kernel, block=block, share=share,
             lays=lays,
         ),
-        grid=(n // block,),
+        grid=((jnp.asarray(n_valid, jnp.int32) + block - 1) // block,),
         in_specs=[stream] * 6
         + [pl.BlockSpec((num_cfgs, 3), lambda i: (0, 0), memory_space=pltpu.SMEM)]
         + [hbm] * 6
@@ -261,7 +323,7 @@ def system_sim_batched_pallas_carry(
         out_specs=[hit_spec] + [hbm] * 6,
         out_shape=[hit_shape]
         + [jax.ShapeDtypeStruct(x.shape, jnp.int32) for x in lanes],
-        scratch_shapes=[pltpu.VMEM(x.shape, jnp.int32) for x in lanes],
+        scratch_shapes=_state_scratch(share, lays),
         input_output_aliases={7 + k: 1 + k for k in range(6)},
         interpret=interpret,
         name="system_sim_carry",
@@ -276,7 +338,7 @@ def system_sim_batched_pallas_carry(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("geom", "valid", "block", "interpret"))
+    jax.jit, static_argnames=("geom", "valid", "block", "share", "interpret"))
 def system_sim_batched_pallas(
     c_set: jnp.ndarray, c_tag: jnp.ndarray,   # int32 [B, N]
     a_set: jnp.ndarray, a_tag: jnp.ndarray,   # int32 [B, N]
@@ -285,14 +347,16 @@ def system_sim_batched_pallas(
     geom: Tuple[int, int, int, int, int, int],
     valid: Tuple[Tuple[int, ...], Tuple[int, ...], Tuple[int, ...]],
     *,
+    share: Share,
     block: int = 512,
     interpret: bool = False,
 ):
     """B-config batched joint-pipeline simulation; returns
     (cache_hit, accel_tlb_hit, mem_tlb_hit), each bool [B, N], bit-identical
-    per config to the batched scan oracle on the same padded envelope."""
+    per config to the batched scan oracle on the same padded envelope.
+    ``share`` is the share map (module docstring)."""
     num_cfgs, n = c_set.shape
-    assert all(len(v) == num_cfgs for v in valid)
+    assert all(len(v) == num_cfgs for v in valid + share)
     block = min(block, n)
     assert n % block == 0, f"trace length {n} must be a multiple of block {block}"
     # Only the six key views stream through SMEM; budgeting the hit word as
@@ -304,15 +368,15 @@ def system_sim_batched_pallas(
     hit_spec, hit_shape = _hit_block(num_cfgs, n, block)
     hits = pl.pallas_call(
         functools.partial(
-            _system_batched_kernel, block=block, lays=lays, valid=valid,
+            _system_batched_kernel, block=block, share=share, lays=lays,
+            valid=valid,
         ),
         grid=(n // block,),
         in_specs=[stream] * 6
         + [pl.BlockSpec((num_cfgs, 3), lambda i: (0, 0), memory_space=pltpu.SMEM)],
         out_specs=hit_spec,
         out_shape=hit_shape,
-        scratch_shapes=[pltpu.VMEM((num_cfgs, lay.rows, LANES), jnp.int32)
-                        for lay in lays for _ in range(2)],
+        scratch_shapes=_state_scratch(share, lays),
         interpret=interpret,
         name="system_sim_batched",
     )(c_set.astype(jnp.int32), c_tag.astype(jnp.int32),

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.kernels.common import SWEEP_MODES, VALID_MODES, resolve_mode
 from repro.kernels.system_sim.kernel import (
+    Share,
     system_sim_batched_pallas,
     system_sim_batched_pallas_carry,
 )
@@ -53,6 +54,7 @@ def system_sim_batched(
     geom: Tuple[int, int, int, int, int, int],
     valid: Tuple[Tuple[int, ...], Tuple[int, ...], Tuple[int, ...]],
     *,
+    share: Share,
     block: int = 512,
     kernel_mode: str = "auto",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -61,7 +63,9 @@ def system_sim_batched(
     through ONE pass over the trace.  Returns (cache_hit, accel_tlb_hit,
     mem_tlb_hit) bool [B, N]; bit-identical per config to
     :func:`repro.core.tlbsim.simulate_system` on that config's own (unpadded)
-    geometry."""
+    geometry.  ``share`` maps each design to the representative whose
+    structures it shares (:mod:`repro.kernels.system_sim.kernel`); the
+    Pallas kernel probes only representatives, the scan every design."""
     mode = resolve_system_mode(kernel_mode)
     if mode == "reference":
         bools = tuple(flags[:, c].astype(bool) for c in range(3))
@@ -69,7 +73,7 @@ def system_sim_batched(
             (c_set, c_tag, a_set, a_tag, m_set, m_tag), bools, geom, valid)
     return system_sim_batched_pallas(
         c_set, c_tag, a_set, a_tag, m_set, m_tag, flags, geom, valid,
-        block=block, interpret=(mode == "pallas_interpret"))
+        share=share, block=block, interpret=(mode == "pallas_interpret"))
 
 
 def system_sim_batched_carry(
@@ -80,6 +84,8 @@ def system_sim_batched_carry(
     state,                                    # 6-tuple int32 [B, S, W]
     now0: int,                                # accesses consumed before chunk
     *,
+    share: Share,
+    length: int,
     block: int = 512,
     kernel_mode: str = "auto",
 ):
@@ -92,9 +98,14 @@ def system_sim_batched_carry(
 
     State layout contract: each structure's carried state must include one
     spare *parked* set row at its last index that no real access ever
-    indexes; Pallas chunks whose length is not a block multiple are padded
-    with accesses into those rows (stamps live only there, padded hit bits
-    dropped), so mid-stream padding is unobservable."""
+    indexes; Pallas chunks are padded to ``length`` accesses (at least the
+    chunk's, rounded up to whole kernel blocks) with accesses into those
+    rows, and the kernel runs only the blocks that hold the chunk's own
+    accesses (stamps of the pads live only in the parked rows, their hit
+    bits are dropped), so mid-stream padding is unobservable and chunks of
+    one ``length`` share one compiled kernel.  Each follower's carried state
+    under the ``share`` map must equal its representative's: it does from
+    fresh state on, in any mode."""
     mode = resolve_system_mode(kernel_mode)
     state = tuple(state)
     if mode == "reference":
@@ -103,7 +114,8 @@ def system_sim_batched_carry(
             (c_set, c_tag, a_set, a_tag, m_set, m_tag), bools,
             state, jnp.asarray(now0))
     n = int(c_set.shape[1])
-    pad = (-n) % min(block, n) if n else 0
+    length = max(int(length), n)
+    pad = length + (-length) % min(block, length) - n
     streams = [c_set, c_tag, a_set, a_tag, m_set, m_tag]
     if pad:
         for k in range(3):
@@ -114,6 +126,6 @@ def system_sim_batched_carry(
             streams[2 * k + 1] = jnp.concatenate(
                 [t, jnp.zeros((t.shape[0], pad), t.dtype)], axis=1)
     hits, state = system_sim_batched_pallas_carry(
-        *streams, flags, state, now0,
-        block=block, interpret=(mode == "pallas_interpret"))
+        *streams, flags, state, now0, n,
+        share=share, block=block, interpret=(mode == "pallas_interpret"))
     return tuple(h[:, :n] for h in hits), state

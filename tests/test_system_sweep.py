@@ -39,25 +39,28 @@ def _assert_rows_match(bev, cfgs, lines):
 # Oracle equivalence.
 # ---------------------------------------------------------------------------
 
+_HETEROGENEOUS = [
+    SystemSimConfig(),                               # cache, no accel TLB
+    SystemSimConfig(cache=None, num_partitions=8),   # cacheless
+    SystemSimConfig(accel_tlb=TLBConfig(entries=8, ways=4),
+                    num_partitions=4, accel_probe_on_miss_only=False),
+    SystemSimConfig(accel_tlb=TLBConfig(entries=2, ways=4),   # entries < ways
+                    page_shift=21, num_partitions=32),
+    SystemSimConfig(mem_tlb=TLBConfig(entries=64, ways=8)),
+    SystemSimConfig(cache=TLBConfig(entries=512, ways=8), num_partitions=16),
+    SystemSimConfig(cache=None, accel_tlb=TLBConfig(entries=16, ways=2),
+                    num_partitions=2, accel_probe_on_miss_only=False),
+    SystemSimConfig(page_shift=21, num_partitions=128),
+]
+
+
 @settings(deadline=None, max_examples=3)
 @given(st.integers(0, 10_000))
 def test_system_kernel_bitexact_vs_oracle_heterogeneous(seed):
     """All three backends on a heterogeneous batch: every structure-presence
     combination, both probe policies, mixed partitions and page sizes."""
     lines = _random_lines(seed)
-    cfgs = [
-        SystemSimConfig(),                               # cache, no accel TLB
-        SystemSimConfig(cache=None, num_partitions=8),   # cacheless
-        SystemSimConfig(accel_tlb=TLBConfig(entries=8, ways=4),
-                        num_partitions=4, accel_probe_on_miss_only=False),
-        SystemSimConfig(accel_tlb=TLBConfig(entries=2, ways=4),   # entries < ways
-                        page_shift=21, num_partitions=32),
-        SystemSimConfig(mem_tlb=TLBConfig(entries=64, ways=8)),
-        SystemSimConfig(cache=TLBConfig(entries=512, ways=8), num_partitions=16),
-        SystemSimConfig(cache=None, accel_tlb=TLBConfig(entries=16, ways=2),
-                        num_partitions=2, accel_probe_on_miss_only=False),
-        SystemSimConfig(page_shift=21, num_partitions=128),
-    ]
+    cfgs = _HETEROGENEOUS
     ref = sweep_system(lines, cfgs, kernel_mode="reference")
     pal = sweep_system(lines, cfgs, kernel_mode="pallas_interpret", block=256)
     _assert_rows_match(ref, cfgs, lines)
@@ -183,6 +186,8 @@ def test_system_mode_resolution():
 _GEOM = (17, 4, 9, 4, 33, 4)
 _VALID = ((4, 2, 4), (4, 4, 1), (2, 4, 4))
 _FLAGS = np.array([[1, 1, 1], [0, 1, 0], [1, 0, 1]], np.int32)
+# The share map of raw streams: every design its own representative.
+_IDENTITY = ((0, 1, 2),) * 3
 
 
 def _key_streams(seed: int, n: int):
@@ -216,7 +221,8 @@ def test_system_kernel_ragged_blocks_match_scan(n, block):
     streams = _key_streams(n + block, n)
     ref = system_sim_batched_ref(tuple(streams), _ref_flags(), _GEOM, _VALID)
     pal = system_sim_batched_pallas(*streams, jnp.asarray(_FLAGS), _GEOM,
-                                    _VALID, block=block, interpret=True)
+                                    _VALID, share=_IDENTITY, block=block,
+                                    interpret=True)
     for k, r, p in zip(HIT_KEYS, ref, pal):
         np.testing.assert_array_equal(np.asarray(p), np.asarray(r), err_msg=k)
 
@@ -245,7 +251,8 @@ def test_system_carry_kernel_ragged_chunks_match_scan(chunks, block):
     for size in chunks:
         part = [s[:, lo:lo + size] for s in streams]
         h_p, st_p = system_sim_batched_pallas_carry(
-            *part, flags, st_p, lo, block=block, interpret=True)
+            *part, flags, st_p, lo, size, share=_IDENTITY, block=block,
+            interpret=True)
         h_r, st_r = system_sim_batched_carry_ref(
             tuple(part), _ref_flags(), st_r, jnp.asarray(lo))
         got.append([np.asarray(h) for h in h_p])
@@ -287,3 +294,168 @@ def test_tlb_carry_kernel_ragged_chunks_match_scan(sets, ways, valid):
         np.testing.assert_array_equal(np.asarray(h_p), np.asarray(h_r))
     for a, b in zip(pal, ref):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# Shared structures: the share map and the kernel that probes only
+# representatives.
+# ---------------------------------------------------------------------------
+
+def _fig10_cfgs():
+    from benchmarks import fig10_performance as fig10
+
+    return fig10.configs()
+
+
+def _fig9_cfgs():
+    from benchmarks import fig9_accel_tlb as fig9
+
+    return fig9.configs()
+
+
+# Duplicate and unique structures of every kind: designs 0-3 and 7 share
+# one cache, 0 and 2 an accelerator TLB, 2 and 3 (same partitions, other
+# probe policy) a memory TLB, and the cacheless 4 and 5 everything.
+_SHARED = [
+    SystemSimConfig(accel_tlb=TLBConfig(entries=16, ways=4), num_partitions=4),
+    SystemSimConfig(num_partitions=4),
+    SystemSimConfig(accel_tlb=TLBConfig(entries=16, ways=4), num_partitions=8),
+    SystemSimConfig(accel_tlb=TLBConfig(entries=16, ways=4), num_partitions=8,
+                    accel_probe_on_miss_only=False),
+    SystemSimConfig(cache=None, num_partitions=4),
+    SystemSimConfig(cache=None, num_partitions=4),
+    SystemSimConfig(cache=TLBConfig(entries=64, ways=2), num_partitions=4),
+    SystemSimConfig(accel_tlb=TLBConfig(entries=16, ways=4), page_shift=21,
+                    num_partitions=4),
+]
+
+_DISTINCT = [
+    SystemSimConfig(cache=TLBConfig(entries=64 << i, ways=4),
+                    accel_tlb=TLBConfig(entries=8 << i, ways=2),
+                    num_partitions=1 << i)
+    for i in range(3)
+]
+
+
+@pytest.mark.parametrize("batch,want,probes", [
+    # One cache, the two conventional designs' accelerator TLBs, seven
+    # memory TLBs: conv-4K, DIPTA and ideal share 1 partition of 4K pages.
+    (_fig10_cfgs, ((0,) * 9, (0, 1) + (-1,) * 7, (0, 1, 2, 3, 4, 5, 6, 0, 0)),
+     10),
+    # One cache and the 8-partition memory TLB of every design but the
+    # baseline; every accelerator TLB differs in size or probe policy.
+    (_fig9_cfgs, ((0,) * 10, tuple(range(9)) + (-1,), (0,) + (1,) * 9), 12),
+    (lambda: _DISTINCT, ((0, 1, 2),) * 3, 9),
+    # Cacheless designs and designs without an accelerator TLB probe no
+    # such structure.
+    (lambda: [SystemSimConfig(cache=None), SystemSimConfig(cache=None)],
+     ((-1, -1), (-1, -1), (0, 0)), 1),
+    (lambda: _SHARED, ((0, 0, 0, 0, -1, -1, 6, 0), (0, -1, 0, 3, -1, -1, -1, 7),
+                       (0, 0, 2, 2, 4, 4, 6, 7)), 10),
+])
+def test_system_share_map(batch, want, probes):
+    share = sweep._system_share(batch())
+    assert share == want
+    assert sweep._probes_per_access(share) == probes
+
+
+def test_system_kernel_shared_structures_bitexact():
+    """A batch with duplicate and unique structures, whole, through the
+    monolithic kernel: every design's hit bits, followers' included, equal
+    the scan's and ``simulate_system``'s."""
+    lines = _random_lines(17, n=900)
+    ref = sweep_system(lines, _SHARED, kernel_mode="reference")
+    pal = sweep_system(lines, _SHARED, kernel_mode="pallas_interpret",
+                       block=256)
+    _assert_rows_match(ref, _SHARED, lines)
+    _assert_rows_match(pal, _SHARED, lines)
+
+
+def _assert_states_equal(got: dict, want: dict):
+    """Every design's every set row; the parked row that each structure's
+    state ends with holds only the pads' stamps (the carry op's state
+    layout contract), so it is left out."""
+    assert got.keys() == want.keys()
+    for k in want:
+        rows = slice(None) if k == "now" else np.s_[:, :-1]
+        np.testing.assert_array_equal(got[k][rows], want[k][rows], err_msg=k)
+
+
+@pytest.mark.parametrize("cuts,block", [((0, 256, 456, 500), 256),
+                                        ((0, 512, 712, 756), 128)])
+def test_system_carry_shared_structures_keep_every_state(cuts, block):
+    """Ragged chunks (after a full one, 200 and 44 accesses: no multiple of
+    128) of the duplicate-and-unique batch through the carry kernel, each
+    padded to the stream's longest chunk, so that with blocks of 128 the
+    kernel skips the grid steps past the chunk: hits equal
+    ``simulate_system``, every design's carried state (followers and absent
+    structures included) equals the carried scan's after every chunk, and a
+    stream resumed from ``export_state`` mid-trace (whose chunks pad to
+    another length) stays bit-identical."""
+    from repro.core.sweep import SystemSweepStream
+
+    lines = _random_lines(19, n=cuts[-1])
+    pal = SystemSweepStream(_SHARED, block=block)
+    ref = SystemSweepStream(_SHARED, block=block)
+    got = []
+    for lo, hi in zip(cuts, cuts[1:]):
+        got.append(pal.run_chunk(lines[lo:hi], kernel_mode="pallas_interpret"))
+        ref.run_chunk(lines[lo:hi], kernel_mode="reference")
+        _assert_states_equal(pal.export_state(), ref.export_state())
+        if lo == 0:
+            resumed = SystemSweepStream(_SHARED, block=block)
+            resumed.import_state(pal.export_state())
+    for lo, hi in zip(cuts[1:], cuts[2:]):
+        again = resumed.run_chunk(lines[lo:hi], kernel_mode="pallas_interpret")
+        for a, b in zip(again, got[cuts.index(lo)]):
+            np.testing.assert_array_equal(a, b)
+    _assert_states_equal(resumed.export_state(), pal.export_state())
+    whole = [np.concatenate([g[k] for g in got], axis=1) for k in range(3)]
+    _assert_rows_match(sweep.BatchedSystemEvents(*whole, n_warm=0),
+                       _SHARED, lines)
+
+
+def test_system_stream_traces_one_kernel_for_a_ragged_trace():
+    """A trace's tail chunks (200 and 44 accesses after a full 512) run in
+    the kernel its full chunk traced and compiled: one trace of the carry
+    kernel for the whole trace, with hits equal to ``simulate_system``
+    (lines from a narrow range, so every structure hits)."""
+    from repro.core.sweep import SystemSweepStream
+    from repro.kernels.system_sim.kernel import system_sim_batched_pallas_carry
+
+    lines = np.random.default_rng(23).integers(0, 2048, 756).astype(np.int64)
+    system_sim_batched_pallas_carry.clear_cache()
+    stream = SystemSweepStream(_DISTINCT, block=128)
+    got = [stream.run_chunk(lines[lo:hi], kernel_mode="pallas_interpret")
+           for lo, hi in ((0, 512), (512, 712), (712, 756))]
+    assert system_sim_batched_pallas_carry._cache_size() == 1
+    whole = [np.concatenate([g[k] for g in got], axis=1) for k in range(3)]
+    assert all(w[:, 512:].any() for w in whole)
+    _assert_rows_match(sweep.BatchedSystemEvents(*whole, n_warm=0),
+                       _DISTINCT, lines)
+
+
+@pytest.mark.parametrize("batch,mode,per_access", [
+    (_fig10_cfgs, "pallas_interpret", 10),
+    (_fig10_cfgs, "reference", 27),
+    # The heterogeneous batch above: two caches, three accelerator TLBs and
+    # eight memory TLBs present and distinct.
+    (lambda: _HETEROGENEOUS, "pallas_interpret", 13),
+])
+def test_system_stream_counts_lru_probes(batch, mode, per_access):
+    """``sweep_system.lru_probes`` counts the structures probed per access:
+    each representative once in the kernel, every structure in the scan."""
+    from repro.core.sweep import SystemSweepStream
+    from repro.runtime import telemetry
+
+    cfgs = batch()
+    n = 300
+    tr = telemetry.get_tracer()
+    tr.start_run(None, run="probes")
+    try:
+        stream = SystemSweepStream(cfgs, block=256)
+        stream.run_chunk(_random_lines(23, n=n), kernel_mode=mode)
+    finally:
+        counters = tr.end_run()["counters"]
+    assert counters["sweep_system.lru_probes"]["value"] == per_access * n
+    assert counters["sweep_system.sim_accesses"]["value"] == len(cfgs) * n

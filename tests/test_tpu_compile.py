@@ -64,15 +64,15 @@ def _state_specs(one_chip, stream):
             for k in state if k != "now"]
 
 
-# Compiles the fig10 system kernel for a described v5e with Mosaic's dumps
-# on; exits 77 where no v5e can be described.
+# Compiles the fig10 system kernel, with the stream's share map, for a
+# described v5e with Mosaic's dumps on; exits 77 where no v5e can be
+# described.
 _FIG10_DUMP = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from benchmarks import fig10_performance as fig10
     from repro.core.sweep import SystemSweepStream
-    from repro.core.tlbsim import SystemSimConfig
     from repro.kernels.system_sim.kernel import system_sim_batched_pallas_carry
 
     try:
@@ -84,46 +84,58 @@ _FIG10_DUMP = textwrap.dedent("""
     one_chip = SingleDeviceSharding(topo.devices[0])
     spec = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(
         tuple(shape), dtype, sharding=one_chip)
-    cfgs = [SystemSimConfig(
-        cache=fig10.CACHE,
-        accel_tlb=fig10.ACCEL_TLB if design == "conventional" else None,
-        mem_tlb=fig10.MEM_TLB, num_partitions=parts, page_shift=shift,
-        accel_probe_on_miss_only=True)
-        for _, parts, shift, design in fig10.CONFIGS]
+    cfgs = fig10.configs()
     stream = SystemSweepStream(cfgs)
     state = stream.export_state()
     keys = spec((len(cfgs), %d))
     jax.jit(lambda *a: system_sim_batched_pallas_carry(
-        *a[:7], tuple(a[7:13]), a[13], block=stream.block)).lower(
+        *a[:7], tuple(a[7:13]), a[13], a[14], share=stream._shares[0],
+        block=stream.block)).lower(
         *[keys] * 6, spec((len(cfgs), 3)),
         *[spec(state[k].shape, state[k].dtype) for k in state if k != "now"],
-        spec(())).compile()
+        spec(()), spec(())).compile()
 """ % CHUNK)
 
 
-def test_system_sim_probe_keeps_to_the_vector_unit(tmp_path):
-    """The fig10 system kernel's compiled access loop moves no value from
-    the vector unit to the scalar unit: its final LLO has no ``llo.vtos``.
-    (A probe that reduced the way choice to scalars had nine per design.)
+@pytest.fixture(scope="module")
+def fig10_llo(tmp_path_factory):
+    """The final LLO of the fig10 system kernel, compiled for a v5e.
 
     The dump flag is read when the TPU library loads, so the compile runs in
-    a child process, and this test comes first in the file: before the
-    ``one_chip`` fixture has loaded the library into this one.  Where the
-    child cannot load it or describe a v5e, the test skips."""
+    a child process, and the tests that use this come first in the file:
+    before the ``one_chip`` fixture has loaded the library into this one.
+    Where the child cannot load it or describe a v5e, they skip."""
+    out = tmp_path_factory.mktemp("fig10_dump")
     root = pathlib.Path(__file__).resolve().parents[1]
     env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
                PYTHONPATH=os.pathsep.join([str(root / "src"), str(root)]))
     env["LIBTPU_INIT_ARGS"] = (env.get("LIBTPU_INIT_ARGS", "")
-                               + f" --xla_mosaic_dump_to={tmp_path}").strip()
+                               + f" --xla_mosaic_dump_to={out}").strip()
     run = subprocess.run([sys.executable, "-c", _FIG10_DUMP], cwd=root,
                          env=env, capture_output=True, text=True, timeout=300)
-    llo = sorted(tmp_path.glob("*system_sim_carry-post-finalize-llo.txt"))
+    llo = sorted(out.glob("*system_sim_carry-post-finalize-llo.txt"))
     if run.returncode != 0 or not llo:
         pytest.skip(f"no Mosaic dump of the fig10 kernel (exit "
                     f"{run.returncode}): {run.stderr[-500:]}")
-    text = llo[-1].read_text()
-    assert "llo.vmin.xlane" in text   # the probe's way choice is in this dump
-    assert text.count("llo.vtos") == 0
+    return llo[-1].read_text()
+
+
+def test_system_sim_probe_keeps_to_the_vector_unit(fig10_llo):
+    """The fig10 system kernel's compiled access loop moves no value from
+    the vector unit to the scalar unit: its final LLO has no ``llo.vtos``.
+    (A probe that reduced the way choice to scalars had nine per design.)"""
+    assert "llo.vmin.xlane" in fig10_llo   # the probe's way choice is here
+    assert fig10_llo.count("llo.vtos") == 0
+
+
+def test_system_sim_probes_each_distinct_structure_once(fig10_llo):
+    """The fig10 batch's nine designs hold 27 structures, of which 10 are
+    distinct: one cache, two accelerator TLBs and seven memory TLBs.  The
+    compiled access loop probes each of the 10 once, in straight-line code:
+    three cross-lane ``min`` reductions per probe (hit way, LRU stamp, LRU
+    way), 30 in all, where a loop over the designs had 9 in its body and
+    ran them 27 times."""
+    assert fig10_llo.count("llo.vmin.xlane") == 3 * 10
 
 
 def test_tlb_sim_carry_compiles_for_the_8_config_sweep(one_chip):
@@ -150,21 +162,17 @@ def test_system_sim_carry_compiles_for_the_fig10_sweep(one_chip):
     from repro.core.tlbsim import SystemSimConfig
     from repro.kernels.system_sim.kernel import system_sim_batched_pallas_carry
 
-    cfgs = [SystemSimConfig(
-        cache=fig10.CACHE,
-        accel_tlb=fig10.ACCEL_TLB if design == "conventional" else None,
-        mem_tlb=fig10.MEM_TLB, num_partitions=parts, page_shift=shift,
-        accel_probe_on_miss_only=True)
-        for _, parts, shift, design in fig10.CONFIGS]
+    cfgs = fig10.configs()
     stream = SystemSweepStream(cfgs)
     assert len(stream.groups) == 1 and len(cfgs) == 9
     state = _state_specs(one_chip, stream)
     keys = _spec(one_chip, (len(cfgs), CHUNK))
     _assert_kernel(
         lambda *a: system_sim_batched_pallas_carry(
-            *a[:7], tuple(a[7:13]), a[13], block=stream.block),
+            *a[:7], tuple(a[7:13]), a[13], a[14], share=stream._shares[0],
+            block=stream.block),
         *[keys] * 6, _spec(one_chip, (len(cfgs), 3)), *state,
-        _spec(one_chip, ()))
+        _spec(one_chip, ()), _spec(one_chip, ()))
 
 
 def test_timeline_carry_compiles_for_the_fig11_matrix(one_chip):
@@ -234,6 +242,7 @@ def _lru_case(one_chip, name):
     keys, state = _spec(one_chip, (2, 512)), _spec(one_chip, (2, 17, 4))
     now, flags = _spec(one_chip, ()), _spec(one_chip, (2, 3))
     geom, valid = (17, 4) * 3, ((4, 4),) * 3
+    share = ((0, 1),) * 3
     return {
         "tlb_sim": (lambda s, t: tlb_sim.tlb_sim_pallas(s, t, 16, 4, block=128),
                     [_spec(one_chip, (512,))] * 2),
@@ -247,12 +256,12 @@ def _lru_case(one_chip, name):
             [keys, keys, state, state, now]),
         "system_sim_batched": (
             lambda *a: system_sim.system_sim_batched_pallas(
-                *a, geom, valid, block=128),
+                *a, geom, valid, share=share, block=128),
             [keys] * 6 + [flags]),
         "system_sim_carry": (
             lambda *a: system_sim.system_sim_batched_pallas_carry(
-                *a[:7], tuple(a[7:13]), a[13], block=128),
-            [keys] * 6 + [flags] + [state] * 6 + [now]),
+                *a[:7], tuple(a[7:13]), a[13], a[14], share=share, block=128),
+            [keys] * 6 + [flags] + [state] * 6 + [now, now]),
     }[name]
 
 
