@@ -62,16 +62,10 @@ def run_config(fig: str, *, resume: bool = False, chunk_accesses=None):
     driver: checkpoints live under ``_cache/ckpt/<fig>/`` (one blob per
     engine call), ``resume`` re-enters them, ``chunk_accesses`` overrides
     the commit granularity (the CI fault-injection smoke shrinks it so a
-    quick run still crosses several chunk boundaries).  ``calibration_dir``
-    points ``kernel_mode="auto"`` at the measured-rate tables under
-    ``_cache/calibration/`` (fed by kernel_bench and every orchestrated
-    run), so bench drivers pick backends by measured speed — library users
-    and tests that build their own ``SweepRunConfig`` stay on the
-    deterministic cold-start heuristics."""
+    quick run still crosses several chunk boundaries)."""
     from repro.core.orchestrator import SweepRunConfig
 
-    kw = {"checkpoint_dir": str(CACHE / "ckpt" / fig), "resume": bool(resume),
-          "calibration_dir": str(CACHE / "calibration")}
+    kw = {"checkpoint_dir": str(CACHE / "ckpt" / fig), "resume": bool(resume)}
     if chunk_accesses:
         kw["chunk_accesses"] = int(chunk_accesses)
     return SweepRunConfig(**kw)

@@ -245,25 +245,6 @@ def _append_bench_entry(entry: dict) -> None:
         os.replace(tmp, BENCH_SWEEP_PATH)
 
 
-def _record_calibration(entry: dict) -> None:
-    """Feed this bench's measured per-backend times into the dispatch
-    calibration table (``_cache/calibration/``): the engine benches time
-    every backend head-to-head, which is exactly the evidence
-    ``kernel_mode="auto"`` needs to stop picking ``pallas_interpret`` where
-    the batched scan is measured faster.  Best-effort — a calibration
-    failure must not fail the bench itself."""
-    from benchmarks.common import CACHE
-    from repro.core import dispatch
-
-    try:
-        store = dispatch.CalibrationStore.for_dir(CACHE / "calibration")
-        n = dispatch.ingest_bench_entries(
-            store, [{**benchtime.device_metadata(), **entry}])
-        print(f"  calibration: {n} backend rate(s) recorded -> {store.path.name}")
-    except (OSError, dispatch.CalibrationCorruptError) as e:
-        print(f"  calibration: NOT recorded ({e})")
-
-
 def _sweep_bench(quick: bool):
     """fig4-style sweep: batched-scan reference vs the stack-distance backend
     (plus the Pallas TPU kernel where a TPU backend is available).
@@ -327,7 +308,6 @@ def _sweep_bench(quick: bool):
     assert entry.get("pallas_bit_identical", True), \
         "pallas sweep diverged from the batched-scan oracle"
     _append_bench_entry(entry)
-    _record_calibration(entry)
 
 
 def _timeline_bench(quick: bool):
@@ -395,7 +375,6 @@ def _timeline_bench(quick: bool):
     # Assert BEFORE recording (see _sweep_bench).
     assert bit_identical, "timeline kernel diverged from the lax.scan oracle"
     _append_bench_entry(entry)
-    _record_calibration(entry)
 
 
 def _timeline_batched_bench(quick: bool):
@@ -500,7 +479,6 @@ def _timeline_batched_bench(quick: bool):
     assert bit_identical, "sweep_timeline diverged from the per-sim oracle"
     assert pallas_identical, "batched timeline kernel diverged from the per-sim oracle"
     _append_bench_entry(entry)
-    _record_calibration(entry)
 
 
 def _system_batched_bench(quick: bool):
@@ -599,7 +577,6 @@ def _system_batched_bench(quick: bool):
     assert bit_identical, "sweep_system diverged from the per-config oracle"
     assert pallas_identical, "batched system kernel diverged from the per-config oracle"
     _append_bench_entry(entry)
-    _record_calibration(entry)
 
 
 # Every engine the bench suite gates: ``--check`` fails when a bench has no

@@ -4,12 +4,9 @@
 header (name, device, clean/errored end), the per-phase wall-clock breakdown
 (span name -> count / total seconds, sorted by where the time went), the
 achieved per-(engine, backend) throughput from the orchestrator's ``chunk``
-spans, a predicted-vs-achieved backend-dispatch table (each ``dispatch``
-event's calibrated per-candidate rate predictions against what the run's
-chunk spans actually achieved), a throughput timeline (chunk-by-chunk
-accesses/s against the run's monotonic clock), and the structured-event
-table (retries, halves, downgrades, resumes, preemptions, checkpoint
-writes).
+spans, a throughput timeline (chunk-by-chunk accesses/s against the run's
+monotonic clock), and the structured-event table (retries, halves,
+downgrades, resumes, preemptions, checkpoint writes).
 
 Sharded scheduler runs write one log per *worker process*
 (``<run>-wN-<pid>.jsonl``) beside the parent's: a positional argument may be
@@ -156,38 +153,6 @@ def scheduler_events(recs: List[dict]) -> List[dict]:
             and r.get("attrs", {}).get("kind") == "scheduler"]
 
 
-def dispatch_table(recs: List[dict]) -> List[dict]:
-    """Predicted-vs-achieved backend dispatch rows: one per (engine call,
-    candidate mode), pairing each ``dispatch`` event's calibrated rate
-    predictions with the rates the run actually achieved (from its ``chunk``
-    spans, simulated accesses per second)."""
-    achieved: Dict[Tuple[str, str, str], dict] = {}
-    for r in _chunk_spans(recs):
-        a = r.get("attrs", {})
-        key = (str(a.get("engine", "?")), str(a.get("name", "?")),
-               str(a.get("mode", "?")))
-        st = achieved.setdefault(key, {"sim_accesses": 0, "elapsed_s": 0.0})
-        st["sim_accesses"] += (int(a.get("accesses", 0) or 0)
-                               * int(a.get("configs", 1) or 1))
-        st["elapsed_s"] += float(r.get("dur_s", 0.0))
-    rows = []
-    for r in recs:
-        if r.get("kind") != "event" or r.get("name") != "dispatch":
-            continue
-        a = r.get("attrs", {})
-        eng, name, chosen = a.get("engine"), a.get("name"), a.get("mode")
-        for mode, rate in (a.get("candidates") or {}).items():
-            st = achieved.get((str(eng), str(name), str(mode)))
-            ach = (st["sim_accesses"] / st["elapsed_s"]
-                   if st and st["elapsed_s"] > 0 else None)
-            rows.append({
-                "engine": eng, "name": name, "mode": mode,
-                "chosen": mode == chosen, "predicted_rate": rate,
-                "achieved_rate": ach, "calibration": a.get("calibration"),
-            })
-    return rows
-
-
 def event_counts(recs: List[dict]) -> Dict[str, int]:
     counts: Dict[str, int] = {}
     for r in recs:
@@ -254,16 +219,6 @@ def render(path: pathlib.Path, recs: List[dict]) -> None:
                                         "duplicate", "owner")
                 if k in a and a[k] is not None)
             print(f"    {t}  {r['name']:<20} {detail}")
-
-    disp = dispatch_table(recs)
-    if disp:
-        print("  ## dispatch (predicted vs achieved, sim accesses/s)")
-        for row in disp:
-            mark = "*" if row["chosen"] else " "
-            print(f"   {mark} {str(row['name']):<16} {str(row['mode']):<18} "
-                  f"predicted={_fmt_rate(row['predicted_rate'])} "
-                  f"achieved={_fmt_rate(row['achieved_rate'])}  "
-                  f"[{row['calibration']}]")
 
     timeline = throughput_timeline(recs)
     if timeline:

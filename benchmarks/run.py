@@ -22,11 +22,8 @@ pure-TLB sweep figures.  fig5 is a hybrid: its miss-ratio grid threads the
 mode through (``stackdist`` applies), and its system-sweep/timeline half
 falls back to ``auto`` for sweep-only modes with a warning logged through
 the ``repro.bench.fig5`` logger on stderr (never stdout — piped CSV output
-stays machine-clean).  ``auto`` itself resolves through the calibrated
-dispatch layer (``repro.core.dispatch``; tables under
-``_cache/calibration/``, fed by the kernel benches and every orchestrated
-run) — ``--explain-dispatch`` prints the decision tables without running
-any sweep."""
+stays machine-clean).  ``auto`` itself resolves through one static rule
+(``repro.kernels.common.auto_mode``)."""
 from __future__ import annotations
 
 import argparse
@@ -45,66 +42,6 @@ _LOG = logging.getLogger("repro.bench.run")
 
 FIGS = ("fig2", "fig4", "fig5", "fig6", "fig7", "fig8", "fig9", "fig10",
         "fig11", "kernels")
-
-
-def _explain_dispatch() -> None:
-    """Print the dispatch decision tables for the three engines' canonical
-    quick shapes — candidates, calibrated rates, predicted runtimes, the
-    chosen mode and why — without running any sweep.  An empty calibration
-    table is bootstrapped from whatever this checkout already measured
-    (BENCH_sweep.json rows + run-log chunk spans for this device kind)."""
-    from benchmarks import common
-    from benchmarks.kernel_bench import BENCH_SWEEP_PATH
-    from repro.core import dispatch
-    from repro.core.sparta import TLBConfig
-    from repro.core.sweep import TLBSweepSpec
-    from repro.core.tlbsim import SystemSimConfig
-
-    store = dispatch.CalibrationStore.for_dir(common.CACHE / "calibration")
-    if not store.exists():
-        n = dispatch.ingest_bench_history(store, BENCH_SWEEP_PATH)
-        n += dispatch.ingest_runlogs(
-            store, sorted(common.RUNLOGS.glob("*.jsonl"))
-            if common.RUNLOGS.exists() else [])
-        _LOG.info("bootstrapped %s from %d recorded rate(s)", store.path, n)
-    print(f"# dispatch decisions ({store.describe()}, "
-          f"device={store.device_kind})")
-
-    tlb_specs = [
-        TLBSweepSpec(TLBConfig(entries=e, ways=4), num_partitions=p,
-                     page_shift=12)
-        for p in (1, 128) for e in (64, 128, 256, 512)]
-    cache = TLBConfig(entries=256, ways=4)
-    mem = TLBConfig(entries=128, ways=4)
-    sys_cfgs = [
-        SystemSimConfig(cache=cache, accel_tlb=None, mem_tlb=mem,
-                        num_partitions=p, page_shift=12)
-        for p in (1, 8, 32)]
-    decisions = [
-        ("fig4-style TLB sweep (8 specs x 120k accesses)",
-         dispatch.decide_tlb("auto", tlb_specs, n_accesses=120_000,
-                             store=store)),
-        ("fig9-style system sweep (3 configs x 10k accesses)",
-         dispatch.decide_system("auto", sys_cfgs, n_accesses=10_000,
-                                store=store)),
-        ("fig11-quick timeline matrix (batch=12 x 8k accesses)",
-         dispatch.decide_timeline("auto", batch=12, n_accesses=8_000,
-                                  store=store)),
-        ("single timeline sim (batch=1 x 8k accesses)",
-         dispatch.decide_timeline("auto", batch=1, n_accesses=8_000,
-                                  store=store)),
-    ]
-    print("engine,candidate,rate_sim_acc_per_s,predicted_s,chosen")
-    for label, d in decisions:
-        print(f"# {label}")
-        for m, c in d.candidates.items():
-            rate = c.get("rate")
-            pred = c.get("predicted_s")
-            print(f"{d.engine},{m},"
-                  f"{rate if rate is not None else 'n/a'},"
-                  f"{pred if pred is not None else 'n/a'},"
-                  f"{'<-- chosen' if m == d.mode else ''}")
-        print(f"#   -> {d.mode} [{d.calibration}]: {d.reason}")
 
 
 def main(argv=None) -> None:
@@ -133,16 +70,8 @@ def main(argv=None) -> None:
                     help="scheduler executor (auto = thread when --workers>1)")
     ap.add_argument("--gc", action="store_true",
                     help="garbage-collect expired checkpoint blobs and stale "
-                         "leases under benchmarks/_cache/ckpt plus stale "
-                         "dispatch calibration tables under "
-                         "benchmarks/_cache/calibration, then exit "
+                         "leases under benchmarks/_cache/ckpt, then exit "
                          "(in-progress runs — fresh leases — are kept)")
-    ap.add_argument("--explain-dispatch", action="store_true",
-                    help="print the backend-dispatch decision tables "
-                         "(candidates, predicted rates, chosen mode, "
-                         "calibration provenance) for the three engines' "
-                         "canonical quick shapes, then exit without running "
-                         "any sweep")
     ap.add_argument("--gc-age-s", type=float, default=7 * 86400.0, metavar="S",
                     help="age threshold for --gc (default: 7 days)")
     ap.add_argument("-v", action="count", default=0, dest="verbose",
@@ -157,7 +86,6 @@ def main(argv=None) -> None:
 
     if args.gc:
         from benchmarks import common
-        from repro.core.dispatch import gc_calibration
         from repro.core.scheduler import gc_checkpoints
 
         summary = gc_checkpoints(common.CACHE / "ckpt", age_s=args.gc_age_s)
@@ -169,18 +97,6 @@ def main(argv=None) -> None:
               f"{len(summary['kept_in_progress'])} in-progress kept, "
               f"{len(summary['kept_young'])} young kept, "
               f"{len(summary['skipped_foreign'])} foreign skipped")
-        cal = gc_calibration(common.CACHE / "calibration", age_s=args.gc_age_s)
-        print(f"# gc {common.CACHE / 'calibration'}")
-        for k in ("deleted", "kept_young", "skipped_foreign"):
-            for p in cal[k]:
-                print(f"{k},{p}")
-        print(f"# {len(cal['deleted'])} calibration deleted, "
-              f"{len(cal['kept_young'])} young kept, "
-              f"{len(cal['skipped_foreign'])} foreign skipped")
-        return
-
-    if args.explain_dispatch:
-        _explain_dispatch()
         return
 
     from repro.runtime.compile_cache import configure_compile_cache

@@ -32,8 +32,8 @@ than the one asked for.  Each phase prints one line (wall and compile
 seconds, the mode that ran, the ladder event count, the comparison); the
 last line of stdout is one JSON object, ``"ok"`` true only if every phase
 passed.  Wall times here include compilation and host work: they are a
-smoke run's, not device metrics.  The sweeps run without checkpoints or
-calibration tables, so nothing is read from disk.
+smoke run's, not device metrics.  The sweeps run without checkpoints, so
+nothing is read from disk.
 """
 from __future__ import annotations
 
@@ -309,7 +309,7 @@ def main(argv=None) -> int:
     import jax
 
     clock = _CompileClock()
-    rc = SweepRunConfig(checkpoint_dir=None, calibration_dir=None)
+    rc = SweepRunConfig(checkpoint_dir=None)
     ok = True
     for phase in (phase_tlb, phase_system, phase_timeline):
         ok = phase(rc, clock, args.seed) and ok

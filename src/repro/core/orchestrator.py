@@ -61,6 +61,7 @@ import random
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.checkpoint.checkpoint import (
@@ -68,18 +69,20 @@ from repro.checkpoint.checkpoint import (
     read_checkpoint_blob,
     write_checkpoint_blob,
 )
-from repro.core import dispatch
 from repro.core.sweep import (
     BatchedSystemEvents,
     BatchedTLBResult,
     SystemSweepStream,
     TLBSweepSpec,
     TLBSweepStream,
+    resolve_tlb_mode,
     sweep_tlb,
 )
 from repro.core.timeline import TimelineResult, TimelineSpec, TimelineSweepStream
 from repro.core.tlbsim import SystemSimConfig
-from repro.kernels.common import interpret_allowed, resolve_mode
+from repro.kernels.common import interpret_allowed
+from repro.kernels.system_sim import resolve_system_mode
+from repro.kernels.timeline import resolve_timeline_mode
 from repro.runtime import telemetry
 from repro.runtime.fault_tolerance import (
     PreemptionHandler,
@@ -105,6 +108,7 @@ __all__ = [
     "run_sweep_system",
     "run_sweep_timeline",
     "merge_throughput",
+    "backend_record",
 ]
 
 # Degradation ladder, fastest first; a run enters at its resolved mode and
@@ -121,6 +125,16 @@ def ladder_from(mode: str) -> Tuple[str, ...]:
     return rungs[rungs.index(mode):]
 
 CKPT_FORMAT = "repro-sweep-ckpt-v1"
+
+
+def backend_record(engine: str, requested: str, mode: str) -> dict:
+    """``meta["dispatch"]``: the backend an engine call runs and the rule
+    that chose it (:func:`repro.kernels.common.auto_mode` under ``"auto"``)."""
+    reason = (f"auto: {mode} on a {jax.default_backend()} backend"
+              if requested == "auto"
+              else f"kernel_mode={requested!r} given explicitly")
+    return {"engine": engine, "requested": requested, "mode": mode,
+            "reason": reason}
 
 
 class Preempted(BaseException):
@@ -151,17 +165,9 @@ class SweepRunConfig:
     simulated transient faults there; ``on_chunk_committed(chunk_idx)``
     fires after a chunk's checkpoint is durably on disk — the harness
     raises a simulated hard kill there.
-
-    ``calibration_dir`` points ``kernel_mode="auto"`` at a measured-rate
-    calibration table directory (:mod:`repro.core.dispatch`) and feeds
-    achieved rates back into it after every run.  ``None`` (the default)
-    keeps decisions on the deterministic cold-start heuristics —
-    calibration is strictly opt-in so test/library behavior never depends
-    on what a particular machine has measured.
     """
 
     checkpoint_dir: Optional[str] = None
-    calibration_dir: Optional[str] = None
     resume: bool = False
     chunk_accesses: int = 65_536
     max_retries: int = 2
@@ -183,11 +189,10 @@ class _ChunkRunner:
 
     def __init__(self, stream, total: int, out_names: Sequence[str],
                  out_dtypes: Sequence, run_chunk: Callable,
-                 start_mode: str, cfg: SweepRunConfig, *, name: str,
-                 trace_sha: str,
-                 decision: Optional[dispatch.DispatchDecision] = None):
+                 decision: dict, cfg: SweepRunConfig, *, name: str,
+                 trace_sha: str):
         self.stream = stream
-        self.decision = decision
+        self.decision = decision       # backend_record(); its mode starts the ladder
         self.total = int(total)
         self.out_names = tuple(out_names)
         self.run_chunk = run_chunk     # (lo, hi, mode) -> tuple of [B, L]
@@ -201,8 +206,7 @@ class _ChunkRunner:
         # thence the figure-JSON _telemetry stamp) — recorded even with the
         # tracer disabled, it is plain accumulation.
         self.throughput: dict = {}
-        start_mode = resolve_mode(start_mode)  # never "auto" past this point
-        self.ladder = ladder_from(start_mode)
+        self.ladder = ladder_from(decision["mode"])
         self.rung = 0
         self.events: List[dict] = []
         self.chunks_committed = 0
@@ -231,8 +235,7 @@ class _ChunkRunner:
             "events": self.events,
             "chunks_committed": (self.chunks_committed if chunks_committed
                                  is None else chunks_committed),
-            "dispatch": (self.decision.to_json() if self.decision is not None
-                         else None),
+            "dispatch": self.decision,
         }
 
     def _write_checkpoint(self, completed: bool, *,
@@ -275,19 +278,17 @@ class _ChunkRunner:
         for nm, buf in zip(self.out_names, self.bufs):
             buf[:, :now] = arrays[f"r_{nm}"]
         self.events = list(meta.get("events", []))
-        # Resume-stickiness: the checkpointed run's DispatchDecision wins
-        # over whatever this process just decided — a calibration table that
-        # changed between runs must never flip the backend mid-stream (the
-        # resumed tail has to be bit-identical to the uninterrupted run).
-        dd = meta.get("dispatch")
-        if dd:
-            blob_dec = dispatch.DispatchDecision.from_json(dd)
-            if blob_dec.mode in LADDER:
-                self.ladder = ladder_from(blob_dec.mode)
-                self.rung = 0
-            self.decision = dataclasses.replace(
-                blob_dec, reason=blob_dec.reason + " (reused from checkpoint)",
-                calibration=f"checkpoint:{blob_dec.calibration}")
+        # Resume-stickiness: the run goes on in the checkpoint's mode, not in
+        # the one this call picked (an explicit kernel_mode may differ): the
+        # ladder starts at the checkpointed run's mode and stands on its
+        # rung, so the resumed tail is bit-identical to the uninterrupted run.
+        # Blobs of any age keep that start mode under dispatch["mode"].
+        dd = meta.get("dispatch") or {}
+        if dd.get("mode") in LADDER:
+            self.ladder = ladder_from(dd["mode"])
+            self.decision = {k: str(dd.get(k, v))
+                             for k, v in self.decision.items()}
+            self.decision["reason"] += " (resumed from checkpoint)"
         mode = meta.get("mode")
         if mode in self.ladder:   # sticky downgrade survives the restart
             self.rung = self.ladder.index(mode)
@@ -455,8 +456,7 @@ class _ChunkRunner:
             "completed_from_checkpoint": completed_from_checkpoint,
             "checkpoint": str(self.path) if self.path else None,
             "throughput": _throughput_meta(self.throughput),
-            "dispatch": (self.decision.to_json() if self.decision is not None
-                         else None),
+            "dispatch": self.decision,
         }
 
 
@@ -540,29 +540,26 @@ def run_sweep_tlb(
         handler = None
         try:
             with tracer.span("engine.prepare"):
-                store = dispatch.store_for(run.calibration_dir)
-                decision = dispatch.decide_tlb(
-                    kernel_mode, specs, n_accesses=n, store=store)
-                dispatch.record_decision(decision, name=name)
-                if decision.mode != "stackdist":
+                decision = backend_record(
+                    "sweep_tlb", kernel_mode,
+                    resolve_tlb_mode(kernel_mode, specs))
+                if decision["mode"] != "stackdist":
                     run, handler = _maybe_handler(run)
                     stream = TLBSweepStream(specs, block=block)
                     runner = _ChunkRunner(
                         stream, n, ("hits",), (bool,),
                         lambda lo, hi, m: (stream.run_chunk(
                             addrs[lo:hi], kernel_mode=m),),
-                        decision.mode, run, name=name,
-                        trace_sha=_sha256_arrays(addrs), decision=decision)
+                        decision, run, name=name,
+                        trace_sha=_sha256_arrays(addrs))
                     done = runner.try_resume()
-            if decision.mode == "stackdist":
-                return _run_stackdist(addrs, specs, decision, store,
+            if decision["mode"] == "stackdist":
+                return _run_stackdist(addrs, specs, decision,
                                       warmup_frac=warmup_frac, block=block,
                                       name=name)
             meta = (runner.meta(completed_from_checkpoint=True) if done
                     else runner.run())
             with tracer.span("engine.finish"):
-                dispatch.observe(runner.decision, meta.get("throughput") or {},
-                                 store=store, name=name)
                 n0 = int(n * warmup_frac)
                 return BatchedTLBResult(hits=runner.bufs[0], n_warm=n - n0), meta
         finally:
@@ -570,13 +567,13 @@ def run_sweep_tlb(
                 handler.uninstall()
 
 
-def _run_stackdist(addrs, specs, decision, store, *, warmup_frac, block,
+def _run_stackdist(addrs, specs, decision, *, warmup_frac, block,
                    name) -> Tuple[BatchedTLBResult, dict]:
     """The monolithic stackdist TLB sweep, still measured: its achieved
     accesses/s lands in meta["throughput"] (and one whole-trace ``chunk``
     span) like the streamed backends'."""
     tracer = telemetry.get_tracer()
-    n, mode = int(addrs.shape[0]), decision.mode
+    n, mode = int(addrs.shape[0]), decision["mode"]
     with tracer.span("chunk", lo=0, hi=n, mode=mode, attempt=0) as span:
         t0 = time.perf_counter()
         res = sweep_tlb(addrs, specs, warmup_frac=warmup_frac,
@@ -591,13 +588,12 @@ def _run_stackdist(addrs, specs, decision, store, *, warmup_frac, block,
         agg = {mode: {"chunks": 1, "accesses": n,
                       "sim_accesses": n * len(specs), "elapsed_s": dur}}
         throughput = _throughput_meta(agg)
-        dispatch.observe(decision, throughput, store=store, name=name)
         return res, {"engine": "sweep_tlb", "resumable": False,
                      "start_mode": mode, "final_mode": mode, "events": [],
                      "chunks_committed": 0, "resumed_from": None,
                      "completed_from_checkpoint": False, "checkpoint": None,
                      "throughput": throughput,
-                     "dispatch": decision.to_json()}
+                     "dispatch": decision}
 
 
 def run_sweep_system(
@@ -621,10 +617,9 @@ def run_sweep_system(
         handler = None
         try:
             with tracer.span("engine.prepare"):
-                store = dispatch.store_for(run.calibration_dir)
-                decision = dispatch.decide_system(
-                    kernel_mode, cfgs, n_accesses=n, store=store)
-                dispatch.record_decision(decision, name=name)
+                decision = backend_record(
+                    "sweep_system", kernel_mode,
+                    resolve_system_mode(kernel_mode))
                 run, handler = _maybe_handler(run)
                 stream = SystemSweepStream(cfgs, block=block)
                 runner = _ChunkRunner(
@@ -632,14 +627,12 @@ def run_sweep_system(
                     (bool, bool, bool),
                     lambda lo, hi, m: stream.run_chunk(lines[lo:hi],
                                                        kernel_mode=m),
-                    decision.mode, run, name=name,
-                    trace_sha=_sha256_arrays(lines), decision=decision)
+                    decision, run, name=name,
+                    trace_sha=_sha256_arrays(lines))
                 done = runner.try_resume()
             meta = (runner.meta(completed_from_checkpoint=True) if done
                     else runner.run())
             with tracer.span("engine.finish"):
-                dispatch.observe(runner.decision, meta.get("throughput") or {},
-                                 store=store, name=name)
                 n0 = int(n * warmup_frac)
                 return BatchedSystemEvents(*runner.bufs, n_warm=n - n0), meta
         finally:
@@ -666,26 +659,21 @@ def run_sweep_timeline(
         handler = None
         try:
             with tracer.span("engine.prepare"):
-                store = dispatch.store_for(run.calibration_dir)
-                decision = dispatch.decide_timeline(
-                    kernel_mode, batch=len(specs), n_accesses=n_acc,
-                    store=store)
-                dispatch.record_decision(decision, name=name)
+                decision = backend_record(
+                    "sweep_timeline", kernel_mode,
+                    resolve_timeline_mode(kernel_mode, batch=len(specs)))
                 run, handler = _maybe_handler(run)
                 stream = TimelineSweepStream(specs, lat, block=block)
                 runner = _ChunkRunner(
                     stream, stream.n, ("latency", "overhead", "done"),
                     (np.float32, np.float32, np.float32),
                     lambda lo, hi, m: stream.run_chunk(lo, hi, kernel_mode=m),
-                    decision.mode, run, name=name,
-                    trace_sha=_sha256_arrays(*stream._stacked),
-                    decision=decision)
+                    decision, run, name=name,
+                    trace_sha=_sha256_arrays(*stream._stacked))
                 done = runner.try_resume()
             meta = (runner.meta(completed_from_checkpoint=True) if done
                     else runner.run())
             with tracer.span("engine.finish"):
-                dispatch.observe(runner.decision, meta.get("throughput") or {},
-                                 store=store, name=name)
                 return stream.finalize(*runner.bufs), meta
         finally:
             if handler is not None:

@@ -66,6 +66,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.checkpoint.checkpoint import (
@@ -77,22 +78,25 @@ from repro.checkpoint.checkpoint import (
     refresh_lease,
     release_lease,
 )
-from repro.core import dispatch
 from repro.core import orchestrator as orch
 from repro.core.orchestrator import (
     LADDER,
     Preempted,
     SweepRunConfig,
     _maybe_handler,
+    backend_record,
     merge_throughput,
 )
 from repro.core.sweep import (
     BatchedSystemEvents,
     BatchedTLBResult,
     TLBSweepSpec,
+    resolve_tlb_mode,
 )
 from repro.core.timeline import TimelineResult, TimelineSpec
 from repro.core.tlbsim import SystemSimConfig
+from repro.kernels.system_sim import resolve_system_mode
+from repro.kernels.timeline import resolve_timeline_mode
 from repro.runtime import telemetry
 from repro.runtime.fault_tolerance import PreemptionHandler
 
@@ -539,8 +543,8 @@ def _schedule(*, engine: str, payload: Callable[[int, int], dict],
     tracer = telemetry.get_tracer()
     n_shards = sched.resolve_shards(n_items)
     kind = sched.resolve_executor()
-    if kind == "process" and dispatch.default_backend() == "tpu":
-        # The backend decision above already touched JAX, so this process
+    if kind == "process" and jax.default_backend() == "tpu":
+        # The backend choice above already touched JAX, so this process
         # holds the chip; a spawned worker that needs it fails or hangs.
         raise ValueError(
             "executor='process' cannot run on a TPU backend: this process "
@@ -826,15 +830,10 @@ def run_sweep_tlb(
             block=block, run=run, name=name)
     addrs = np.asarray(addrs)
     specs = list(specs)
-    # The dispatch decision is made ONCE over the full spec set (stackdist
-    # eligibility and calibration lookups are properties of the whole sweep)
-    # and passed concrete to every shard, so sharding can never flip the
-    # backend choice.
-    decision = dispatch.decide_tlb(
-        kernel_mode, specs, n_accesses=int(addrs.shape[0]),
-        store=dispatch.store_for(run.calibration_dir))
-    dispatch.record_decision(decision, name=name)
-    mode = decision.mode
+    # The backend is chosen ONCE over the full spec set (stackdist
+    # eligibility is a property of the whole sweep) and passed concrete to
+    # every shard, so sharding can never flip the backend choice.
+    mode = resolve_tlb_mode(kernel_mode, specs)
     n = int(addrs.shape[0])
     shards, meta = _schedule(
         engine="sweep_tlb",
@@ -842,7 +841,7 @@ def run_sweep_tlb(
                                 "warmup_frac": warmup_frac, "block": block,
                                 "mode": mode},
         n_items=len(specs), mode=mode, run_cfg=run, sched=sched, name=name)
-    meta["dispatch"] = decision.to_json()
+    meta["dispatch"] = backend_record("sweep_tlb", kernel_mode, mode)
     rows = [np.zeros((sh.hi - sh.lo, n), bool) if sh.arrays is None
             else np.asarray(sh.arrays["hits"], bool)
             for sh in shards]
@@ -869,12 +868,8 @@ def run_sweep_system(
             block=block, run=run, name=name)
     lines = np.asarray(lines)
     cfgs = list(cfgs)
-    # Decided once globally (see run_sweep_tlb): shards get a concrete mode.
-    decision = dispatch.decide_system(
-        kernel_mode, cfgs, n_accesses=int(lines.shape[0]),
-        store=dispatch.store_for(run.calibration_dir))
-    dispatch.record_decision(decision, name=name)
-    mode = decision.mode
+    # Chosen once globally (see run_sweep_tlb): shards get a concrete mode.
+    mode = resolve_system_mode(kernel_mode)
     n = int(lines.shape[0])
     shards, meta = _schedule(
         engine="sweep_system",
@@ -882,7 +877,7 @@ def run_sweep_system(
                                 "warmup_frac": warmup_frac, "block": block,
                                 "mode": mode},
         n_items=len(cfgs), mode=mode, run_cfg=run, sched=sched, name=name)
-    meta["dispatch"] = decision.to_json()
+    meta["dispatch"] = backend_record("sweep_system", kernel_mode, mode)
     cols = {}
     for nm in ("cache_hit", "accel_tlb_hit", "mem_tlb_hit"):
         cols[nm] = np.concatenate(
@@ -910,22 +905,16 @@ def run_sweep_timeline(
             specs, lat, kernel_mode=kernel_mode, block=block, run=run,
             name=name)
     specs = list(specs)
-    # The batch-aware decision must see the GLOBAL batch size, not a
-    # shard's — otherwise a single-spec shard would flip to the scan path
-    # and the merged run would not be bit-identical to the unsharded one.
-    decision = dispatch.decide_timeline(
-        kernel_mode, batch=len(specs),
-        n_accesses=max((int(np.asarray(sp.lines).shape[0]) for sp in specs),
-                       default=0),
-        store=dispatch.store_for(run.calibration_dir))
-    dispatch.record_decision(decision, name=name)
-    mode = decision.mode
+    # The batch-aware rule must see the GLOBAL batch size, not a shard's —
+    # otherwise a single-spec shard would flip to the scan path and the
+    # merged run would not be bit-identical to the unsharded one.
+    mode = resolve_timeline_mode(kernel_mode, batch=len(specs))
     shards, meta = _schedule(
         engine="sweep_timeline",
         payload=lambda lo, hi: {"specs": specs[lo:hi], "lat": lat,
                                 "block": block, "mode": mode},
         n_items=len(specs), mode=mode, run_cfg=run, sched=sched, name=name)
-    meta["dispatch"] = decision.to_json()
+    meta["dispatch"] = backend_record("sweep_timeline", kernel_mode, mode)
     results: List[TimelineResult] = []
     for sh in shards:
         for j, g in enumerate(range(sh.lo, sh.hi)):

@@ -48,6 +48,7 @@ from repro.kernels.stackdist import stack_scan
 __all__ = [
     "STACKDIST_INF",
     "AUTO_MAX_WAYS",
+    "auto_eligible",
     "MAX_CAP",
     "prev_occurrence",
     "stack_depths",
@@ -63,6 +64,13 @@ STACKDIST_INF = np.int32(np.iinfo(np.int32).max)
 # at most this: the scan state is [lanes, W], so huge fully-associative
 # geometries would trade the N-step scan for a W-wide one.
 AUTO_MAX_WAYS = 16
+
+
+def auto_eligible(ways) -> bool:
+    """May ``"auto"`` pick this engine for LRU structures of these
+    associativities?  A memory-shape limit of the capped stack, not a
+    measured preference."""
+    return max(ways) <= AUTO_MAX_WAYS
 
 # Hard cap: beyond this the capped-stack state stops being "small" in the
 # sense the engine is built around; use the sequential reference instead.

@@ -53,7 +53,7 @@ from typing import Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dispatch, stackdist
+from repro.core import stackdist
 from repro.core.sparta import TLBConfig
 from repro.core.tlbsim import (
     LINE_SHIFT,
@@ -65,7 +65,7 @@ from repro.core.tlbsim import (
     _scan_tlb_batched,
     padded_tlb_state,
 )
-from repro.kernels.common import resolve_mode
+from repro.kernels.common import SWEEP_MODES, resolve_mode
 from repro.kernels.system_sim import resolve_system_mode, system_sim_batched
 from repro.kernels.system_sim.ref import system_sim_batched_ref as _scan_system_batched
 from repro.runtime import telemetry
@@ -180,6 +180,17 @@ def _sweep_keys(
     return np.stack([r[0] for r in rows]), np.stack([r[1] for r in rows])
 
 
+def resolve_tlb_mode(kernel_mode: str, specs: Sequence[TLBSweepSpec]) -> str:
+    """Validate ``kernel_mode`` against :data:`SWEEP_MODES` and resolve
+    ``"auto"`` for this TLB sweep: the stack-distance engine when every
+    spec's associativity is eligible (:func:`repro.core.stackdist.
+    auto_eligible`), else the generic rule."""
+    return resolve_mode(
+        kernel_mode, valid=SWEEP_MODES, engine="sweep_tlb",
+        stackdist_ok=stackdist.auto_eligible(
+            sp.cfg.effective_ways for sp in specs))
+
+
 def sweep_tlb(
     addrs: np.ndarray,
     specs: Sequence[TLBSweepSpec],
@@ -203,11 +214,7 @@ def sweep_tlb(
             "sweep_tlb batch mixes page_shift=None (VPN-stream) specs with "
             "page_shift-set (line-stream) specs; one input stream cannot be both"
         )
-    # Backend selection is the dispatch layer's job; a bare (unorchestrated)
-    # call makes a cold-start decision — the orchestrator passes calibrated,
-    # already-concrete modes down to the streams instead.
-    mode = dispatch.decide_tlb(
-        kernel_mode, specs, n_accesses=len(addrs)).mode
+    mode = resolve_tlb_mode(kernel_mode, specs)
     if mode == "stackdist":
         hits = _sweep_tlb_stackdist(addrs, specs)
         n0 = int(hits.shape[1] * warmup_frac)
@@ -548,8 +555,7 @@ def sweep_system(
     """
     if not cfgs:
         raise ValueError("sweep_system needs at least one config")
-    mode = dispatch.decide_system(
-        kernel_mode, cfgs, n_accesses=int(lines.shape[0])).mode
+    mode = resolve_system_mode(kernel_mode)
 
     streams = [np.stack(rows) for rows in zip(*(_system_keys(lines, c) for c in cfgs))]
 

@@ -363,14 +363,7 @@ def sweep_timeline(
         cols.append(row)
     stacked = [np.stack([row[k] for row in cols]) for k in range(8)]
 
-    # Backend selection through the dispatch layer (cold-start for a bare
-    # call; the orchestrator makes the calibrated decision and passes a
-    # concrete mode).  resolve_timeline_mode still validates + rejects
-    # sweep-only backends for explicit modes.
-    from repro.core import dispatch
-
-    mode = dispatch.decide_timeline(
-        kernel_mode, batch=len(specs), n_accesses=n_max).mode
+    mode = resolve_timeline_mode(kernel_mode, batch=len(specs))
     if mode == "reference":
         chunks = [list(range(len(specs)))]
     else:

@@ -1,4 +1,4 @@
-"""Shared kernel-dispatch helpers.
+"""Shared kernel-mode helpers: the one backend rule, and SMEM budgeting.
 
 Every kernel package exposes ``ops.py`` with a public op that takes
 ``kernel_mode``:
@@ -12,20 +12,28 @@ Every kernel package exposes ``ops.py`` with a public op that takes
                            kernels against the oracle.  CPU backends only:
                            on a TPU it would hide the chip, so it is refused
                            there.
-* ``"auto"``             — resolved by :mod:`repro.core.dispatch`, the one
-                           calibrated backend-selection layer.  For a bare
-                           per-op call that is the cold-start rule
-                           (``pallas`` on TPU backends, else ``reference``);
-                           the orchestrated engines make a full
-                           :class:`~repro.core.dispatch.DispatchDecision`
-                           with per-candidate predicted rates.
+* ``"auto"``             — resolved by :func:`auto_mode`, one static rule.
 
 The trace-sweep engine (:mod:`repro.core.sweep`) accepts one extra mode on
 top of the generic four: ``"stackdist"``, the exact sort-based
 stack-distance backend (:mod:`repro.core.stackdist`).  Sweep entry points
-validate against :data:`SWEEP_MODES`; whether ``"auto"`` picks it is the
-dispatch layer's call (every-spec-eligible pure-LRU TLBs) — per-op kernels
-keep the plain four-mode registry.
+validate against :data:`SWEEP_MODES` and tell :func:`auto_mode` whether
+every spec is eligible for it; per-op kernels keep the plain four modes.
+
+The rule for ``"auto"``, first match wins:
+
+=========================  ==========================  ===============
+engine                     condition                   mode
+=========================  ==========================  ===============
+``sweep_tlb``              every spec stackdist-       ``stackdist``
+                           eligible
+``sweep_timeline``         batch of 1 or less          ``reference``
+any                        ``tpu`` backend             ``pallas``
+any                        any other backend           ``reference``
+=========================  ==========================  ===============
+
+A single timeline sim gives the kernel nothing to amortise, so its scan
+runs; on a CPU the interpreter is never picked.
 """
 from __future__ import annotations
 
@@ -37,18 +45,29 @@ VALID_MODES = ("auto", "reference", "pallas", "pallas_interpret")
 SWEEP_MODES = VALID_MODES + ("stackdist",)
 
 
+def auto_mode(engine: str, *, batch: int = 1, stackdist_ok: bool = False) -> str:
+    """The backend ``kernel_mode="auto"`` runs for ``engine``
+    (``"sweep_tlb"``, ``"sweep_system"``, ``"sweep_timeline"``, or anything
+    else for a bare per-op call): the table in this module's docstring.
+    ``batch`` is the timeline batch; ``stackdist_ok`` says every spec of a
+    TLB sweep is eligible for the stack-distance engine."""
+    if engine == "sweep_tlb" and stackdist_ok:
+        return "stackdist"
+    if engine == "sweep_timeline" and batch <= 1:
+        return "reference"
+    return "pallas" if jax.default_backend() == "tpu" else "reference"
+
+
 def resolve_mode(
     kernel_mode: str,
     *,
     valid: Sequence[str] = VALID_MODES,
+    engine: str = "op",
+    batch: int = 1,
+    stackdist_ok: bool = False,
 ) -> str:
-    """Validate ``kernel_mode`` against ``valid`` and resolve ``"auto"``.
-
-    Explicit modes are always honoured as given; ``"auto"`` resolves to the
-    dispatch layer's generic cold-start default (engine entry points make a
-    richer, calibrated decision through :mod:`repro.core.dispatch` before
-    their per-op calls ever see a mode).
-    """
+    """Validate ``kernel_mode`` against ``valid`` and resolve ``"auto"``
+    through :func:`auto_mode`.  Explicit modes are honoured as given."""
     if kernel_mode not in valid:
         raise ValueError(f"kernel_mode={kernel_mode!r}; expected one of {tuple(valid)}")
     if kernel_mode == "pallas_interpret" and not interpret_allowed():
@@ -57,9 +76,7 @@ def resolve_mode(
             f"CPU validation mode; on the {jax.default_backend()!r} backend "
             f"use 'pallas'")
     if kernel_mode == "auto":
-        from repro.core import dispatch
-
-        return dispatch.default_mode()
+        return auto_mode(engine, batch=batch, stackdist_ok=stackdist_ok)
     return kernel_mode
 
 

@@ -57,9 +57,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Shared with the host-side batched oracle (via padded_tlb_state):
-# kernel/oracle bit-identity depends on both using the same poison scheme.
-from repro.core.tlbsim import _POISON_LAST, _POISON_TAG
 from repro.kernels.common import smem_block
 from repro.kernels.tlb_sim.kernel import (
     LANES,
@@ -70,6 +67,9 @@ from repro.kernels.tlb_sim.kernel import (
     poisoned_state,
     to_lanes,
 )
+# Shared with the host-side batched oracle (via padded_tlb_state):
+# kernel/oracle bit-identity depends on both using the same poison scheme.
+from repro.kernels.tlb_sim.ref import _POISON_LAST, _POISON_TAG
 
 
 # Per structure (cache, accel TLB, mem TLB): each design's representative,

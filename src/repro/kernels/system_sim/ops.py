@@ -43,7 +43,7 @@ def resolve_system_mode(kernel_mode: str) -> str:
             f"probes break the LRU stack-inclusion property, so the "
             f"stack-distance engine cannot serve it; expected one of "
             f"{VALID_MODES}")
-    return resolve_mode(kernel_mode)
+    return resolve_mode(kernel_mode, engine="sweep_system")
 
 
 def system_sim_batched(
@@ -91,7 +91,7 @@ def system_sim_batched_carry(
 ):
     """Chunk-resumable :func:`system_sim_batched`: run ONE trace chunk
     against caller-owned carried state (three
-    :func:`repro.core.tlbsim.padded_tlb_state` pairs) and the global access
+    :func:`repro.kernels.tlb_sim.ref.padded_tlb_state` pairs) and the global access
     counter.  Returns ``((c, a, m) hit bits bool [B, L], state')``; chunked
     execution is bit-identical to the monolithic op in any mode and across
     mode changes at chunk boundaries.

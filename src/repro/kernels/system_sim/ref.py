@@ -16,7 +16,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.tlbsim import padded_tlb_state
+from repro.kernels.tlb_sim.ref import padded_tlb_state
 
 
 @functools.partial(jax.jit, static_argnames=("geom", "valid"))

@@ -41,22 +41,15 @@ def resolve_timeline_mode(kernel_mode: str, *, batch: int = 1) -> str:
 
     Sweep-only backends are rejected loudly (no silent coercion): the
     timeline is not a pure-LRU sweep, so ``"stackdist"`` cannot apply.
-    ``"auto"`` resolves through the dispatch layer's cold-start rule: the
-    scan reference for a degenerate (single-sim) batch — the 0.87x
-    single-sequential-sim Pallas path is never auto-selected cold — and the
-    generic backend rule otherwise (calibrated decisions happen upstream in
-    :mod:`repro.core.dispatch` before per-op calls see a mode).
+    ``"auto"`` resolves through :func:`repro.kernels.common.auto_mode`: the
+    scan reference for a single-sim batch, the generic rule otherwise.
     """
     if kernel_mode in SWEEP_MODES and kernel_mode not in VALID_MODES:
         raise ValueError(
             f"kernel_mode={kernel_mode!r} is a sweep_tlb/miss_ratio_curve-only "
             f"backend, not a timeline backend; the timeline engine accepts "
             f"one of {VALID_MODES}")
-    if kernel_mode == "auto":
-        from repro.core import dispatch
-
-        return dispatch.cold_start_mode("sweep_timeline", batch=batch)
-    return resolve_mode(kernel_mode)
+    return resolve_mode(kernel_mode, engine="sweep_timeline", batch=batch)
 
 
 def timeline_sim(

@@ -28,7 +28,7 @@ choice, hit and update mask stay vectors (:func:`lru_probe`).  These
 kernels store each hit to an SMEM word, the probe's one vector-to-scalar
 transfer; the joint-system kernel keeps its hits on the vector unit
 (``repro.kernels.system_sim``).  The host-side oracles are
-``repro.core.tlbsim._scan_tlb`` and ``repro.core.tlbsim._scan_tlb_batched``.
+``repro.kernels.tlb_sim.ref._scan_tlb`` and ``_scan_tlb_batched``.
 """
 from __future__ import annotations
 
@@ -40,10 +40,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import next_multiple, smem_block
+
 # Shared with the host-side batched oracle: kernel/oracle bit-identity
 # depends on both using the same poison scheme.
-from repro.core.tlbsim import _POISON_LAST, _POISON_TAG
-from repro.kernels.common import next_multiple, smem_block
+from repro.kernels.tlb_sim.ref import _POISON_LAST, _POISON_TAG
 
 LANES = 128
 

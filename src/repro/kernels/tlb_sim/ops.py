@@ -74,7 +74,7 @@ def tlb_sim_batched_carry(
 ):
     """Chunk-resumable :func:`tlb_sim_batched`: run ONE trace chunk against
     caller-owned carried LRU state (initialise with
-    :func:`repro.core.tlbsim.padded_tlb_state`) and the global access counter
+    :func:`repro.kernels.tlb_sim.ref.padded_tlb_state`) and the global access counter
     ``now0``.  Returns ``(hits bool [B, L], tags', last')``; feeding chunks
     sequentially is bit-identical to the monolithic op — in any mode, and
     across mode *changes* at chunk boundaries (the degradation ladder), since

@@ -5,13 +5,14 @@ On a TPU backend nothing may stand in for the chip unseen:
 * a compile or lowering error (Mosaic, the XLA compiler, ValueError,
   NotImplementedError, INVALID_ARGUMENT) is never transient, so the
   orchestrator neither retries, halves nor downgrades it;
-* the interpreter is no rung of the degradation ladder, no dispatch
-  candidate and no explicit mode there;
+* the interpreter is no rung of the degradation ladder, never what
+  ``"auto"`` resolves to and no explicit mode there;
 * the scheduler's process executor refuses to start, since the parent
   already holds the chip.
 
 These run on CPU with ``jax.default_backend`` steered to ``"tpu"`` (the
-dispatch tests' seam); the interpret-mode behaviour on CPU stays as it was.
+backend-rule tests' seam); the interpret-mode behaviour on CPU stays as it
+was.
 """
 import multiprocessing
 
@@ -21,12 +22,13 @@ from _faultinject import transient_faults
 
 import jax
 import repro.kernels.common as kc
-from repro.core import dispatch, scheduler
+from repro.core import scheduler
 from repro.core.orchestrator import SweepRunConfig, ladder_from, run_sweep_tlb
 from repro.core.sparta import TLBConfig
-from repro.core.sweep import TLBSweepSpec, sweep_tlb
-from repro.core.tlbsim import SystemSimConfig
+from repro.core.sweep import TLBSweepSpec, resolve_tlb_mode, sweep_tlb
 from repro.kernels.common import resolve_mode
+from repro.kernels.system_sim import resolve_system_mode
+from repro.kernels.timeline import resolve_timeline_mode
 from repro.runtime.fault_tolerance import PreemptionHandler, is_transient
 
 BLOCK = 128
@@ -110,26 +112,31 @@ def test_cpu_ladder_keeps_the_interpreter():
     assert ladder_from("pallas_interpret") == ("pallas_interpret", "reference")
 
 
+def _resolvers(specs):
+    """Each engine's entry point, asked for ``mode`` (timeline at batch 1
+    and 8, TLB on a stackdist-ineligible sweep)."""
+    return [lambda m: resolve_tlb_mode(m, [s for s in specs
+                                           if s.cfg.ways == 32]),
+            resolve_system_mode,
+            lambda m: resolve_timeline_mode(m, batch=1),
+            lambda m: resolve_timeline_mode(m, batch=8),
+            resolve_mode]
+
+
 def test_no_interpret_candidate_or_mode_on_tpu(on_tpu):
     _, specs = _inputs()
-    decisions = [
-        dispatch.decide_tlb("auto", specs),
-        dispatch.decide_system("auto", [SystemSimConfig(num_partitions=8)]),
-        dispatch.decide_timeline("auto", batch=8),
-    ]
-    for d in decisions:
-        assert "pallas" in d.candidates
-        assert "pallas_interpret" not in d.candidates
-    with pytest.raises(ValueError, match="pallas_interpret"):
-        resolve_mode("pallas_interpret")
-    with pytest.raises(ValueError, match="pallas_interpret"):
-        dispatch.decide_timeline("pallas_interpret", batch=8)
+    for resolve in _resolvers(specs):
+        assert resolve("auto") in ("pallas", "reference")
+        with pytest.raises(ValueError, match="pallas_interpret"):
+            resolve("pallas_interpret")
+    assert resolve_system_mode("auto") == "pallas"
 
 
 def test_interpret_is_the_cpu_candidate():
-    d = dispatch.decide_system("auto", [SystemSimConfig(num_partitions=8)])
-    assert "pallas_interpret" in d.candidates and "pallas" not in d.candidates
-    assert resolve_mode("pallas_interpret") == "pallas_interpret"
+    _, specs = _inputs()
+    for resolve in _resolvers(specs):
+        assert resolve("auto") == "reference"
+        assert resolve("pallas_interpret") == "pallas_interpret"
 
 
 def test_process_executor_refuses_a_tpu_backend(on_tpu):
