@@ -48,8 +48,10 @@ along as data flags; the batched scan oracle lives in
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -507,6 +509,110 @@ def _system_keys(lines: np.ndarray, cfg: SystemSimConfig):
     return c_set, c_tag, a_set, a_tag, m_set, m_tag
 
 
+def _system_key_rules(cfgs: Sequence[SystemSimConfig]):
+    """Per structure (cache, accel TLB, mem TLB), each design's key rule as
+    :func:`_system_keys` applies it to the line address, ``(shift,
+    partitions, sets)``, or ``None`` where the design has no such
+    structure."""
+    return (
+        tuple(None if c.cache is None else (0, 1, _geom(c.cache)[0])
+              for c in cfgs),
+        tuple(None if c.accel_tlb is None else
+              (c.page_shift - LINE_SHIFT, 1, _geom(c.accel_tlb)[0])
+              for c in cfgs),
+        tuple((c.page_shift - LINE_SHIFT, c.num_partitions, _geom(c.mem_tlb)[0])
+              for c in cfgs),
+    )
+
+
+def _keys_fit_device(lines: np.ndarray, rules) -> bool:
+    """Whether :func:`_device_system_keys` gives ``lines`` the host's keys:
+    every line is a uint32 and every tag an int32.  Where not, the host
+    builds the keys (:func:`_system_keys`), and raises its ``tag overflow``
+    where a tag does not fit."""
+    if lines.size == 0 or (lines.dtype.kind == "i" and int(lines.min()) < 0):
+        return False
+    hi = int(lines.max())
+    return hi < 1 << 32 and all(
+        (hi >> shift) // (parts * sets) < 1 << 31
+        for rows in rules for shift, parts, sets in filter(None, rows))
+
+
+def _padded_lines(lines: np.ndarray, length: int) -> np.ndarray:
+    """The lines as uint32, zero-padded to ``length``."""
+    out = np.zeros(length, np.uint32)
+    out[:lines.shape[0]] = lines
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("rules", "parked"))
+def _device_system_keys(lines, n_valid, *, rules, parked):
+    """:func:`_system_keys` of one line stream (uint32 [L]) on the device,
+    stacked over the designs of ``rules`` (:func:`_system_key_rules`): the
+    six (set, tag) streams, int32 [B, L] each.  Each distinct key rule is
+    derived once; a design without the structure gets zeros; positions from
+    ``n_valid`` on get the pads the kernels expect, set ``parked[k]`` of
+    structure k and tag 0."""
+    views = {}
+
+    def view(rule):
+        if rule not in views:
+            shift, parts, sets = rule
+            v = lines >> shift
+            p, k = (v % parts, v // parts) if parts > 1 else (0, v)
+            views[rule] = ((p * sets + k % sets).astype(jnp.int32),
+                           (k // sets).astype(jnp.int32))
+        return views[rule]
+
+    zeros = jnp.zeros(lines.shape, jnp.int32)
+    live = jnp.arange(lines.shape[0]) < n_valid
+    out = []
+    for rows, park in zip(rules, parked):
+        pairs = [(zeros, zeros) if r is None else view(r) for r in rows]
+        out.append(jnp.where(live, jnp.stack([s for s, _ in pairs]), park))
+        out.append(jnp.where(live, jnp.stack([t for _, t in pairs]), 0))
+    return tuple(out)
+
+
+def _rows(rules, designs):
+    """``rules`` restricted to ``designs``, in their order."""
+    return tuple(tuple(rows[i] for i in designs) for rows in rules)
+
+
+def _key_streams(lines: np.ndarray, cfgs: Sequence[SystemSimConfig],
+                 groups: Sequence[Sequence[int]],
+                 parked: Sequence[Sequence[int]], length: int):
+    """Each group's six key streams, int32 [len(g), length] device arrays:
+    :func:`_system_keys` of ``lines`` for each design of the group, then
+    pads to ``length`` in the group's ``parked`` set rows with tag 0.  Where
+    the lines fit (:func:`_keys_fit_device`), one uint32 upload of the
+    lines and the keys derived on the device; else built on the host, padded
+    and uploaded.  Counts the chunk as ``sweep_system.device_key_chunks`` or
+    ``sweep_system.host_key_chunks``."""
+    tracer = telemetry.get_tracer()
+    n = lines.shape[0]
+    rules = _system_key_rules(cfgs)
+    on_device = _keys_fit_device(lines, rules)
+    if tracer.active:
+        tracer.counter("sweep_system.%s_key_chunks"
+                       % ("device" if on_device else "host")).add(1)
+    if on_device:
+        with tracer.span("chunk.upload"):
+            dev_lines = jnp.asarray(_padded_lines(lines, length))
+        with tracer.span("chunk.keys"):
+            return [_device_system_keys(dev_lines, n, rules=_rows(rules, g),
+                                        parked=tuple(park))
+                    for g, park in zip(groups, parked)]
+    with tracer.span("chunk.keys"):
+        streams = [np.stack(rows) for rows in
+                   zip(*(_system_keys(lines, c) for c in cfgs))]
+        padded = [[np.pad(streams[k][g], ((0, 0), (0, length - n)),
+                          constant_values=park[k // 2] if k % 2 == 0 else 0)
+                   for k in range(6)] for g, park in zip(groups, parked)]
+    with tracer.span("chunk.upload"):
+        return [[jnp.asarray(s) for s in group] for group in padded]
+
+
 def _system_share(cfgs: Sequence[SystemSimConfig]):
     """The share map of one kernel batch
     (:mod:`repro.kernels.system_sim.kernel`): per structure, each design's
@@ -556,8 +662,14 @@ def sweep_system(
     if not cfgs:
         raise ValueError("sweep_system needs at least one config")
     mode = resolve_system_mode(kernel_mode)
-
-    streams = [np.stack(rows) for rows in zip(*(_system_keys(lines, c) for c in cfgs))]
+    lines = np.asarray(lines)
+    n = lines.shape[0]
+    n0 = int(n * warmup_frac)
+    # The Pallas path pads the trace tail to whole blocks with accesses
+    # parked in an extra set row (index = envelope sets) that no real config
+    # ever indexes.
+    blk = min(block, n)
+    pad = 0 if mode == "reference" else (-n) % blk
 
     def envelope(geoms):
         return max(g[0] for g in geoms), max(g[1] for g in geoms), tuple(g[1] for g in geoms)
@@ -566,8 +678,6 @@ def sweep_system(
     a_geo = [_geom(c.accel_tlb) for c in cfgs]
     m_geo = [(_geom(c.mem_tlb)[0] * c.num_partitions, _geom(c.mem_tlb)[1]) for c in cfgs]
 
-    n = lines.shape[0]
-    n0 = int(n * warmup_frac)
     if mode == "reference":
         cs, cw, c_valid = envelope(c_geo)
         asets, aw, a_valid = envelope(a_geo)
@@ -580,8 +690,10 @@ def sweep_system(
                 lambda c: c.accel_probe_on_miss_only,
             )
         )
+        [streams] = _key_streams(lines, cfgs, [range(len(cfgs))],
+                                 [(cs, asets, ms)], n)
         ys = _scan_system_batched(
-            tuple(jnp.asarray(s) for s in streams),
+            tuple(streams),
             flags,
             (cs, cw, asets, aw, ms, mw),
             (c_valid, a_valid, m_valid),
@@ -590,30 +702,23 @@ def sweep_system(
         return BatchedSystemEvents(c_hit, a_hit, m_hit, n_warm=n - n0)
 
     # Pallas path: chunk the batch so each chunk's three-structure envelope
-    # fits the VMEM scratch budget, and pad the trace tail to whole blocks
-    # with accesses parked in an extra set row (index = envelope sets) that
-    # no real config ever indexes.
+    # fits the VMEM scratch budget.
     flags_np = np.asarray(
         [[c.cache is not None, c.accel_tlb is not None, c.accel_probe_on_miss_only]
          for c in cfgs], np.int32)
     dims = [c_geo[i] + a_geo[i] + m_geo[i] for i in range(len(cfgs))]
-    blk = min(block, n)
-    pad = (-n) % blk
     hits = [np.empty((len(cfgs), n), dtype=bool) for _ in range(3)]
-    for chunk in _system_vmem_chunks(dims, block=blk):
-        geom, valid, chunk_streams = [], [], []
-        for k, geos in enumerate((c_geo, a_geo, m_geo)):
-            sets = max(geos[i][0] for i in chunk)
-            ways = max(geos[i][1] for i in chunk)
-            s_c, t_c = streams[2 * k][chunk], streams[2 * k + 1][chunk]
-            if pad:
-                s_c = np.pad(s_c, ((0, 0), (0, pad)), constant_values=sets)
-                t_c = np.pad(t_c, ((0, 0), (0, pad)), constant_values=0)
-            geom += [sets + (1 if pad else 0), ways]
+    chunks = _system_vmem_chunks(dims, block=blk)
+    parked = [[max(geos[i][0] for i in chunk) for geos in (c_geo, a_geo, m_geo)]
+              for chunk in chunks]
+    keys = _key_streams(lines, cfgs, chunks, parked, n + pad)
+    for chunk, park, chunk_keys in zip(chunks, parked, keys):
+        geom, valid = [], []
+        for sets, geos in zip(park, (c_geo, a_geo, m_geo)):
+            geom += [sets + (1 if pad else 0), max(geos[i][1] for i in chunk)]
             valid.append(tuple(geos[i][1] for i in chunk))
-            chunk_streams += [jnp.asarray(s_c), jnp.asarray(t_c)]
         ys = system_sim_batched(
-            *chunk_streams, jnp.asarray(flags_np[chunk]),
+            *chunk_keys, jnp.asarray(flags_np[chunk]),
             tuple(geom), tuple(valid), block=blk,
             share=_system_share([cfgs[i] for i in chunk]), kernel_mode=mode)
         for h, y in zip(hits, ys):
@@ -652,15 +757,19 @@ class SystemSweepStream:
         self._flags = np.asarray(
             [[c.cache is not None, c.accel_tlb is not None,
               c.accel_probe_on_miss_only] for c in self.cfgs], np.int32)
+        self._group_flags = [jnp.asarray(self._flags[g]) for g in self.groups]
+        self._parked = []
         self._state = []
         for g in self.groups:
-            st = []
+            st, parked = [], []
             for geos in self._geos:
                 sets = max(geos[i][0] for i in g)
                 ways = max(geos[i][1] for i in g)
                 valid = tuple(geos[i][1] for i in g)
                 st += list(padded_tlb_state(len(g), sets + 1, ways, valid))
+                parked.append(sets)
             self._state.append(tuple(st))
+            self._parked.append(tuple(parked))
         self.now = 0
         # Every chunk runs padded to the longest this stream has run, so a
         # trace's short tail reuses the kernel its full chunks compiled.
@@ -688,23 +797,20 @@ class SystemSweepStream:
         mode = resolve_system_mode(kernel_mode)
         tracer = telemetry.get_tracer()
         lines = np.asarray(lines)
-        with tracer.span("chunk.keys"):
-            streams = [np.stack(rows) for rows in
-                       zip(*(_system_keys(lines, c) for c in self.cfgs))]
         n = lines.shape[0]
         self._length = max(self._length, n)
+        keys = _key_streams(
+            lines, self.cfgs, self.groups, self._parked,
+            self._length + (-self._length) % (min(self.block, self._length) or 1))
         from repro.kernels.system_sim import system_sim_batched_carry
 
         hits = [np.empty((len(self.cfgs), n), dtype=bool) for _ in range(3)]
         new_state = []
         for gi, g in enumerate(self.groups):
-            with tracer.span("chunk.upload"):
-                keys = [jnp.asarray(s[g]) for s in streams]
-                flags = jnp.asarray(self._flags[g])
             with tracer.span("chunk.launch"):
                 ys, st = system_sim_batched_carry(
-                    *keys, flags, self._state[gi], self.now,
-                    share=self._shares[gi], length=self._length,
+                    *keys[gi], self._group_flags[gi], self._state[gi],
+                    self.now, share=self._shares[gi], n_valid=n,
                     block=self.block, kernel_mode=mode)
             with tracer.span("chunk.pull"):
                 for h, y in zip(hits, ys):

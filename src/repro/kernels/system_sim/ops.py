@@ -85,46 +85,36 @@ def system_sim_batched_carry(
     now0: int,                                # accesses consumed before chunk
     *,
     share: Share,
-    length: int,
+    n_valid: int,
     block: int = 512,
     kernel_mode: str = "auto",
 ):
     """Chunk-resumable :func:`system_sim_batched`: run ONE trace chunk
     against caller-owned carried state (three
     :func:`repro.kernels.tlb_sim.ref.padded_tlb_state` pairs) and the global access
-    counter.  Returns ``((c, a, m) hit bits bool [B, L], state')``; chunked
+    counter.  Returns ``((c, a, m) hit bits bool [B, n_valid], state')``; chunked
     execution is bit-identical to the monolithic op in any mode and across
     mode changes at chunk boundaries.
 
     State layout contract: each structure's carried state must include one
     spare *parked* set row at its last index that no real access ever
-    indexes; Pallas chunks are padded to ``length`` accesses (at least the
-    chunk's, rounded up to whole kernel blocks) with accesses into those
-    rows, and the kernel runs only the blocks that hold the chunk's own
-    accesses (stamps of the pads live only in the parked rows, their hit
-    bits are dropped), so mid-stream padding is unobservable and chunks of
-    one ``length`` share one compiled kernel.  Each follower's carried state
-    under the ``share`` map must equal its representative's: it does from
-    fresh state on, in any mode."""
+    indexes.  The caller pads the streams: they hold the chunk's
+    ``n_valid`` own accesses, then accesses into those rows (tag 0) up to
+    a whole number of kernel blocks.  The kernel runs only the blocks that
+    hold the chunk's own accesses (stamps of the pads live only in the
+    parked rows, their hit bits are dropped), so mid-stream padding is
+    unobservable and chunks padded to one length share one compiled
+    kernel; the reference mode runs the first ``n_valid`` accesses.  Each
+    follower's carried state under the ``share`` map must equal its
+    representative's: it does from fresh state on, in any mode."""
     mode = resolve_system_mode(kernel_mode)
     state = tuple(state)
+    n = int(n_valid)
+    streams = (c_set, c_tag, a_set, a_tag, m_set, m_tag)
     if mode == "reference":
         bools = tuple(flags[:, c].astype(bool) for c in range(3))
         return system_sim_batched_carry_ref(
-            (c_set, c_tag, a_set, a_tag, m_set, m_tag), bools,
-            state, jnp.asarray(now0))
-    n = int(c_set.shape[1])
-    length = max(int(length), n)
-    pad = length + (-length) % min(block, length) - n
-    streams = [c_set, c_tag, a_set, a_tag, m_set, m_tag]
-    if pad:
-        for k in range(3):
-            parked = int(state[2 * k].shape[1]) - 1
-            s, t = streams[2 * k], streams[2 * k + 1]
-            streams[2 * k] = jnp.concatenate(
-                [s, jnp.full((s.shape[0], pad), parked, s.dtype)], axis=1)
-            streams[2 * k + 1] = jnp.concatenate(
-                [t, jnp.zeros((t.shape[0], pad), t.dtype)], axis=1)
+            tuple(s[:, :n] for s in streams), bools, state, jnp.asarray(now0))
     hits, state = system_sim_batched_pallas_carry(
         *streams, flags, state, now0, n,
         share=share, block=block, interpret=(mode == "pallas_interpret"))

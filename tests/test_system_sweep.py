@@ -459,3 +459,169 @@ def test_system_stream_counts_lru_probes(batch, mode, per_access):
         counters = tr.end_run()["counters"]
     assert counters["sweep_system.lru_probes"]["value"] == per_access * n
     assert counters["sweep_system.sim_accesses"]["value"] == len(cfgs) * n
+
+
+# ---------------------------------------------------------------------------
+# Key streams derived on the device from one uint32 line upload.
+# ---------------------------------------------------------------------------
+
+# Sets and partition counts that are no power of two.
+_ODD = [
+    SystemSimConfig(cache=TLBConfig(entries=96, ways=4),
+                    accel_tlb=TLBConfig(entries=12, ways=4), num_partitions=3),
+    SystemSimConfig(cache=TLBConfig(entries=40, ways=8),
+                    mem_tlb=TLBConfig(entries=80, ways=8), num_partitions=6,
+                    page_shift=21),
+    SystemSimConfig(cache=None, mem_tlb=TLBConfig(entries=28, ways=4),
+                    num_partitions=7),
+]
+
+# Lines at the edges of the int32 and uint32 ranges, among random ones.
+_EDGES = np.array([0, 2**31 - 1, 2**31, 2**32 - 1], np.int64)
+
+
+def _wide_lines(seed: int, n: int) -> np.ndarray:
+    lines = np.random.default_rng(seed).integers(0, 2**32, n).astype(np.int64)
+    lines[:_EDGES.size] = _EDGES
+    return lines
+
+
+@pytest.mark.parametrize("batch,n,length", [
+    (_fig10_cfgs, 700, 700),
+    (_fig9_cfgs, 700, 700),
+    (lambda: _ODD, 700, 700),
+    (lambda: _HETEROGENEOUS, 700, 700),
+    # A partial chunk: pads from the 300th position on.
+    (_fig10_cfgs, 300, 768),
+    (lambda: _ODD, 5, 512),
+])
+def test_device_keys_match_host_keys(batch, n, length):
+    """The device key streams equal ``_system_keys`` stacked over the
+    designs, bit for bit, and every pad sits in its structure's parked row
+    with tag 0."""
+    import jax.numpy as jnp
+
+    cfgs = batch()
+    lines = _wide_lines(n, n)
+    rules = sweep._system_key_rules(cfgs)
+    assert sweep._keys_fit_device(lines, rules)
+    parked = (1001, 1002, 1003)
+    got = sweep._device_system_keys(
+        jnp.asarray(sweep._padded_lines(lines, length)), n, rules=rules,
+        parked=parked)
+    want = [np.stack(rows) for rows in
+            zip(*(sweep._system_keys(lines, c) for c in cfgs))]
+    for k, (g, w) in enumerate(zip(got, want)):
+        g = np.asarray(g)
+        assert g.shape == (len(cfgs), length) and g.dtype == np.int32
+        np.testing.assert_array_equal(g[:, :n], w, err_msg=f"stream {k}")
+        assert (g[:, n:] == (parked[k // 2] if k % 2 == 0 else 0)).all()
+
+
+def _hits(evs):
+    return [evs.cache_hit, evs.accel_tlb_hit, evs.mem_tlb_hit]
+
+
+@pytest.mark.parametrize("mode", ["reference", "pallas_interpret"])
+@pytest.mark.parametrize("batch", [_fig10_cfgs, lambda: _ODD])
+def test_chunked_sweep_matches_monolithic_on_device_keys(batch, mode):
+    """Chunked ``run_sweep_system`` (a full chunk of 512 and a partial one
+    of 188, padded to the full one) equals the monolithic ``sweep_system``
+    and ``simulate_system`` on lines across the whole uint32 range, and
+    every chunk's keys come from the device."""
+    from repro.core.orchestrator import SweepRunConfig, run_sweep_system
+    from repro.runtime import telemetry
+
+    cfgs = batch()
+    lines = _wide_lines(29, 700)
+    tr = telemetry.get_tracer()
+    tr.start_run(None, run="device-keys")
+    try:
+        bev, _ = run_sweep_system(lines, cfgs, kernel_mode=mode, block=256,
+                                  run=SweepRunConfig(chunk_accesses=512))
+    finally:
+        counters = tr.end_run()["counters"]
+    assert counters["sweep_system.device_key_chunks"]["value"] == 2
+    assert "sweep_system.host_key_chunks" not in counters
+    whole = sweep_system(lines, cfgs, kernel_mode=mode, block=256)
+    for a, b in zip(_hits(bev), _hits(whole)):
+        np.testing.assert_array_equal(a, b)
+    _assert_rows_match(bev, cfgs, lines)
+
+
+def _count_key_chunks(fn):
+    from repro.runtime import telemetry
+
+    tr = telemetry.get_tracer()
+    tr.start_run(None, run="key-chunks")
+    try:
+        fn()
+    finally:
+        counters = tr.end_run()["counters"]
+    return {k: counters.get(f"sweep_system.{k}_key_chunks", {}).get("value", 0)
+            for k in ("device", "host")}
+
+
+@pytest.mark.parametrize("mode", ["reference", "pallas_interpret"])
+def test_lines_past_uint32_take_the_host_keys(mode):
+    """A chunk holding a line at 2**32 builds its keys on the host, with
+    the hits ``simulate_system`` gives, and counts a host key chunk; the
+    chunk before it, whose lines fit, a device key chunk."""
+    from repro.core.sweep import SystemSweepStream
+
+    cfgs = _fig10_cfgs()
+    lines = _wide_lines(31, 600)
+    lines[450] = 2**32
+    stream = SystemSweepStream(cfgs, block=256)
+    got = []
+    counts = [_count_key_chunks(lambda lo=lo, hi=hi: got.append(
+        stream.run_chunk(lines[lo:hi], kernel_mode=mode)))
+        for lo, hi in ((0, 400), (400, 600))]
+    assert counts == [{"device": 1, "host": 0}, {"device": 0, "host": 1}]
+    whole = [np.concatenate([g[k] for g in got], axis=1) for k in range(3)]
+    _assert_rows_match(sweep.BatchedSystemEvents(*whole, n_warm=0), cfgs,
+                       lines)
+    mono = _count_key_chunks(lambda: _assert_rows_match(
+        sweep_system(lines, cfgs, kernel_mode=mode, block=256), cfgs, lines))
+    assert mono == {"device": 0, "host": 1}
+
+
+@pytest.mark.parametrize("line", [2**31 + 5, 2**40])
+def test_tag_overflow_takes_the_host_keys_and_raises(line):
+    """A line whose tag passes 2**31 under a one-set cache (below 2**32 as
+    well as past it) takes the host keys, which raise their ``tag
+    overflow`` as before."""
+    from repro.core.sweep import SystemSweepStream
+
+    cfgs = [SystemSimConfig(cache=TLBConfig(entries=1, ways=1))]
+    lines = np.array([3, line, 7], np.int64)
+    assert not sweep._keys_fit_device(lines, sweep._system_key_rules(cfgs))
+    stream = SystemSweepStream(cfgs, block=256)
+
+    def run():
+        with pytest.raises(ValueError, match="tag overflow"):
+            stream.run_chunk(lines, kernel_mode="reference")
+    assert _count_key_chunks(run) == {"device": 0, "host": 1}
+    with pytest.raises(ValueError, match="tag overflow"):
+        sweep_system(lines, cfgs, kernel_mode="reference")
+
+
+@pytest.mark.parametrize("line", [2**31 + 3, 2**32])
+def test_key_streams_pad_each_group_alike_on_both_paths(line):
+    """``_key_streams`` gives each VMEM group its designs' keys, then pads
+    in the group's parked rows with tag 0, whether the keys are derived on
+    the device (a line below 2**32) or built on the host (one at 2**32)."""
+    cfgs = _fig10_cfgs()
+    lines = _wide_lines(37, 300) % (2**31)
+    lines[7] = line
+    groups = [[0, 2, 4], [1, 3, 5, 6, 7, 8]]
+    parked = [(501, 502, 503), (601, 602, 603)]
+    got = sweep._key_streams(lines, cfgs, groups, parked, 512)
+    want = [np.stack(rows) for rows in
+            zip(*(sweep._system_keys(lines, c) for c in cfgs))]
+    for g, park, streams in zip(groups, parked, got):
+        for k, s in enumerate(streams):
+            s = np.asarray(s)
+            assert s.shape == (len(g), 512) and s.dtype == np.int32
+            np.testing.assert_array_equal(s[:, :300], want[k][g])
+            assert (s[:, 300:] == (park[k // 2] if k % 2 == 0 else 0)).all()

@@ -175,6 +175,21 @@ def test_system_sim_carry_compiles_for_the_fig10_sweep(one_chip):
         _spec(one_chip, ()), _spec(one_chip, ()))
 
 
+def test_system_device_keys_compile_for_the_fig10_chunk(one_chip):
+    """The fig10 stream's key streams, derived on the chip from one uint32
+    chunk of lines, compile at the chunk's length."""
+    from benchmarks import fig10_performance as fig10
+    from repro.core import sweep
+
+    stream = sweep.SystemSweepStream(fig10.configs())
+    rules = sweep._rows(sweep._system_key_rules(stream.cfgs), stream.groups[0])
+    text = jax.jit(lambda lines, n: sweep._device_system_keys(
+        lines, n, rules=rules, parked=stream._parked[0])
+    ).lower(_spec(one_chip, (CHUNK,), jnp.uint32),
+            _spec(one_chip, ())).compile().as_text()
+    assert "u32[%d]" % CHUNK in text
+
+
 def test_timeline_carry_compiles_for_the_fig11_matrix(one_chip):
     from benchmarks import fig11_tail_latency as fig11
     from repro.kernels.timeline.kernel import timeline_sim_batched_pallas_carry
